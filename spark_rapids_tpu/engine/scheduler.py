@@ -47,6 +47,7 @@ import threading
 from typing import Callable, Iterator, List, Optional, TypeVar
 
 from spark_rapids_tpu.engine import cancel as CX
+from spark_rapids_tpu.engine import compile_clock
 from spark_rapids_tpu.engine import retry as R
 from spark_rapids_tpu.exec.transitions import current_task_id, set_task_id
 from spark_rapids_tpu.memory.semaphore import TpuSemaphore
@@ -102,7 +103,7 @@ class _Attempt:
     speculative duplicate), with its task-scoped cancel token."""
 
     __slots__ = ("future", "token", "submit_ns", "started_ns",
-                 "speculative")
+                 "speculative", "_compile_ns0")
 
     def __init__(self, future: "cf.Future", token: "CX.CancelToken",
                  submit_ns: int, speculative: bool):
@@ -114,6 +115,17 @@ class _Attempt:
         # on an 8-thread pool would read the whole second wave as slow)
         self.started_ns: Optional[int] = None
         self.speculative = speculative
+        self._compile_ns0 = 0
+
+    def mark_started(self, now_ns: int) -> None:
+        self._compile_ns0 = compile_clock.compiling_ns(now_ns)
+        self.started_ns = now_ns
+
+    def runtime_ns(self, now_ns: int) -> int:
+        """Time since a pool thread picked the task up, less the time
+        programs were being built: a cold program is not a straggler."""
+        compiling = compile_clock.compiling_ns(now_ns) - self._compile_ns0
+        return now_ns - self.started_ns - compiling
 
 
 class TaskScheduler:
@@ -363,7 +375,7 @@ class TaskScheduler:
                          attempt: _Attempt) -> T:
         from spark_rapids_tpu.obs.trace import wall_ns
 
-        attempt.started_ns = wall_ns()
+        attempt.mark_started(wall_ns())
         handle = CX.set_task_token(token)
         try:
             if speculative:
@@ -482,7 +494,7 @@ class TaskScheduler:
                             if a0.started_ns is None or \
                                     not a0.future.running():
                                 continue
-                            if now - a0.started_ns < thr_ns:
+                            if a0.runtime_ns(now) < thr_ns:
                                 continue
                             al.append(self._spawn_attempt(
                                 pool, p, fn, True))

@@ -26,9 +26,11 @@ the set of in-flight dispatch registrations on a fixed cadence:
 The timeout is cost-calibrated: `watchdog.dispatchTimeoutMs` when set,
 else 8x the admission-time CostModel prediction of the query's task wall
 (QueryContext.predicted_work_ns, obs/calibrate.py), else a 30s cold-
-start default. The daemon is deliberately CONTEXT-FREE (it acts on
-tokens captured at registration, never on ambient state), uses only
-timed waits, and is torn down with the shared session runtime.
+start default. Time during which a program is being built is not silence
+(engine/compile_clock.py): a cold program's first dispatch may compile
+for minutes. The daemon is deliberately CONTEXT-FREE (it acts on tokens
+captured at registration, never on ambient state), uses only timed
+waits, and is torn down with the shared session runtime.
 """
 
 from __future__ import annotations
@@ -38,6 +40,7 @@ import threading
 from typing import Dict, Optional
 
 from spark_rapids_tpu import conf as C
+from spark_rapids_tpu.engine import compile_clock
 from spark_rapids_tpu.obs.trace import wall_ns
 from spark_rapids_tpu.utils import metrics as M
 
@@ -59,7 +62,7 @@ class DispatchEntry:
     """One in-flight dispatch attempt under watch."""
 
     __slots__ = ("site", "token", "ctx", "start_ns", "timeout_ms",
-                 "released", "escalated", "_cvar_token")
+                 "released", "escalated", "_cvar_token", "_compile_ns0")
 
     def __init__(self, site: str, token, ctx, start_ns: int,
                  timeout_ms: float):
@@ -75,6 +78,14 @@ class DispatchEntry:
         self.released = threading.Event()
         self.escalated = False
         self._cvar_token = None
+        # a program's first dispatch includes its build (or the wait for
+        # another thread's build of it), which is not silence
+        self._compile_ns0 = compile_clock.compiling_ns(start_ns)
+
+    def silent_ms(self, now_ns: int) -> float:
+        """Wall time in flight, less the time programs were being built."""
+        compiling = compile_clock.compiling_ns(now_ns) - self._compile_ns0
+        return (now_ns - self.start_ns - compiling) / 1e6
 
 
 class DispatchWatchdog:
@@ -186,7 +197,7 @@ class DispatchWatchdog:
             with self._mu:
                 entries = list(self._entries.values())
             for entry in entries:
-                silent_ms = (now - entry.start_ns) / 1e6
+                silent_ms = entry.silent_ms(now)
                 if silent_ms < entry.timeout_ms:
                     continue
                 if not entry.released.is_set():

@@ -97,7 +97,7 @@ def key_proxy(col: ColV) -> KeyProxy:
     # integral / date / timestamp. A logically-int64 column whose vrange
     # fits int32 sorts/groups on an int32 proxy (value-preserving, so order
     # and equality are unchanged) — argsort over emulated-int64 pairs is the
-    # hottest lane in sort-based groupby on TPU (BENCH_I64.json).
+    # hottest lane in sort-based groupby on TPU (BENCH_I64_r04.json).
     from spark_rapids_tpu.ops.values import narrow_colv
 
     col = narrow_colv(col)
@@ -346,8 +346,7 @@ def _cumsum_wrap(x):
     mod 2^64: lo-lane wrap at step i shows as clo[i] < clo[i-1], and the
     running wrap count is the hi-lane carry) instead of XLA's 32-bit-pair
     int64 emulation, whose log2(n) scan levels each pay the measured 9.18x
-    emulation tax (BENCH_I64_r04.json; exactness check in
-    tools/tpu_kernel_micro2.py). CPU XLA has native int64 — keep the plain
+    emulation tax (BENCH_I64_r04.json). CPU XLA has native int64 — keep the plain
     cumsum there (the 2-lane form measured ~2.5x slower on CPU)."""
     dt = jnp.dtype(x.dtype)
     if dt.kind not in "iu" or dt.itemsize < 8 \
@@ -403,9 +402,9 @@ def _segmented_scan(per_row_sorted, starts, combine):
     """Inclusive segmented scan (Blelloch flag-carry form): within each run
     of rows sharing a group, accumulate with `combine`; reset at every
     `starts` flag. One associative_scan — log2(capacity) fused elementwise
-    levels, NO scatter. This is the TPU answer to the measured scatter cliff
-    (BENCH_TPU_r04_stages.json: scatter segment reductions 0.63 GB/s vs
-    3+ GB/s for everything else at 16M rows): the per-group reduction
+    levels, NO scatter. This is the TPU answer to the scatter cliff round 4
+    measured on a v5e (scatter segment reductions 0.63 GB/s vs 3+ GB/s for
+    everything else at 16M rows): the per-group reduction
     becomes scan + boundary gather, same as the int-sum cumsum trick but
     valid for ANY associative op and numerically safe for float sums
     (accumulation restarts at each group, so no cross-group magnitude
@@ -535,7 +534,7 @@ def segment_reduce(op: str, data, validity, gid, num_rows, capacity: int):
         if sorted_ok:
             # scatter-free lane: every reduction is scan + boundary gather
             # over the group-sorted order (scatter segment reductions are the
-            # one slow TPU kernel, BENCH_TPU_r04_stages.json)
+            # one slow TPU kernel)
             nonnull = _sorted_counts(validity & in_group, gi, capacity)
             outv = nonnull > 0
             vmask = (validity & in_group)[gi.order]

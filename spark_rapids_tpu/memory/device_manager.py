@@ -19,14 +19,15 @@ import logging
 import threading
 from typing import Optional
 
-from spark_rapids_tpu import _jax_setup  # noqa: F401
+from spark_rapids_tpu import _jax_setup
 import jax
 
 from spark_rapids_tpu import conf as C
 
 log = logging.getLogger(__name__)
 
-_DEFAULT_HBM_BYTES = 16 << 30  # v5e has 16 GiB HBM/chip
+# the cpu backend's allocator reports no limit: budget it like one v5e chip
+_CPU_BACKEND_HBM_BYTES = 16 << 30
 
 
 class TpuDeviceManager:
@@ -82,6 +83,8 @@ class TpuDeviceManager:
         # independent devices.
         self.device = devices[0]
         self.platform = self.device.platform
+        # before the engine's first compile on any entry point
+        _jax_setup.place_compile_cache(self.platform)
         override = self.conf.get(C.HBM_SIZE_OVERRIDE)
         if override:
             self.hbm_total = override
@@ -211,15 +214,19 @@ class TpuDeviceManager:
 
     @staticmethod
     def _detect_hbm(device) -> int:
-        try:
-            stats = device.memory_stats()
-            if stats:
-                for key in ("bytes_limit", "bytes_reservable_limit"):
-                    if key in stats and stats[key]:
-                        return int(stats[key])
-        except Exception:
-            pass
-        return _DEFAULT_HBM_BYTES
+        """The device's memory limit as its allocator reports it. Only the
+        cpu backend, which reports none, gets the constant: an accelerator
+        without a limit is an error, not a guessed 16 GiB."""
+        if device.platform == "cpu":
+            return _CPU_BACKEND_HBM_BYTES
+        stats = device.memory_stats() or {}
+        for key in ("bytes_limit", "bytes_reservable_limit"):
+            if stats.get(key):
+                return int(stats[key])
+        raise RuntimeError(
+            f"{device} ({device.platform}) reports no memory limit "
+            f"(memory_stats: {sorted(stats)}); set "
+            f"{C.HBM_SIZE_OVERRIDE.key} to size the HBM budget by hand")
 
     # -- accounting ----------------------------------------------------------
     def note_donation(self, nbytes: int) -> None:

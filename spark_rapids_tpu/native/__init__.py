@@ -31,13 +31,19 @@ def _build() -> bool:
     gxx = shutil.which("g++") or shutil.which("clang++")
     if gxx is None:
         return False
+    # build beside the target and rename: another process loading the
+    # library never sees a half-written file
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            [gxx, "-O2", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _SO],
+            [gxx, "-O2", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp],
             check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO)
         return True
     except Exception as e:  # noqa: BLE001
         log.info("native build skipped: %s", e)
+        if os.path.exists(tmp):
+            os.remove(tmp)
         return False
 
 

@@ -105,7 +105,7 @@ class BinaryArithmetic(BinaryExpression):
         return None
 
     def _cast_operands(self, ctx, lv, rv):
-        npdt = self._narrow_npdt(ctx, lv, rv) or self.data_type.to_np()
+        npdt = self._narrow_npdt(ctx, lv, rv) or ctx.np_dtype(self.data_type)
         types = (self.left.data_type, self.right.data_type)
 
         def cast(x, dt):
@@ -278,11 +278,13 @@ class Divide(BinaryArithmetic):
             q, ok3 = DU.fit_precision(xp, q, res.precision)
             ok = ok1 & ok2 & ok3
             return ColV(res, xp.where(ok, q, 0), ok)
-        npdt = self.data_type.to_np()
+        npdt = ctx.np_dtype(self.data_type)
         l, r = _d(lv), _d(rv)
         l = l.astype(npdt) if hasattr(l, "astype") else float(l)
         r_arr = r.astype(npdt) if hasattr(r, "astype") else float(r)
-        safe_r = xp.where(r_arr == 0, 1.0, r_arr) if hasattr(r_arr, "dtype") else \
+        # a typed one: a bare 1.0 is traced as an f64 constant under x64
+        safe_r = xp.where(r_arr == 0, npdt.type(1), r_arr) \
+            if hasattr(r_arr, "dtype") else \
             (1.0 if r_arr == 0 else r_arr)
         return l / safe_r
 
@@ -400,7 +402,7 @@ class Remainder(BinaryArithmetic):
         if self._decimal_types() is not None:
             return self._decimal_mod(ctx, lv, rv, positive=False)
         xp = ctx.xp
-        npdt = self._narrow_npdt(ctx, lv, rv) or self.data_type.to_np()
+        npdt = self._narrow_npdt(ctx, lv, rv) or ctx.np_dtype(self.data_type)
         l, r = _d(lv), _d(rv)
         l = l.astype(npdt) if hasattr(l, "astype") else l
         r = r.astype(npdt) if hasattr(r, "astype") else r
@@ -453,7 +455,7 @@ class Pmod(BinaryArithmetic):
         if self._decimal_types() is not None:
             return self._decimal_mod(ctx, lv, rv, positive=True)
         xp = ctx.xp
-        npdt = self._narrow_npdt(ctx, lv, rv) or self.data_type.to_np()
+        npdt = self._narrow_npdt(ctx, lv, rv) or ctx.np_dtype(self.data_type)
         l, r = _d(lv), _d(rv)
         l = l.astype(npdt) if hasattr(l, "astype") else l
         r = r.astype(npdt) if hasattr(r, "astype") else r
@@ -559,11 +561,4 @@ class Signum(UnaryExpression):
         return DataType.FLOAT64
 
     def do_columnar(self, ctx, v):
-        return ctx.xp.sign(v.data).astype(self.data_type.to_np() if not ctx.is_device
-                                          else _phys(ctx))
-
-
-def _phys(ctx):
-    from spark_rapids_tpu.columnar.batch import physical_np_dtype
-
-    return physical_np_dtype(DataType.FLOAT64)
+        return ctx.xp.sign(v.data).astype(ctx.np_dtype(self.data_type))

@@ -186,7 +186,7 @@ class BinaryExpression(Expression):
         if isinstance(lv, ScalarV) and lv.is_null or \
            isinstance(rv, ScalarV) and rv.is_null:
             cap = ctx.capacity
-            npdt = self.data_type.to_np()
+            npdt = ctx.np_dtype(self.data_type)
             data = ctx.xp.zeros((cap,), dtype=npdt if npdt != object else None) \
                 if self.data_type is not DataType.STRING else None
             validity = ctx.xp.zeros((cap,), dtype=bool)
@@ -264,7 +264,8 @@ class TernaryExpression(Expression):
             if self.data_type is DataType.STRING:
                 return _null_string_col(ctx)
             return ColV(self.data_type,
-                        ctx.xp.zeros((ctx.capacity,), dtype=self.data_type.to_np()),
+                        ctx.xp.zeros((ctx.capacity,),
+                                     dtype=ctx.np_dtype(self.data_type)),
                         ctx.xp.zeros((ctx.capacity,), dtype=bool))
         data = self.do_columnar(ctx, *vals)
         validity = and_validity(

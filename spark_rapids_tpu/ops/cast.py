@@ -112,7 +112,7 @@ class Cast(UnaryExpression):
             if to is DataType.BOOL:
                 return data != 0
             if to.is_floating:
-                npdt = self._phys(ctx, to)
+                npdt = ctx.np_dtype(to)
                 return data.astype(npdt) / npdt.type(float(DU.POW10[frm.scale]))
             if to.is_integral:
                 # truncate toward zero, overflow -> null
@@ -120,7 +120,7 @@ class Cast(UnaryExpression):
                 q = xp.where(data < 0, -q, q)
                 info = np.iinfo(to.to_np())
                 ok = (q >= info.min) & (q <= info.max)
-                out = xp.where(ok, q, 0).astype(self._phys(ctx, to))
+                out = xp.where(ok, q, 0).astype(ctx.np_dtype(to))
                 return self._dec_result(ctx, v, to, out, ok)
             raise NotImplementedError(f"cast {frm} -> {to}")
         # numeric -> decimal
@@ -173,25 +173,13 @@ class Cast(UnaryExpression):
                     f"cast to {getattr(to, 'value', to)} overflowed (ANSI)")
         return ColV(to, out, ok)
 
-    def _phys(self, ctx, dt):
-        if ctx.is_device:
-            from spark_rapids_tpu.columnar.batch import physical_np_dtype
-
-            return physical_np_dtype(dt)
-        return dt.to_np()
-
     # -- numeric / datetime --------------------------------------------------
     def _numeric_datetime(self, ctx, v, frm, to):
         xp = ctx.xp
         if is_decimal(frm) or is_decimal(to):
             return self._decimal(ctx, v, frm, to)
         data = v.data
-        if ctx.is_device:
-            from spark_rapids_tpu.columnar.batch import physical_np_dtype
-
-            npdt = physical_np_dtype(to)
-        else:
-            npdt = to.to_np()
+        npdt = ctx.np_dtype(to)
         if frm is DataType.DATE and to is DataType.TIMESTAMP:
             return data.astype(np.int64) * MICROS_PER_DAY
         if frm is DataType.TIMESTAMP and to is DataType.DATE:

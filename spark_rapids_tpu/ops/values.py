@@ -96,6 +96,17 @@ class EvalContext:
     def row_mask(self):
         return self.xp.arange(self.capacity) < self.num_rows
 
+    def np_dtype(self, dt) -> np.dtype:
+        """The dtype an op computes `dt` in: on the device the physical one
+        (DOUBLE is f32 where the backend has no f64 — an f64 traced into a
+        TPU program is not refused but emulated, at minutes of compile), on
+        the numpy engine the exact one."""
+        if self.is_device:
+            from spark_rapids_tpu.columnar.batch import physical_np_dtype
+
+            return physical_np_dtype(dt)
+        return dt.to_np()
+
 
 def narrow_colv(cv: ColV) -> ColV:
     """int32 view of a logically-int64 column whose value range fits int32

@@ -2,9 +2,10 @@
 
 The engine's sync-vs-stay-lazy tradeoffs (e.g. compacting partial-aggregate
 output with a row-count round trip) depend on how expensive a host<->device
-synchronization actually is.  On a locally attached chip a fence is
-~0.1-1 ms and early compaction wins; on a tunneled/remote PJRT backend a
-fence can cost tens of milliseconds, dwarfing any compute it saves.  The
+synchronization actually is.  On a locally attached chip a fence is about
+a millisecond (0.8-1.1 ms on a TPU v5 lite, chip_smoke.py 2026-09-26) and
+early compaction wins; on a remote PJRT backend a fence can cost tens of
+milliseconds, dwarfing any compute it saves.  The
 reference hardcodes the cheap-sync assumption (CUDA streams on a local GPU);
 a TPU-native engine instead measures once and lets policies adapt.
 

@@ -1,0 +1,289 @@
+"""Main-path programs compiled for a described TPU v5e, at SF1 capacities.
+
+The CPU suite cannot take the chip's branches: `device_float64_supported()`
+is a test on the backend, so DOUBLE stays f64 and 64-bit cumulative sums
+stay native here. These tests force the device flavour (jax.default_backend
+patched to say "tpu" — in the test, not through an option of the program),
+capture the programs the engine really builds for chip_smoke's q6/q1 from
+parquet, and hand them to the TPU compiler that is installed here without a
+chip (on-chip-measurement guide, section 2). Each asserts that the lowered
+text holds no f64: the chip's compiler would not refuse one, it would
+emulate it, at minutes of compile per program (the first chip run of PR 22
+spent 33 s on q6 for that reason).
+
+Sort-bearing programs compile for minutes at every size (q1's aggregate
+update kernel: 255 s cold on the v5e, PR 22), so q1's is only lowered here
+and its compile is left to chip_smoke.py.
+"""
+
+import os
+import sys
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+ROW_GROUP_CAP = 1 << 19   # an SF1 lineitem row group (chip_smoke.py)
+PARTITION_CAP = 1 << 21   # a 1.5M-row SF1 partition
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: keep the cache off around them."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+class _Recorded:
+    """A jitted function that notes each call made with concrete arguments
+    (a call from inside another trace is that program's business)."""
+
+    def __init__(self, fun, jitted, calls):
+        self._fun, self._jitted, self._calls = fun, jitted, calls
+
+    def __call__(self, *a, **k):
+        leaves = jax.tree.leaves((a, k))
+        if not any(isinstance(x, jax.core.Tracer) for x in leaves):
+            self._calls.append((self._fun, self._jitted, a, k))
+        return self._jitted(*a, **k)
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
+
+
+class _Recorder:
+    """jax.jit stand-in: what it builds is recorded."""
+
+    def __init__(self):
+        self.calls = []
+        self._jit = jax.jit
+
+    def __call__(self, fun=None, **kw):
+        if fun is None:
+            return lambda f: self(f, **kw)
+        return _Recorded(fun, self._jit(fun, **kw), self.calls)
+
+    def wrap_module(self, mp, module):
+        """Programs a module jitted when it was imported — before this
+        recorder stood in for jax.jit — are wrapped where they live."""
+        for name, obj in list(vars(module).items()):
+            if not isinstance(obj, _Recorded) and hasattr(obj, "lower") \
+                    and hasattr(obj, "__wrapped__"):
+                mp.setattr(module, name,
+                           _Recorded(obj.__wrapped__, obj, self.calls))
+
+    def programs(self, qualname_part: str, filename: str):
+        return [c for c in self.calls
+                if qualname_part in getattr(c[0], "__qualname__", "")
+                and c[0].__code__.co_filename.endswith(filename)]
+
+
+@pytest.fixture(scope="module")
+def device_flavour():
+    """The engine as it traces on the chip: DOUBLE is f32, 64-bit cumsum
+    rides two lanes. Captures what it builds; nothing runs on a TPU."""
+    from spark_rapids_tpu.engine import jit_cache
+
+    from spark_rapids_tpu.io import parquet_device
+
+    mp = pytest.MonkeyPatch()
+    rec = _Recorder()
+    mp.setattr(jax, "default_backend", lambda: "tpu")
+    mp.setattr(jax, "jit", rec)
+    rec.wrap_module(mp, parquet_device)
+    jit_cache.clear()
+    try:
+        yield rec
+    finally:
+        mp.undo()
+        jit_cache.clear()
+
+
+@pytest.fixture(scope="module")
+def smoke_programs(device_flavour, tmp_path_factory):
+    """q6 and q1 of chip_smoke.py at sf=0.002 from parquet, q1 on the
+    host-loop executor (at SF1 its SPMD stage exceeds spmd.maxSortLanes and
+    degrades to it: what the chip really ran in PR 22)."""
+    import chip_smoke
+    from spark_rapids_tpu.benchmarks import tpch
+
+    dev, ref = chip_smoke.open_sessions(
+        {"rapids.tpu.sql.spmd.enabled": False})
+    try:
+        _, paths, _ = chip_smoke.generate_and_write(
+            ref, 0.002, 0, str(tmp_path_factory.mktemp("compile_data")))
+        tables = chip_smoke.read_tables(dev, paths)
+        rows = {q: tpch.QUERIES[q](tables).collect() for q in ("q6", "q1")}
+    finally:
+        dev.stop()
+        ref.stop()
+    assert len(rows["q6"]) == 1 and len(rows["q1"]) > 1
+    return types.SimpleNamespace(rec=device_flavour, rows=rows)
+
+
+def _at_capacity(args, tiny: int, cap: int, sharding):
+    """The captured call's arguments as shapes on the described chip, every
+    axis of the tiny run's capacity widened to the SF1 one."""
+    def widen(x):
+        if isinstance(x, (jax.Array, np.ndarray)):
+            shape = tuple(cap if d == tiny else d for d in x.shape)
+            return jax.ShapeDtypeStruct(shape, x.dtype, sharding=sharding)
+        return x
+
+    return jax.tree.map(widen, args)
+
+
+def _capacity_of(args) -> int:
+    return max(x.shape[0] for x in jax.tree.leaves(args)
+               if isinstance(x, (jax.Array, np.ndarray)) and x.ndim == 1)
+
+
+def _lower(jitted, args, kwargs):
+    lowered = jitted.lower(*args, **kwargs)
+    text = lowered.as_text()
+    assert "f64" not in text, \
+        [ln for ln in text.splitlines() if "f64" in ln][:5]
+    return lowered, text
+
+
+def test_cumsum_wrap_lanes(one_chip, no_persistent_cache):
+    from spark_rapids_tpu.exec import rowkeys as RK
+
+    # tpulint: jit-cache -- one-shot compile of the device-only branch
+    lowered, _ = _lower(jax.jit(RK._cumsum_wrap_lanes), (
+        jax.ShapeDtypeStruct((PARTITION_CAP,), jnp.int64,
+                             sharding=one_chip),), {})
+    ma = lowered.compile().memory_analysis()
+    assert ma.temp_size_in_bytes < 1 << 30
+
+
+def test_q6_fused_aggregate_in_f32(smoke_programs, one_chip,
+                                   no_persistent_cache):
+    """q6's fused stage: the filter and l_extendedprice * l_discount folded
+    into the partial aggregate's update kernel, DOUBLE narrowed to f32."""
+    calls = [c for c in smoke_programs.rec.programs(
+        "_build_update_kernel", "exec/aggregate.py")
+        if "stablehlo.sort" not in c[1].lower(*c[2], **c[3]).as_text()]
+    assert calls, "q6 built no global update kernel"
+    fun, jitted, args, kwargs = calls[0]
+    tiny = _capacity_of(args)
+    lowered, text = _lower(jitted, _at_capacity(
+        args, tiny, ROW_GROUP_CAP, one_chip), kwargs)
+    assert f"tensor<{ROW_GROUP_CAP}xf32>" in text
+    assert "stablehlo.sort" not in text  # a global aggregate
+    lowered.compile()
+
+
+def test_q1_aggregate_update_kernel_lowers_in_f32(smoke_programs, one_chip):
+    """The sort-based group-by update of q1 (two encoded string keys, eight
+    aggregates). Lowered only: its compile is minutes (module docstring)."""
+    calls = [c for c in smoke_programs.rec.programs(
+        "_build_update_kernel", "exec/aggregate.py")
+        if "stablehlo.sort" in c[1].lower(*c[2], **c[3]).as_text()]
+    assert calls, "q1 built no sort-based update kernel"
+    fun, jitted, args, kwargs = calls[0]
+    tiny = _capacity_of(args)
+    _, text = _lower(jitted, _at_capacity(
+        args, tiny, ROW_GROUP_CAP, one_chip), kwargs)
+    assert f"tensor<{ROW_GROUP_CAP}xf32>" in text
+
+
+def test_parquet_decode_programs(device_flavour, one_chip,
+                                 no_persistent_cache, tmp_path):
+    """Device decode of one SF1-sized row group: a DOUBLE price column
+    (PLAIN, narrowed to f32 at decode) and a dictionary DATE column."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from spark_rapids_tpu.columnar.dtypes import DataType
+    from spark_rapids_tpu.io import parquet_device as PD
+
+    rows = ROW_GROUP_CAP - 1234
+    rng = np.random.default_rng(0)
+    path = str(tmp_path / "rg.parquet")
+    pq.write_table(pa.table({
+        "price": (rng.random(rows) * 100_000).round(2),
+        "shipdate": pa.array(rng.integers(8036, 10562, rows)
+                             .astype(np.int32)).cast(pa.date32()),
+    }), path, compression="snappy", row_group_size=ROW_GROUP_CAP)
+    pf = pq.ParquetFile(path)
+    rg = pf.metadata.row_group(0)
+    start = len(device_flavour.calls)
+    for ci, dt in ((0, DataType.FLOAT64), (1, DataType.DATE)):
+        col = rg.column(ci)
+        cv = PD.decode_chunk_device(
+            PD.read_chunk_bytes(path, col), dt, rows,
+            max_def=pf.schema.column(ci).max_definition_level,
+            cap=ROW_GROUP_CAP, codec=col.compression)
+        assert cv.data.shape == (ROW_GROUP_CAP,)
+        assert cv.data.dtype == (np.float32 if ci == 0 else np.int32)
+    decode = [c for c in device_flavour.calls[start:]
+              if c[0].__code__.co_filename.endswith("io/parquet_device.py")]
+    assert len(decode) >= 2
+    for fun, jitted, args, kwargs in decode:
+        lowered, _ = _lower(jitted, _at_capacity(
+            args, -1, -1, one_chip), kwargs)
+        lowered.compile()
+
+
+def test_ici_exchange_on_four_chip_mesh(topo, device_flavour,
+                                        no_persistent_cache):
+    """The hash exchange of the ICI shuffle tier (shard_map + all_to_all)
+    over a mesh of the four described chips: q3's join exchange on
+    o_orderkey, eight partitions, with a DOUBLE payload."""
+    from spark_rapids_tpu.columnar.dtypes import DataType
+    from spark_rapids_tpu.ops.base import BoundReference
+    from spark_rapids_tpu.parallel.mesh import DATA_AXIS
+    from spark_rapids_tpu.shuffle import ici
+
+    cap = 1 << 16
+    mesh = Mesh(np.array(topo.devices[:4]), (DATA_AXIS,))
+    dtypes = (DataType.INT64, DataType.FLOAT64, DataType.DATE)
+    kernel = ici._build_exchange_kernel(
+        mesh, tuple(d.value for d in dtypes),
+        ("hash", [BoundReference(0, DataType.INT64, True)], ()),
+        8, cap, (0, 0, 0))
+    sh = NamedSharding(mesh, P(DATA_AXIS))
+
+    def arg(dtype):
+        return jax.ShapeDtypeStruct((4, cap), dtype, sharding=sh)
+
+    args = [arg(np.bool_), arg(np.int64), arg(np.float32), arg(np.int32)] \
+        + [arg(np.bool_)] * 3
+    lowered, text = _lower(kernel, args, {})
+    assert "all_to_all" in text
+    compiled = lowered.compile()
+    assert "all-to-all" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < 16 << 30

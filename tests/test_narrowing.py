@@ -1,7 +1,7 @@
 """Range-aware int64->int32 narrowing tests.
 
-XLA emulates int64 on TPU as 32-bit pairs (~9.8x measured cost,
-BENCH_I64.json). `rapids.tpu.sql.int64.narrowing.enabled` lets device
+XLA emulates int64 on TPU as 32-bit pairs (9.18x measured cost,
+BENCH_I64_r04.json). `rapids.tpu.sql.int64.narrowing.enabled` lets device
 kernels compute logically-int64 expressions in int32 lanes when static
 value-range metadata (`vrange`) proves the result identical. These tests
 pin the PROOF OBLIGATIONS: narrowing must never change a result, at any

@@ -1,0 +1,6 @@
+"""Peak bytes in use on the fullest chip after the window
+(memory_stats()["peak_bytes_in_use"]), in GB."""
+
+
+def read(run):
+    return run.memory_peak_bytes / 1e9

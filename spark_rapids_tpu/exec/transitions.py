@@ -35,6 +35,7 @@ from spark_rapids_tpu.exec.base import (
     count_output,
 )
 from spark_rapids_tpu.memory.semaphore import TpuSemaphore
+from spark_rapids_tpu.obs import trace as OBS
 from spark_rapids_tpu.utils import metrics as M
 
 _task_counter = iter(range(1, 1 << 62))
@@ -119,6 +120,13 @@ def sink_download_many(run):
         with_retry,
     )
 
+    if OBS.current_tracer() is not None:
+        # the fence carries its size: on the caller's DeviceToHost span,
+        # the device bytes of the columns fetched (dictionaries stay
+        # behind; a partial bucket is trimmed before the transfer)
+        OBS.annotate(batches=len(run),
+                     bytes=sum(c.device_memory_size()
+                               for b in run for c in b.columns))
     try:
         return with_retry(lambda: to_host_many(run),
                           site="transfer.download")
@@ -173,7 +181,9 @@ class HostToDeviceExec(TpuExec):
                     # the host batch is intact, so the retry is pure
                     db = with_retry(lambda: hb.to_device(),
                                     site="transfer.upload")
-                peak_mem.set_max(db.device_memory_size())
+                    size = db.device_memory_size()
+                    OBS.annotate(batches=1, bytes=size)
+                peak_mem.set_max(size)
                 yield db
 
         return PartitionedBatches(child_pb.num_partitions,

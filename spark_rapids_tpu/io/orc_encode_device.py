@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.dtypes import DataType
+from spark_rapids_tpu.obs.trace import span as obs_span
 
 # ORC type kinds (orc_proto Type.Kind)
 _KIND = {
@@ -357,16 +358,17 @@ def write_file(path: str, attrs, batches: List[ColumnarBatch],
     body = bytearray(header)
     stripe_infos: List[Tuple[int, int, int, int]] = []
     total_rows = 0
-    for b in batches:
-        if b.host_rows() == 0:
-            continue
-        offset = len(body)
-        data, sfooter, rows = _encode_stripe(attrs, b, comp_kind)
-        sfooter = _compress_stream(sfooter, comp_kind)
-        body += data
-        body += sfooter
-        stripe_infos.append((offset, len(data), len(sfooter), rows))
-        total_rows += rows
+    with obs_span("write.encode"):
+        for b in batches:
+            if b.host_rows() == 0:
+                continue
+            offset = len(body)
+            data, sfooter, rows = _encode_stripe(attrs, b, comp_kind)
+            sfooter = _compress_stream(sfooter, comp_kind)
+            body += data
+            body += sfooter
+            stripe_infos.append((offset, len(data), len(sfooter), rows))
+            total_rows += rows
 
     # Footer
     footer = bytearray()
@@ -399,9 +401,12 @@ def write_file(path: str, attrs, batches: List[ColumnarBatch],
     ps += _fb(8000, b"ORC")                # magic
     assert len(ps) < 256
 
-    with open(path, "wb") as f:
-        f.write(bytes(body))
-        f.write(bytes(footer))
-        f.write(bytes(ps))
-        f.write(struct.pack("B", len(ps)))
+    with obs_span("write.file", encoder="device", path=path,
+                  rows=total_rows, bytes=len(body) + len(footer)
+                  + len(ps) + 1):
+        with open(path, "wb") as f:
+            f.write(bytes(body))
+            f.write(bytes(footer))
+            f.write(bytes(ps))
+            f.write(struct.pack("B", len(ps)))
     return total_rows

@@ -56,6 +56,7 @@ from spark_rapids_tpu.columnar.batch import (
     physical_np_dtype,
 )
 from spark_rapids_tpu.columnar.dtypes import DataType
+from spark_rapids_tpu.obs import trace as OBS
 
 
 # ---------------------------------------------------------------------------
@@ -1108,10 +1109,14 @@ def decode_chunk_device(chunk: bytes, dtype: DataType, num_rows: int,
     Arrow host path)."""
     from spark_rapids_tpu.columnar.batch import ColumnVector
 
-    if codec != "UNCOMPRESSED":
-        chunk, pages = normalize_chunk(chunk, codec)
-    else:
-        pages = parse_pages(chunk)
+    with OBS.span("scan.parse") as sp:
+        if codec != "UNCOMPRESSED":
+            chunk, pages = normalize_chunk(chunk, codec)
+        else:
+            pages = parse_pages(chunk)
+        if sp is not None:
+            sp.attrs["bytes_out"] = len(chunk)
+    OBS.annotate(pages=len(pages))  # on the caller's scan.decode
     from spark_rapids_tpu.columnar.dtypes import is_decimal
 
     cap = cap or bucket_capacity(max(num_rows, 1))
@@ -1123,7 +1128,8 @@ def decode_chunk_device(chunk: bytes, dtype: DataType, num_rows: int,
     if is_dec_flba and not 1 <= flba_len <= 16:
         raise _Unsupported(f"FLBA decimal byte length {flba_len}")
     npdt = np.dtype(np.int32) if is_string else physical_np_dtype(dtype)
-    chunk_dev = jnp.asarray(np.frombuffer(chunk, dtype=np.uint8))
+    with OBS.span("scan.upload", bytes=len(chunk)):
+        chunk_dev = jnp.asarray(np.frombuffer(chunk, dtype=np.uint8))
 
     if not is_string and not is_dec_flba:
         flat = _try_flat_fixed(chunk, chunk_dev, pages, dtype, num_rows,

@@ -27,6 +27,7 @@ writer.
 from __future__ import annotations
 
 import functools
+import os
 import struct
 from typing import List, Optional, Tuple
 
@@ -41,6 +42,7 @@ from spark_rapids_tpu.columnar.batch import (
     device_float64_supported,
 )
 from spark_rapids_tpu.columnar.dtypes import DataType, DecimalType
+from spark_rapids_tpu.obs.trace import span as obs_span
 
 MAGIC = b"PAR1"
 
@@ -358,15 +360,29 @@ def write_file(path: str, attrs, batches: List[ColumnarBatch],
     # encode: pages[column][batch] -> (def_bytes, val_bytes, n_present, n)
     pages: List[List[Tuple[bytes, bytes, int, int]]] = [[] for _ in attrs]
     total_rows = 0
-    for b in batches:
-        # live-masked batches (exchange outputs) compact first: validity
-        # and offsets must be positional over the rows actually written
-        b = ensure_compact(b)
-        for ci, a in enumerate(attrs):
-            defb, valb, npres = encode_column_page(b.columns[ci],
-                                                   b.num_rows)
-            pages[ci].append((defb, valb, npres, b.num_rows))
-        total_rows += b.num_rows
+    with obs_span("write.encode"):
+        for b in batches:
+            # live-masked batches (exchange outputs) compact first:
+            # validity and offsets must be positional over the rows
+            # actually written
+            b = ensure_compact(b)
+            for ci, a in enumerate(attrs):
+                defb, valb, npres = encode_column_page(b.columns[ci],
+                                                       b.num_rows)
+                pages[ci].append((defb, valb, npres, b.num_rows))
+            total_rows += b.num_rows
+    with obs_span("write.file", encoder="device", path=path,
+                  rows=total_rows) as sp:
+        _write_pages(path, attrs, pages, total_rows, codec_id, pa_codec)
+        if sp is not None:
+            sp.attrs["bytes"] = os.path.getsize(path)
+    return total_rows
+
+
+def _write_pages(path: str, attrs, pages, total_rows: int, codec_id: int,
+                 pa_codec) -> None:
+    """Host control plane of write_file: compress each page payload where
+    a codec is set, frame it, and close the file with the footer."""
     with open(path, "wb") as f:
         f.write(MAGIC)
         offset = 4
@@ -431,4 +447,3 @@ def write_file(path: str, attrs, batches: List[ColumnarBatch],
         f.write(footer)
         f.write(struct.pack("<I", len(footer)))
         f.write(MAGIC)
-    return total_rows

@@ -39,6 +39,7 @@ from spark_rapids_tpu.exec.base import (
 from spark_rapids_tpu.exec.transitions import current_task_id
 from spark_rapids_tpu.io.arrow_convert import arrow_to_host_batch
 from spark_rapids_tpu.memory.semaphore import TpuSemaphore
+from spark_rapids_tpu.obs.trace import span as obs_span
 from spark_rapids_tpu.ops.base import AttributeReference
 from spark_rapids_tpu.utils import metrics as M
 
@@ -810,9 +811,15 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
             if pv:
                 hb = _with_partition_columns(
                     hb, rest + [a for a in self.attrs if a.name in pv], pv)
-            host_part = hb.to_device()
             host_names = [a.name for a in rest] + \
                 [a.name for a in self.attrs if a.name in pv]
+            # the host-decoded columns go up at their full width (the
+            # device decoder's chunks go up compressed-size, in
+            # io/parquet_device.py, under the same span name)
+            with obs_span("scan.upload", columns=len(host_names)) as sp:
+                host_part = hb.to_device()
+                if sp is not None:
+                    sp.attrs["bytes"] = host_part.device_memory_size()
         cols = []
         for a in self.attrs:
             if a.name in dev_cols:
@@ -968,10 +975,19 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
     def _read_device(self, split: FileSplit, conf):
         """Device decode for one split; None -> no column qualified (caller
         uses the host path). Mixed batches combine device-decoded columns
-        with host-decoded/partition-value columns at the same capacity."""
+        with host-decoded/partition-value columns at the same capacity.
+
+        Spans (docs/observability.md): `scan.split` for the split's
+        planning (footer, schema maps, eligibility), then per row group,
+        as SIBLINGS of it, the admission wait and `scan.rowgroup` > per
+        column `scan.read`, `scan.decode` (> `scan.parse`, `scan.upload`,
+        in io/parquet_device.py), `scan.host_decode` and `scan.upload`
+        for the columns Arrow decodes. The wait is a sibling so that a
+        task queued in `Acquire TPU Semaphore` sits shallower in the tree
+        than any step of the task that holds the permit — its scan, and
+        the sink's `DeviceToHost` further down the same task."""
         import pyarrow.parquet as pq
 
-        from spark_rapids_tpu.columnar.batch import bucket_capacity
         from spark_rapids_tpu.io import parquet_device as PD
         from spark_rapids_tpu.io.arrow_convert import arrow_to_host_batch
 
@@ -981,85 +997,109 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
         encoded_ok = conf.get(C3.ENCODED_ENABLED)
         fixed_ok = encoded_ok and conf.get(C3.ENCODED_FIXED_DICTIONARIES)
         max_frac = conf.get(C3.ENCODED_MAX_DICT_FRACTION)
-        pf = pq.ParquetFile(split.path)
-        md = pf.metadata
         pv = dict(split.partition_values)
-        schema_index = {md.row_group(0).column(ci).path_in_schema: ci
-                        for ci in range(md.num_columns)}
-        # required columns carry NO definition levels in v1 data pages —
-        # max_def must match or the value stream is misparsed
-        max_def = {pf.schema.column(ci).name:
-                   pf.schema.column(ci).max_definition_level
-                   for ci in range(len(pf.schema.names))}
-        # FLBA byte length per column (decimals; 0 for other physicals)
-        flba_len = {pf.schema.column(ci).name:
-                    (getattr(pf.schema.column(ci), "length", 0) or 0)
-                    for ci in range(len(pf.schema.names))}
         data_attrs = [a for a in self.attrs if a.name not in pv]
-        eligible = []
-        for a in data_attrs:
-            ci = schema_index.get(a.name)
-            if ci is not None and PD.column_eligible(
-                    md.row_group(0).column(ci), a.data_type):
-                eligible.append(a)
+        with obs_span("scan.split", path=split.path) as split_span:
+            pf = pq.ParquetFile(split.path)
+            md = pf.metadata
+            schema_index = {md.row_group(0).column(ci).path_in_schema: ci
+                            for ci in range(md.num_columns)}
+            # required columns carry NO definition levels in v1 data
+            # pages — max_def must match or the value stream is misparsed
+            max_def = {pf.schema.column(ci).name:
+                       pf.schema.column(ci).max_definition_level
+                       for ci in range(len(pf.schema.names))}
+            # FLBA byte length per column (decimals; 0 for other physicals)
+            flba_len = {pf.schema.column(ci).name:
+                        (getattr(pf.schema.column(ci), "length", 0) or 0)
+                        for ci in range(len(pf.schema.names))}
+            eligible = []
+            for a in data_attrs:
+                ci = schema_index.get(a.name)
+                if ci is not None and PD.column_eligible(
+                        md.row_group(0).column(ci), a.data_type):
+                    eligible.append(a)
         if not eligible:
             return None
         groups = list(split.row_groups) if split.row_groups is not None \
             else list(range(md.num_row_groups))
+        if split_span is not None:
+            split_span.attrs["row_groups"] = len(groups)
+            split_span.attrs["device_columns"] = len(eligible)
         rest = [a for a in data_attrs if a not in eligible]
         out = []
         for rg in groups:
             rows = md.row_group(rg).num_rows
-            cap = bucket_capacity(max(rows, 1))
             TpuSemaphore.get().acquire_if_necessary(current_task_id())
-            dev_cols = {}
-            for a in eligible:
-                col = md.row_group(rg).column(schema_index[a.name])
-                chunk = PD.read_chunk_bytes(split.path, col)
-                try:
-                    dev_cols[a.name] = PD.decode_chunk_device(
-                        chunk, a.data_type, rows,
-                        max_def=max_def.get(a.name, 1), cap=cap,
-                        codec=col.compression,
-                        flba_len=flba_len.get(a.name, 0),
-                        encoded_ok=(
+            with obs_span("scan.rowgroup", path=split.path, rg=rg, rows=rows):
+                dev_cols = {}
+                for a in eligible:
+                    col = md.row_group(rg).column(schema_index[a.name])
+                    try:
+                        dev_cols[a.name] = self._decode_chunk(
+                            split.path, col, a, rows,
+                            max_def.get(a.name, 1),
+                            flba_len.get(a.name, 0),
                             (encoded_ok
                              and a.data_type is DataType.STRING)
                             or (fixed_ok and a.data_type in (
                                 DataType.INT64, DataType.DATE,
-                                DataType.TIMESTAMP))),
-                        max_dict_fraction=max_frac)
-                except PD._Unsupported as e:
-                    # a page shape outside the device decoder's scope: the
-                    # whole split decodes on the host, and the query's
-                    # cpuFallbackEvents says so. Anything else the decoder
-                    # raises (a compiler or runtime error of the device)
-                    # propagates to with_retry and the query, like any
-                    # other operator's
-                    import logging
+                                DataType.TIMESTAMP)),
+                            max_frac)
+                    except PD._Unsupported as e:
+                        # a page shape outside the device decoder's scope:
+                        # the whole split decodes on the host, and the
+                        # query's cpuFallbackEvents says so. Anything else
+                        # the decoder raises (a compiler or runtime error
+                        # of the device) propagates to with_retry and the
+                        # query, like any other operator's
+                        import logging
 
-                    from spark_rapids_tpu.utils import metrics as M
-
-                    M.record_cpu_fallback()
-                    logging.getLogger(__name__).warning(
-                        "device parquet decode refused column %r of %s "
-                        "(%s); the split is decoded on the host", a.name,
-                        split.path, e)
-                    return None
-                if ENC.is_encoded(dev_cols[a.name]):
-                    ENC.record_scan_emission(dev_cols[a.name], rows)
-                # footer statistics -> value range: device-decoded columns
-                # never pass through a host array, so the upload-time min/max
-                # pass (columnar.batch.host_value_range) can't see them; the
-                # writer's chunk stats carry the same proof for free
-                dev_cols[a.name].vrange = _pq_stats_vrange(a.data_type, col)
-            verify_footer_vranges(dev_cols)
-            hb = None
-            if rest or pv:
-                sub = FileSplit(split.path, "parquet", (rg,), split.options,
-                                split.partition_values)
-                table = read_split(sub, rest)
-                hb = arrow_to_host_batch(table, rest)
-            out.extend(self._assemble_device_batch(dev_cols, hb, rest, pv,
-                                                   rows, conf))
+                        M.record_cpu_fallback()
+                        if split_span is not None:
+                            split_span.attrs["fallback"] = f"{a.name}: {e}"
+                        logging.getLogger(__name__).warning(
+                            "device parquet decode refused column %r "
+                            "of %s (%s); the split is decoded on the "
+                            "host", a.name, split.path, e)
+                        return None
+                    if ENC.is_encoded(dev_cols[a.name]):
+                        ENC.record_scan_emission(dev_cols[a.name], rows)
+                    # footer statistics -> value range: device-decoded
+                    # columns never pass through a host array, so the
+                    # upload-time min/max pass (columnar.batch.
+                    # host_value_range) can't see them; the writer's chunk
+                    # stats carry the same proof for free
+                    dev_cols[a.name].vrange = _pq_stats_vrange(
+                        a.data_type, col)
+                verify_footer_vranges(dev_cols)
+                hb = None
+                if rest or pv:
+                    sub = FileSplit(split.path, "parquet", (rg,),
+                                    split.options, split.partition_values)
+                    with obs_span("scan.host_decode", columns=len(rest)):
+                        table = read_split(sub, rest)
+                        hb = arrow_to_host_batch(table, rest)
+                out.extend(self._assemble_device_batch(
+                    dev_cols, hb, rest, pv, rows, conf))
         return out
+
+    @staticmethod
+    def _decode_chunk(path: str, col, attr, rows: int, max_def: int,
+                      flba_len: int, encoded_ok: bool, max_frac: float):
+        """One column chunk: its bytes read (`scan.read`) and handed to
+        the device decoder (`scan.decode`)."""
+        from spark_rapids_tpu.columnar.batch import bucket_capacity
+        from spark_rapids_tpu.io import parquet_device as PD
+
+        with obs_span("scan.read", column=attr.name) as sp:
+            chunk = PD.read_chunk_bytes(path, col)
+            if sp is not None:
+                sp.attrs["bytes"] = len(chunk)
+        with obs_span("scan.decode", column=attr.name,
+                      codec=col.compression):
+            return PD.decode_chunk_device(
+                chunk, attr.data_type, rows, max_def=max_def,
+                cap=bucket_capacity(max(rows, 1)), codec=col.compression,
+                flba_len=flba_len, encoded_ok=encoded_ok,
+                max_dict_fraction=max_frac)

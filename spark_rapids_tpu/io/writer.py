@@ -26,6 +26,7 @@ import numpy as np
 
 from spark_rapids_tpu.columnar.batch import HostColumnarBatch
 from spark_rapids_tpu.io.arrow_convert import host_batch_to_arrow
+from spark_rapids_tpu.obs.trace import span as obs_span
 from spark_rapids_tpu.ops.base import AttributeReference
 from spark_rapids_tpu.plan import logical as L
 
@@ -34,29 +35,32 @@ class WriteError(RuntimeError):
     pass
 
 
-def execute_write(session, plan: L.WriteFile) -> None:
+def execute_write(session, plan: L.WriteFile):
+    """Run the write inside the query scope the session opened
+    (TpuSession.execute_write); returns the physical plan that produced
+    the rows, None where mode=ignore found the path taken."""
     path = plan.path
     if os.path.exists(path):
         if plan.mode == "error":
             raise WriteError(
                 f"path {path} already exists (mode=error[ifexists])")
         if plan.mode == "ignore":
-            return
+            return None
         if plan.mode == "overwrite":
             shutil.rmtree(path, ignore_errors=True)
     os.makedirs(path, exist_ok=True)
 
     child = plan.children[0]
     attrs = child.output
-    physical = session._physical_plan(child)
-
     # optional sort after hash ops so written files cluster equal keys
     # (reference: GpuTransitionOverrides.insertHashOptimizeSorts :171-204)
     from spark_rapids_tpu.plan.transition_overrides import (
         insert_hash_optimize_sort,
     )
 
-    physical = insert_hash_optimize_sort(physical, session.conf)
+    with obs_span("plan", kind="stage"):
+        physical = insert_hash_optimize_sort(
+            session._physical_plan(child), session.conf)
 
     # Device-side parquet encode (reference: ColumnarOutputWriter.scala:
     # 62-177 encodes on the accelerator): peel the root DeviceToHost
@@ -90,11 +94,12 @@ def execute_write(session, plan: L.WriteFile) -> None:
         and OE.codec_supported(orc_compression)
         and isinstance(physical, DeviceToHostExec)
         and OE.schema_encodable(attrs))
-    if device_encode or device_encode_orc:
-        physical = physical.children[0]
+    # the device encoders take DEVICE batches: they run the sink's child
+    source = physical.children[0] \
+        if device_encode or device_encode_orc else physical
 
     ctx = session._exec_context()
-    pb = physical.execute(ctx)
+    pb = source.execute(ctx)
     write_id = uuid.uuid4().hex[:12]
 
     def write_partition(pidx: int) -> int:
@@ -103,8 +108,10 @@ def execute_write(session, plan: L.WriteFile) -> None:
 
         # the device encoders read raw (offsets, bytes) string layouts:
         # encoded columns decode at the writer boundary
-        batches = [decode_batch(b) if isinstance(b, ColumnarBatch) else b
-                   for b in pb.iterator(pidx) if b.num_rows > 0]
+        with obs_span("write.collect"):
+            batches = [decode_batch(b) if isinstance(b, ColumnarBatch)
+                       else b
+                       for b in pb.iterator(pidx) if b.num_rows > 0]
         if not batches:
             return 0
         if device_encode and plan.partition_by:
@@ -122,14 +129,17 @@ def execute_write(session, plan: L.WriteFile) -> None:
         if plan.partition_by:
             return _write_partitioned(batches, attrs, plan, path, pidx,
                                       write_id)
-        table = _concat_arrow(batches, attrs)
         fname = f"part-{pidx:05d}-{write_id}.{_ext(plan.fmt)}"
-        _write_table(table, os.path.join(path, fname), plan)
-        return table.num_rows
+        return _write_arrow_file(batches, attrs,
+                                 os.path.join(path, fname), plan)
 
-    session.scheduler.run_job(pb.num_partitions, write_partition)
-    with open(os.path.join(path, "_SUCCESS"), "w"):
-        pass
+    with obs_span("stage:write", kind="stage",
+                  partitions=pb.num_partitions):
+        session.scheduler.run_job(pb.num_partitions, write_partition)
+    with obs_span("write.commit"):
+        with open(os.path.join(path, "_SUCCESS"), "w"):
+            pass
+    return physical
 
 
 def _ext(fmt: str) -> str:
@@ -141,6 +151,21 @@ def _concat_arrow(batches: List[HostColumnarBatch], attrs):
 
     tables = [host_batch_to_arrow(b, attrs) for b in batches]
     return tables[0] if len(tables) == 1 else pa.concat_tables(tables)
+
+
+def _write_arrow_file(batches: List[HostColumnarBatch], attrs,
+                      file_path: str, plan: L.WriteFile) -> int:
+    """One file through Arrow's host writer; returns its rows. The two
+    spans split the host's work: building the Arrow table, then Arrow's
+    encode + compress + file I/O (one call, so one span)."""
+    with obs_span("write.arrow"):
+        table = _concat_arrow(batches, attrs)
+    with obs_span("write.file", encoder="arrow", path=file_path) as sp:
+        _write_table(table, file_path, plan)
+        if sp is not None:
+            sp.attrs.update(rows=table.num_rows,
+                            bytes=os.path.getsize(file_path))
+    return table.num_rows
 
 
 def _write_table(table, file_path: str, plan: L.WriteFile) -> None:
@@ -289,11 +314,10 @@ def _write_partitioned(batches: List[HostColumnarBatch], attrs, plan,
         out_dir = os.path.join(path, _partition_dirname(attrs, part_idx,
                                                         key))
         os.makedirs(out_dir, exist_ok=True)
-        table = _concat_arrow(group_batches, data_attrs)
         fname = f"part-{pidx:05d}-{seq:03d}-{write_id}.{_ext(plan.fmt)}"
-        _write_table(table, os.path.join(out_dir, fname), plan)
+        total += _write_arrow_file(group_batches, data_attrs,
+                                   os.path.join(out_dir, fname), plan)
         seq += 1
-        total += table.num_rows
     return total
 
 

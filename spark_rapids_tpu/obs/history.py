@@ -137,6 +137,7 @@ def build_record(qid: str, tenant: str, status: str, plan_sig,
     # per-operator measured spans flattened from the PR 11 trace
     ops: Dict[str, dict] = {}
     events: List[dict] = []
+    sites: Dict[str, dict] = {}
     if trace is not None:
         for sp in trace.spans():
             if sp.kind == "op":
@@ -146,9 +147,17 @@ def build_record(qid: str, tenant: str, status: str, plan_sig,
                 rec_op["wall_ns"] += sp.duration_ns
                 rec_op["dispatches"] += sp.counts.get("deviceDispatches", 0)
             elif sp.kind == "site":
-                events.append({"kind": "site", "name": sp.name,
-                               "wall_ns": sp.duration_ns,
-                               **{k: v for k, v in sp.counts.items()}})
+                # one row a site NAME, not a span: a scan opens a few
+                # spans a column chunk (scan.read, scan.decode, ...), and
+                # the record must stay a line, not a timeline
+                ev = sites.setdefault(sp.name, {"kind": "site",
+                                                "name": sp.name,
+                                                "calls": 0, "wall_ns": 0})
+                ev["calls"] += 1
+                ev["wall_ns"] += sp.duration_ns
+                for k, v in sp.counts.items():
+                    ev[k] = ev.get(k, 0) + v
+        events.extend(sites.values())
         rec["dropped_spans"] = trace.dropped_spans
     rec["operators"] = [
         {"name": name, "class": CAL.classify(name), **vals}

@@ -307,6 +307,20 @@ def span(name: str, kind: str = KIND_SITE, **attrs):
     return _SpanCtx(tr, name, kind, attrs)
 
 
+def annotate(**attrs) -> None:
+    """Set attrs on the calling thread's open span — for a site that
+    learns a size only inside a span someone else opened (`trace_range`
+    yields nothing; `scan.decode` is opened by the scan around the
+    decoder). Nothing happens with tracing off, or when the open span
+    belongs to another query's tracer."""
+    tr = current_tracer()
+    if tr is None:
+        return
+    sp = _CURRENT_SPAN.get()
+    if sp is not None and sp.owner is tr:
+        sp.attrs.update(attrs)
+
+
 class QueryTrace:
     """A finished query's immutable span tree + exporters. Stashed on
     `session.last_query_trace` after every traced query."""

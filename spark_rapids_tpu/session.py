@@ -12,6 +12,7 @@ Plan capture for tests mirrors ExecutionPlanCaptureCallback
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import threading
 from typing import Any, Dict, Iterator, List, Optional, Sequence
@@ -50,6 +51,18 @@ import weakref
 
 _RUNTIME_LOCK = threading.Lock()
 _LIVE_SESSIONS: "weakref.WeakSet" = weakref.WeakSet()
+
+
+class _QueryRun:
+    """What TpuSession._query_scope hands its body: the tenant's breaker,
+    and the slot for the physical plan it ran (None until planning
+    succeeded), which the scope's accounting reads on exit."""
+
+    __slots__ = ("breaker", "physical")
+
+    def __init__(self, breaker):
+        self.breaker = breaker
+        self.physical: Optional[PhysicalExec] = None
 
 
 class PlanCapture:
@@ -650,16 +663,14 @@ class TpuSession:
                            force_tracing: bool = False,
                            timeout_s: Optional[float] = None):
         """Run one query; returns per-partition lists of host batches (in
-        partition order). The serving entry point: installs the per-query
-        QueryContext (tenant metrics + breaker + injector + retry budget
-        + CancelToken), routes eligible queries through the server's
-        micro-batcher, and otherwise runs the device/degradation
-        pipeline. `timeout_s` overrides rapids.tpu.engine.deadlineMs for
-        this call (df.collect(timeout=...))."""
-        from spark_rapids_tpu.engine import async_exec as AX
+        partition order). The serving entry point: enters the query scope
+        (_query_scope: QueryContext, tracer, accounting), routes eligible
+        queries through the server's micro-batcher, and otherwise runs
+        the device/degradation pipeline. `timeout_s` overrides
+        rapids.tpu.engine.deadlineMs for this call
+        (df.collect(timeout=...))."""
         from spark_rapids_tpu.engine import cancel as CX
         from spark_rapids_tpu.engine import retry as R
-        from spark_rapids_tpu.plan.fusion import count_fused_stages
         from spark_rapids_tpu.utils import faultinject as FI
         from spark_rapids_tpu.utils import metrics as M
 
@@ -678,6 +689,67 @@ class TpuSession:
                 "query refused")
             err.counted = True
             raise err
+
+        with self._query_scope(plan, timeout_s=timeout_s,
+                               force_tracing=force_tracing) as run:
+            breaker = run.breaker
+            routed = self._maybe_micro_batch(plan, breaker,
+                                             allow_micro_batch)
+            if routed is not None:
+                return routed
+            cpu_fallback_ok = self.conf.get(C.CPU_FALLBACK_ENABLED)
+            if breaker.is_open() and cpu_fallback_ok:
+                # the tenant's device path is unhealthy: remaining queries
+                # plan straight on the CPU engine instead of burning
+                # retries. Like the device-failure fallback below, this
+                # run is the backstop: injected faults must not chase it
+                M.record_cpu_fallback()
+                FI.disable()
+                run.physical, results = self._execute_on_cpu(
+                    plan, use_plan_cache)
+            else:
+                # half-open recovery (engine/retry.CircuitBreaker): a
+                # tripped breaker past its cooldown lets probe queries
+                # through — charge the slot so a silent wedge cannot hold
+                # the half-open window open forever
+                if breaker.state() == "half_open":
+                    breaker.note_probe()
+                try:
+                    run.physical, results = self._execute_device(
+                        plan, use_plan_cache)
+                    # the probe verdict: a device query completing closes
+                    # a tripped breaker (no-op on a closed one)
+                    breaker.note_success()
+                except Exception as e:  # noqa: BLE001 — degradation boundary
+                    if not R.failure_is_device_rooted(e):
+                        raise
+                    run.physical, results = self._degrade_device_failure(
+                        plan, e, breaker, cpu_fallback_ok, use_plan_cache)
+            return results
+
+    @contextlib.contextmanager
+    def _query_scope(self, plan: L.LogicalPlan,
+                     timeout_s: Optional[float] = None,
+                     force_tracing: bool = False,
+                     deadline_from_conf: bool = True):
+        """THE scope of one query, entered by every action (a collect
+        through execute_partitions, a write through execute_write):
+        installs the per-query QueryContext (tenant metrics + breaker +
+        injector + retry budget and policy + issue-ahead flags +
+        CancelToken, and the QueryTracer when tracing or history is on),
+        yields a `_QueryRun` whose `physical` the body fills in, and on
+        the way out — however the body ended — accounts a cancellation,
+        pops the context and publishes last_query_metrics,
+        last_query_trace, the tenant totals and the history record.
+        `deadline_from_conf=False` (a write) leaves
+        rapids.tpu.engine.deadlineMs out of the token: it stays
+        cancellable, it has no deadline."""
+        from spark_rapids_tpu.engine import async_exec as AX
+        from spark_rapids_tpu.engine import cancel as CX
+        from spark_rapids_tpu.engine import retry as R
+        from spark_rapids_tpu.plan.fusion import count_fused_stages
+        from spark_rapids_tpu.utils import faultinject as FI
+        from spark_rapids_tpu.utils import metrics as M
 
         # the executing session's conf drives the process-wide narrowing
         # flag (conf.sync_int64_narrowing: covers clone_with copies and
@@ -705,7 +777,8 @@ class TpuSession:
         # the query's CancelToken (engine/cancel.py): per-call timeout
         # wins over the session deadline conf; no deadline = a plain
         # cancellable token (cancel_all / drain / cancel.race still work)
-        deadline_ms = self.conf.get(C.ENGINE_DEADLINE_MS)
+        deadline_ms = self.conf.get(C.ENGINE_DEADLINE_MS) \
+            if deadline_from_conf else 0
         deadline_s = timeout_s if timeout_s is not None else (
             deadline_ms / 1000.0 if deadline_ms > 0 else None)
         qctx.cancel = CX.CancelToken(deadline_s)
@@ -739,7 +812,7 @@ class TpuSession:
         # would make every later drain/stop burn its full quiesce timeout
         with self._inflight_lock:
             self._inflight.add(qctx.cancel)
-        physical = None
+        run = _QueryRun(breaker)
         # explicit success flag for the flight recorder's status tag:
         # sys.exc_info() inside the finally would also see an ENCLOSING
         # handler's exception and mislabel a successful nested query
@@ -751,41 +824,8 @@ class TpuSession:
             from spark_rapids_tpu.engine.watchdog import DispatchWatchdog
 
             DispatchWatchdog.configure(self.conf)
-            routed = self._maybe_micro_batch(plan, breaker,
-                                             allow_micro_batch)
-            if routed is not None:
-                q_succeeded = True
-                return routed
-            cpu_fallback_ok = self.conf.get(C.CPU_FALLBACK_ENABLED)
-            if breaker.is_open() and cpu_fallback_ok:
-                # the tenant's device path is unhealthy: remaining queries
-                # plan straight on the CPU engine instead of burning
-                # retries. Like the device-failure fallback below, this
-                # run is the backstop: injected faults must not chase it
-                M.record_cpu_fallback()
-                FI.disable()
-                physical, results = self._execute_on_cpu(
-                    plan, use_plan_cache)
-            else:
-                # half-open recovery (engine/retry.CircuitBreaker): a
-                # tripped breaker past its cooldown lets probe queries
-                # through — charge the slot so a silent wedge cannot hold
-                # the half-open window open forever
-                if breaker.state() == "half_open":
-                    breaker.note_probe()
-                try:
-                    physical, results = self._execute_device(
-                        plan, use_plan_cache)
-                    # the probe verdict: a device query completing closes
-                    # a tripped breaker (no-op on a closed one)
-                    breaker.note_success()
-                except Exception as e:  # noqa: BLE001 — degradation boundary
-                    if not R.failure_is_device_rooted(e):
-                        raise
-                    physical, results = self._degrade_device_failure(
-                        plan, e, breaker, cpu_fallback_ok, use_plan_cache)
+            yield run
             q_succeeded = True
-            return results
         except (CX.TpuQueryCancelled, CX.TpuOverloadedError) as e:
             # terminal by contract (engine/cancel.py): count it once,
             # note it on the trace, reclaim everything the query holds
@@ -804,8 +844,8 @@ class TpuSession:
             # last-completed-wins per session.
             snap = qctx.snapshot()
             self.last_query_metrics = {
-                M.FUSED_STAGES: (count_fused_stages(physical)
-                                 if physical is not None else 0),
+                M.FUSED_STAGES: (count_fused_stages(run.physical)
+                                 if run.physical is not None else 0),
             }
             for name in (M.DEVICE_DISPATCHES, M.RETRIES, M.SPLIT_RETRIES,
                          M.CPU_FALLBACK_EVENTS, M.FETCH_RETRIES, M.FENCES,
@@ -841,9 +881,9 @@ class TpuSession:
                     self.tenant_metric_totals[name] = \
                         self.tenant_metric_totals.get(name, 0) + v
             if record_history:
-                self._record_history(qctx, physical, snap, finished_trace,
-                                     _wall_ns() - q_started_ns,
-                                     q_succeeded)
+                self._record_history(qctx, run.physical, snap,
+                                     finished_trace,
+                                     _wall_ns() - q_started_ns, q_succeeded)
 
     def _on_query_killed(self, qctx, e: BaseException) -> None:
         """Account + reclaim for a cancelled/shed/deadline-rejected query
@@ -1319,9 +1359,16 @@ class TpuSession:
         return rows
 
     def execute_write(self, plan: L.WriteFile) -> None:
+        """Run one write as a query: the same scope a collect enters
+        (context, tracer, last_query_metrics / last_query_trace, tenant
+        totals), none of execute_partitions' routing around it — no
+        micro-batching, no breaker/CPU ladder, no admission, no deadline
+        from conf — so a write plans, retries and fails as it always
+        did."""
         from spark_rapids_tpu.io.writer import execute_write
 
-        execute_write(self, plan)
+        with self._query_scope(plan, deadline_from_conf=False) as run:
+            run.physical = execute_write(self, plan)
 
 
 class SessionBuilder:

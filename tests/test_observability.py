@@ -69,26 +69,225 @@ def test_span_api_is_noop_outside_traced_query():
     assert isinstance(wall_ns(), int)
 
 
-def test_tracing_off_records_no_trace(session):
-    q = _flagship(_mk_df(session))
-    q.collect()
+def _write_source(session, num_partitions=3):
+    """A device plan over a DOUBLE, a LONG and an INT column: the write
+    under test in its two encoders (with deviceEncode on, the CPU backend
+    — which has f64 — takes the device encoder; a TPU refuses DOUBLE
+    there and takes Arrow's, the path deviceEncode=off forces here)."""
+    rng = np.random.default_rng(11)
+    n = 6000
+    df = session.createDataFrame(
+        {"k": rng.integers(0, 50, n).astype(np.int64),
+         "v": rng.random(n),
+         "d": rng.integers(0, 1000, n).astype(np.int32)},
+        [("k", "long"), ("v", "double"), ("d", "int")],
+        num_partitions=num_partitions)
+    return df.filter(F.col("d") >= 0).withColumn("w", F.col("v") * 2.0), n
+
+
+def _parquet_source(session, tmp_path, row_groups=2):
+    """lineitem-like files on disk (dictionary-encoded snappy, several
+    row groups) and the DataFrame that scans them on the device."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    rng = np.random.default_rng(5)
+    rows = 4096
+    root = tmp_path / "src"
+    root.mkdir()
+    for i in range(2):
+        table = pa.table({
+            "q": rng.integers(1, 51, rows * row_groups).astype(np.int64),
+            "p": rng.integers(0, 1000, rows * row_groups).astype(np.int32),
+            "x": rng.integers(0, 11, rows * row_groups) / 100.0})
+        pq.write_table(table, str(root / f"f{i}.parquet"),
+                       row_group_size=rows, compression="snappy")
+    return session.read.parquet(str(root)), root
+
+
+def _collect_flagship(session, tmp_path):
+    _flagship(_mk_df(session)).collect()
+
+
+def _collect_parquet_scan(session, tmp_path):
+    df, _ = _parquet_source(session, tmp_path / "scan")
+    df.filter(F.col("p") < 500).agg(F.sum("x")).collect()
+
+
+def _write_parquet(session, tmp_path):
+    df, _ = _write_source(session)
+    df.write.parquet(str(tmp_path / "out"))
+
+
+_ACTIONS = {"flagship": _collect_flagship,
+            "parquet_scan": _collect_parquet_scan,
+            "write": _write_parquet}
+
+
+@pytest.mark.parametrize("action", sorted(_ACTIONS))
+def test_tracing_off_records_no_trace(session, tmp_path, action):
+    (tmp_path / "scan").mkdir()
+    _ACTIONS[action](session, tmp_path)
     assert session.last_query_trace is None
 
 
-def test_tracing_adds_zero_dispatches_and_zero_fences(session):
-    """THE overhead contract: the flagship query's deviceDispatches and
-    fencesPerQuery are identical with tracing on vs off."""
-    q = _flagship(_mk_df(session))
-    q.collect()  # warm compiles under tracing-off
-    q.collect()
+@pytest.mark.parametrize("action", sorted(_ACTIONS))
+def test_tracing_adds_zero_dispatches_and_zero_fences(session, tmp_path,
+                                                      action):
+    """THE overhead contract: an action's deviceDispatches and
+    fencesPerQuery are identical with tracing on vs off — the flagship
+    query, a device-decoded parquet scan (the scan.* spans) and a write
+    (a query since PR 25: the write.* spans)."""
+    run = _ACTIONS[action]
+    for sub in ("w0", "w1", "on0", "on1"):
+        (tmp_path / sub / "scan").mkdir(parents=True)
+    run(session, tmp_path / "w0")  # warm compiles under tracing-off
+    before = M.dispatch_count(), M.fence_count()
+    run(session, tmp_path / "w1")
     off = dict(session.last_query_metrics)
+    off_process = (M.dispatch_count() - before[0],
+                   M.fence_count() - before[1])
     session.set_conf(C.OBS_TRACING.key, True)
-    q.collect()  # warm any tracing-path plan-cache interaction
-    q.collect()
+    run(session, tmp_path / "on0")  # warm any tracing-path plan-cache use
+    before = M.dispatch_count(), M.fence_count()
+    run(session, tmp_path / "on1")
     on = dict(session.last_query_metrics)
     assert on[M.DEVICE_DISPATCHES] == off[M.DEVICE_DISPATCHES]
     assert on[M.FENCES] == off[M.FENCES]
+    # the process-wide counters, as the benchmark reads them around an
+    # action, agree with the per-query ones either way
+    assert (M.dispatch_count() - before[0],
+            M.fence_count() - before[1]) == off_process
     assert session.last_query_trace is not None
+
+
+# ---------------------------------------------------------------------------
+# A write is a query: scope, span tree, accounting
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("encoder", ["device", "arrow"])
+def test_traced_write_span_tree_and_accounting(session, tmp_path, encoder):
+    import os
+
+    session.set_conf(C.OBS_TRACING.key, True)
+    session.set_conf(C.PARQUET_DEVICE_ENCODE.key, encoder == "device")
+    df, n = _write_source(session, num_partitions=3)
+    # a query first, so that a write that left last_query_metrics alone
+    # would show THIS query's numbers
+    _flagship(_mk_df(session)).collect()
+    earlier = dict(session.last_query_metrics)
+    assert earlier[M.FENCES] >= 1
+    out = tmp_path / "out"
+    before = M.dispatch_count(), M.fence_count()
+    df.write.parquet(str(out))
+    process = (M.dispatch_count() - before[0], M.fence_count() - before[1])
+    trace = session.last_query_trace
+    assert trace is not None and trace.root.name == "query:WriteFile"
+    names = [s.name for s in trace.spans()]
+    assert names.count("plan") == 1
+    assert [c.name for c in trace.root.children] == \
+        ["plan", "stage:write", "write.commit"], trace.render()
+    stage = trace.find("stage:write")[0]
+    assert stage.kind == "stage"
+    tasks = [c for c in stage.children if c.kind == "task"]
+    assert sorted(t.name for t in tasks) == ["task:p0", "task:p1", "task:p2"]
+    for t in tasks:
+        assert "write.collect" in [c.name for c in t.children]
+    # one write.file a file: rows sum to the rows written, bytes are the
+    # closed files' sizes, and the encoder that ran is on record
+    files = sorted(str(f) for f in out.glob("*.parquet"))
+    spans = trace.find("write.file")
+    assert sorted(s.attrs["path"] for s in spans) == files
+    assert {s.attrs["encoder"] for s in spans} == {encoder}
+    assert sum(s.attrs["rows"] for s in spans) == n
+    assert {s.attrs["path"]: s.attrs["bytes"] for s in spans} == \
+        {f: os.path.getsize(f) for f in files}
+    inner = "write.encode" if encoder == "device" else "write.arrow"
+    assert names.count(inner) == len(files)
+    # every increment landed on some span, the query's own metrics are
+    # the write's (not the earlier query's), and both agree with the
+    # process-wide counters read around the action
+    totals = trace.counts_total()
+    mine = session.last_query_metrics
+    assert (totals.get(M.DEVICE_DISPATCHES, 0),
+            totals.get(M.FENCES, 0)) == process
+    assert (mine[M.DEVICE_DISPATCHES], mine[M.FENCES]) == process
+    assert process[0] >= 1
+    assert mine != earlier
+    # the sink's fence carries its size (the Arrow path downloads whole
+    # columns through DeviceToHost; the device encoder downloads pages)
+    if encoder == "arrow":
+        d2h = trace.find("DeviceToHost")
+        assert len(d2h) >= len(files) and process[1] >= len(files)
+        assert all(s.attrs["bytes"] > 0 and s.attrs["batches"] >= 1
+                   for s in d2h)
+
+
+def test_traced_parquet_scan_spans(tmp_path):
+    """One scan.read and one scan.decode a (row group, column); the bytes
+    read are the footers' compressed chunk sizes; a task waiting for the
+    admission permit is SHALLOWER in the tree than a permit holder's read
+    and no deeper than its row group (the benchmark labels an idle gap
+    with the deepest open span: the worker's step, not the waiters)."""
+    import pyarrow.parquet as pq
+
+    # one permit: with two splits the second task waits in the semaphore
+    session = srt.new_session({C.CONCURRENT_TPU_TASKS.key: 1,
+                               "rapids.tpu.sql.spmd.meshDevices": 1,
+                               C.OBS_TRACING.key: True})
+    try:
+        df, root = _parquet_source(session, tmp_path)
+        df.filter(F.col("p") < 500).agg(F.sum("x"), F.sum("q")).collect()
+        trace = session.last_query_trace
+        assert session.last_query_metrics[M.CPU_FALLBACK_EVENTS] == 0
+    finally:
+        session.stop()
+
+    expected = {}
+    for f in sorted(root.glob("*.parquet")):
+        md = pq.ParquetFile(str(f)).metadata
+        for rg in range(md.num_row_groups):
+            for ci in range(md.num_columns):
+                col = md.row_group(rg).column(ci)
+                expected[(str(f), rg, col.path_in_schema)] = \
+                    col.total_compressed_size
+    depth = {}
+
+    def walk(sp, d):
+        depth[id(sp)] = d
+        for c in sp.children:
+            walk(c, d + 1)
+
+    walk(trace.root, 0)
+    reads, decodes = {}, {}
+    splits = trace.find("scan.split")
+    assert len(splits) == 2
+    for split in splits:
+        assert "fallback" not in split.attrs
+        assert split.attrs["row_groups"] == 2
+        assert split.attrs["device_columns"] == 3
+    for rg_span in trace.find("scan.rowgroup"):
+        for c in rg_span.children:
+            key = (rg_span.attrs["path"], rg_span.attrs["rg"],
+                   c.attrs.get("column"))
+            if c.name == "scan.read":
+                assert key not in reads
+                reads[key] = c.attrs["bytes"]
+            elif c.name == "scan.decode":
+                assert key not in decodes
+                decodes[key] = c
+    assert reads == expected
+    assert set(decodes) == set(expected)
+    for sp in decodes.values():
+        assert sp.attrs["codec"] == "SNAPPY" and sp.attrs["pages"] >= 1
+        assert [c.name for c in sp.children] == ["scan.parse", "scan.upload"]
+        parse, upload = sp.children
+        assert parse.attrs["bytes_out"] == upload.attrs["bytes"] > 0
+    waits = trace.find("Acquire TPU Semaphore")
+    assert waits
+    deepest_wait = max(depth[id(s)] for s in waits)
+    assert deepest_wait < min(depth[id(s)] for s in trace.find("scan.read"))
+    assert deepest_wait <= min(depth[id(s)]
+                               for s in trace.find("scan.rowgroup"))
 
 
 # ---------------------------------------------------------------------------

@@ -471,6 +471,90 @@ def _expand_hybrid(chunk_u8, out_start, is_rle, value, bit_off,
                      packed).astype(jnp.int32)
 
 
+# The forms one hybrid stream's expansion takes. Which one is read off the
+# host's run table, never asked for: `_RUNS` is the general per-lane
+# lookup above; `_PACKED` a stream that is one contiguous run of
+# bit-packed values once its run and page headers are dropped
+# (`_pack_value_stream`); `_ONES` a definition-level stream the host has
+# counted as all present.
+_RUNS, _PACKED, _ONES = "runs", "packed", "ones"
+_PACK_LANES = 32  # values a packed group holds: `bw` whole 32-bit words
+
+
+def _expand_stream(src, tab, bit_width: int, cap: int, form: str):
+    """values[j] for j in [0, cap) of one hybrid stream, in the form its
+    run table allows. `src` is what that form reads: the chunk's bytes
+    (`_RUNS`), the word planes of `_pack_value_stream` (`_PACKED`),
+    nothing (`_ONES`)."""
+    if form == _ONES:
+        return jnp.ones((cap,), jnp.int32)
+    if form == _PACKED:
+        return _unpack_planes(src, bit_width, cap)
+    return _expand_hybrid(src, *tab, bit_width, cap)
+
+
+def _unpack_planes(planes, bit_width: int, cap: int):
+    """Bit-unpack with static shapes only. planes: uint32
+    [bit_width, groups]; column g holds the `bit_width` little-endian
+    words of values 32g .. 32g+31, so value k of every group is a shift
+    and a mask of plane (k * bit_width) // 32 (and of the next plane,
+    where the value straddles two words): 32 elementwise passes over
+    rows of `groups` lanes, then one interleave. No lane looks anything
+    up."""
+    mask = jnp.uint32((1 << bit_width) - 1)
+    lanes = []
+    for k in range(_PACK_LANES):
+        w, sh = divmod(k * bit_width, 32)
+        v = planes[w] >> jnp.uint32(sh)
+        if sh + bit_width > 32:
+            v = v | (planes[w + 1] << jnp.uint32(32 - sh))
+        lanes.append(v & mask)
+    out = jnp.stack(lanes, axis=1).reshape(-1)
+    return out[:cap].astype(jnp.int32)
+
+
+def _pack_value_stream(chunk: bytes, pages, bit_width: int,
+                       cap: int) -> Optional[np.ndarray]:
+    """Host half of the `_PACKED` form. `pages`: per data page, in order,
+    (RunTable, values the page holds), None for a page without a run
+    table. The stream qualifies when every run is bit-packed and every
+    run but the stream's last is full — no page but the last that holds
+    values pads its final group — so the values are ONE bit-packed
+    sequence with headers spliced in. Returns the runs' payload bytes,
+    headers dropped, zero-padded to `cap` values and laid out as the
+    word planes `_unpack_planes` reads; None where the stream needs the
+    general form. A byte copy: no value is decoded here."""
+    starts, lens = [], []
+    padded = False
+    for page in pages:
+        if page is None:
+            return None
+        rt, n = page
+        if n == 0:
+            continue
+        if padded or rt.total < n or bool(rt.is_rle.any()):
+            return None
+        padded = rt.total > n
+        counts = np.diff(rt.out_start, append=np.int32(rt.total))
+        starts.append(rt.bit_off >> 3)
+        lens.append(counts.astype(np.int64) // 8 * bit_width)
+    if not starts:
+        return None
+    starts = np.concatenate(starts)
+    ends = starts + np.concatenate(lens)
+    capw = max(cap, _PACK_LANES)
+    nbytes = capw // 8 * bit_width
+    total = int((ends - starts).sum())
+    if int(ends.max()) > len(chunk) or total > nbytes:
+        return None
+    view = memoryview(chunk)
+    parts = [view[s:e] for s, e in zip(starts.tolist(), ends.tolist())]
+    parts.append(bytes(nbytes - total))
+    words = np.frombuffer(b"".join(parts), np.dtype("<u4")).reshape(
+        capw // _PACK_LANES, bit_width)
+    return np.ascontiguousarray(words.T)
+
+
 def _parse_delta_header(chunk: bytes, pos: int, end: int, n_values: int):
     """Host control plane for one DELTA_BINARY_PACKED page: walk the block/
     miniblock headers into per-miniblock tables (bit offset, width,
@@ -812,31 +896,27 @@ def _pack_flat_tabs(tabs):
     return (out_start, is_rle, value, bit_off)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
-def _flat_dict_kernel(chunk_u8, def_tab, val_tab, dict_vals, bw: int,
-                      cap: int, cap_p: int, has_def: bool):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def _flat_dict_kernel(src, def_tab, val_tab, dict_vals, bw: int,
+                      cap: int, cap_p: int, def_form: str, val_form: str):
     """Whole-chunk dictionary decode in one program: validity expansion,
-    index expansion, dictionary gather, dense->row assembly."""
-    if has_def:
-        validity = _expand_hybrid(chunk_u8, *def_tab, 1, cap).astype(bool)
-    else:
-        validity = jnp.ones((cap,), bool)
-    idx = _expand_hybrid(chunk_u8, *val_tab, bw, cap_p)
+    index expansion, dictionary gather. Each stream expands in the form
+    the host read off its run table (`_expand_stream`)."""
+    validity = _expand_stream(src, def_tab, 1, cap, def_form).astype(bool)
+    idx = _expand_stream(src, val_tab, bw, cap_p, val_form)
     dense = dict_vals[jnp.clip(idx, 0, dict_vals.shape[0] - 1)]
     return dense, validity
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
-def _flat_dict_codes_kernel(chunk_u8, def_tab, val_tab, bw: int,
-                            cap: int, cap_p: int, has_def: bool):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _flat_dict_codes_kernel(src, def_tab, val_tab, bw: int,
+                            cap: int, cap_p: int, def_form: str,
+                            val_form: str):
     """_flat_dict_kernel WITHOUT the dictionary gather: the expanded
     index stream IS the encoded column's code array
     (columnar/encoded.py — fixed-value dictionary chunks)."""
-    if has_def:
-        validity = _expand_hybrid(chunk_u8, *def_tab, 1, cap).astype(bool)
-    else:
-        validity = jnp.ones((cap,), bool)
-    idx = _expand_hybrid(chunk_u8, *val_tab, bw, cap_p)
+    validity = _expand_stream(src, def_tab, 1, cap, def_form).astype(bool)
+    idx = _expand_stream(src, val_tab, bw, cap_p, val_form)
     return idx.astype(jnp.int32), validity
 
 
@@ -869,14 +949,12 @@ def _rle_run_table(val_tabs, num_rows: int):
 
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
 def _flat_plain_kernel(chunk_u8, def_tab, page_meta, np_dtype_name: str,
-                       cap: int, cap_p: int, has_def: bool):
+                       cap: int, cap_p: int, def_form: str):
     """Whole-chunk PLAIN decode: per-lane page lookup (searchsorted over
     dense offsets), byte gather, bitcast. page_meta: int32/int64 [2, m] =
     (dense_end, byte_pos)."""
-    if has_def:
-        validity = _expand_hybrid(chunk_u8, *def_tab, 1, cap).astype(bool)
-    else:
-        validity = jnp.ones((cap,), bool)
+    validity = _expand_stream(chunk_u8, def_tab, 1, cap,
+                              def_form).astype(bool)
     dt = np.dtype(np_dtype_name)
     w = dt.itemsize
     i = jnp.arange(cap_p, dtype=jnp.int32)
@@ -894,18 +972,25 @@ def _flat_plain_kernel(chunk_u8, def_tab, page_meta, np_dtype_name: str,
     return dense, validity
 
 
-@functools.partial(jax.jit, static_argnums=(3,))
-def _flat_finish(dense, validity, nums, cap: int):
-    """Mask validity to the row count and spread dense values to rows."""
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _flat_finish(dense, validity, nums, cap: int, dense_is_rows: bool):
+    """Mask validity to the row count and spread dense values to rows.
+    Where every row is present (`dense_is_rows`: the host counted the
+    definition levels) the dense position IS the row position and
+    nothing is spread."""
     validity = validity & (jnp.arange(cap) < nums[0])
-    data = _assemble(validity, dense, cap)
+    if dense_is_rows:
+        dense = _pad_to(dense, cap, 0)
+        data = jnp.where(validity, dense, jnp.zeros((), dense.dtype))
+    else:
+        data = _assemble(validity, dense, cap)
     return data, validity
 
 
 _FIXED_ENC_DTYPES = (DataType.INT64, DataType.DATE, DataType.TIMESTAMP)
 
 
-def _try_flat_fixed(chunk: bytes, chunk_dev, pages, dtype: DataType,
+def _try_flat_fixed(chunk: bytes, upload, pages, dtype: DataType,
                     num_rows: int, max_def: int, cap: int, npdt,
                     encoded_ok: bool = False,
                     max_dict_fraction: float = 1.0):
@@ -916,6 +1001,19 @@ def _try_flat_fixed(chunk: bytes, chunk_dev, pages, dtype: DataType,
     and 2-3 jitted dispatches decode the entire chunk. Returns a
     ColumnVector, or None when the chunk's shape needs the general
     per-page path (mixed/exotic encodings, strings, bools, FLBA).
+
+    What the run tables state is not recomputed on the device: definition
+    levels the host counted as all present are not expanded and nothing
+    is spread to rows (`_ONES`, `dense_is_rows`), and a dictionary-index
+    stream that is bit-packed throughout goes up as its packed payload
+    alone and is unpacked with static shapes (`_PACKED`: the program's
+    key is (bit width, capacity), not the chunk's bytes or runs). Any
+    other stream takes `_expand_hybrid` on the uploaded chunk. `upload`
+    gives the chunk on the device, sent on first use: the packed form
+    never asks for it. The caller's `scan.decode` span reads `expand` =
+    `packed` where no stream of the chunk needed the per-lane lookup,
+    `runs` where one did, `plain` for a PLAIN chunk without one (its
+    values are gathered a lane all the same).
 
     With `encoded_ok`, an INT64/DATE/TIMESTAMP dictionary chunk clearing
     the ndv/rows heuristic emits a DictionaryColumn instead: codes ARE
@@ -953,6 +1051,7 @@ def _try_flat_fixed(chunk: bytes, chunk_dev, pages, dtype: DataType,
     chunk_np = np.frombuffer(chunk, dtype=np.uint8)
     def_tabs = []
     val_tabs = []
+    val_pages = []  # (RunTable, present) a page, None for a bw == 0 page
     plain_dense_end = []
     plain_pos = []
     rows = 0
@@ -988,6 +1087,7 @@ def _try_flat_fixed(chunk: bytes, chunk_dev, pages, dtype: DataType,
                 return None
             if pbw == 0:
                 val_tabs.append(_synth_rle_tab(present, 0))
+                val_pages.append(None)
             else:
                 if bw is None:
                     bw = pbw
@@ -995,18 +1095,43 @@ def _try_flat_fixed(chunk: bytes, chunk_dev, pages, dtype: DataType,
                     return None
                 rt = parse_runs(chunk, pos, end, pbw, n_present)
                 val_tabs.append(_shifted_tab(rt, present, n_present))
+                val_pages.append((rt, n_present))
         else:
             plain_dense_end.append(present + n_present)
             plain_pos.append(pos)
         rows += p.num_values
         present += n_present
-    has_def = max_def > 0
     cap_p = bucket_capacity(max(present, 1))
+    # every row present (a required column, or a nullable one whose def
+    # levels the host counted as all 1): no validity to expand, and the
+    # dense position is the row position
+    dense_is_rows = present == rows
+    def_form = _ONES if dense_is_rows else _RUNS
     def_tab = tuple(jnp.asarray(a) for a in _pack_flat_tabs(def_tabs)) \
-        if has_def else _EMPTY_TAB()
+        if def_form == _RUNS else _EMPTY_TAB()
     nums = np.asarray([num_rows, present], np.int32)
     if dict_mode:
         dp = dict_pages[0]
+        bw = int(bw or 1)
+        # the packed form reads nothing of the uploaded chunk (the
+        # dictionary page is read on the host too, so it has to lie
+        # inside the chunk, where the device's clipped read would not
+        # mind), so it is taken where the def levels need no chunk
+        # either: none goes up twice
+        dict_whole = dp.data_start + dp.num_values * \
+            np.dtype(npdt).itemsize <= len(chunk)
+        planes = _pack_value_stream(chunk, val_pages, bw, cap_p) \
+            if def_form == _ONES and dict_whole else None
+        if planes is not None:
+            val_form, val_tab = _PACKED, _EMPTY_TAB()
+            with OBS.span("scan.upload", bytes=planes.nbytes):
+                src = jnp.asarray(planes)
+        else:
+            val_form = _RUNS
+            val_tab = tuple(jnp.asarray(a)
+                            for a in _pack_flat_tabs(val_tabs))
+            src = upload()
+        OBS.annotate(expand=val_form)
         # host run table: only when the whole chunk is present (run
         # output offsets == row offsets — a nullable schema still
         # qualifies as long as no NULL actually occurs) and every value
@@ -1026,21 +1151,29 @@ def _try_flat_fixed(chunk: bytes, chunk_dev, pages, dtype: DataType,
                     chunk, dtype=np.dtype(npdt), count=dp.num_values,
                     offset=dp.data_start).astype(dtype.to_np())
                 d = DeviceDictionary.from_fixed_values(host_vals, dtype)
-                val_tab = tuple(jnp.asarray(a)
-                                for a in _pack_flat_tabs(val_tabs))
                 codes, validity = _flat_dict_codes_kernel(
-                    chunk_dev, def_tab, val_tab, int(bw or 1), cap,
-                    cap_p, has_def)
-                codes, validity = _flat_finish(codes, validity, nums, cap)
+                    src, def_tab, val_tab, bw, cap, cap_p, def_form,
+                    val_form)
+                codes, validity = _flat_finish(codes, validity, nums, cap,
+                                               dense_is_rows)
                 out = DictionaryColumn(dtype, codes, validity, d)
                 out.runs = runs  # run values ARE codes for encoded cols
                 return out
-        dict_vals = _bitcast_values(chunk_dev, np.int32(dp.data_start),
-                                    dp.num_values, np.dtype(npdt).name)
-        val_tab = tuple(jnp.asarray(a) for a in _pack_flat_tabs(val_tabs))
+        if val_form == _PACKED:
+            # the dictionary from the host's bytes too, padded to a
+            # bucket so that its length keys no program
+            host_dict = np.frombuffer(
+                chunk, dtype=np.dtype(npdt), count=dp.num_values,
+                offset=dp.data_start)
+            dict_vals = jnp.asarray(np.pad(
+                host_dict,
+                (0, bucket_capacity(dp.num_values) - dp.num_values)))
+        else:
+            dict_vals = _bitcast_values(src, np.int32(dp.data_start),
+                                        dp.num_values, np.dtype(npdt).name)
         dense, validity = _flat_dict_kernel(
-            chunk_dev, def_tab, val_tab, dict_vals, int(bw or 1), cap,
-            cap_p, has_def)
+            src, def_tab, val_tab, dict_vals, bw, cap, cap_p, def_form,
+            val_form)
         runs_out = None
         if runs is not None and dp.num_values:
             # decoded emission still benefits from runs: values via one
@@ -1053,7 +1186,8 @@ def _try_flat_fixed(chunk: bytes, chunk_dev, pages, dtype: DataType,
             sel = np.clip(runs.values, 0, dp.num_values - 1)
             runs_out = _RT(runs.starts,
                            host_vals[sel].astype(dtype.to_np()), num_rows)
-        data, validity = _flat_finish(dense, validity, nums, cap)
+        data, validity = _flat_finish(dense, validity, nums, cap,
+                                      dense_is_rows)
         out = ColumnVector(dtype, data, validity)
         out.runs = runs_out
         return out
@@ -1063,10 +1197,13 @@ def _try_flat_fixed(chunk: bytes, chunk_dev, pages, dtype: DataType,
         meta[1] = plain_pos
         if int(meta.max()) * np.dtype(npdt).itemsize < (1 << 31):
             meta = meta.astype(np.int32)
+        # PLAIN values are still gathered a lane (page lookup, byte
+        # gather): never `packed`, which is the gather-free form's name
+        OBS.annotate(expand="plain" if def_form == _ONES else _RUNS)
         dense, validity = _flat_plain_kernel(
-            chunk_dev, def_tab, meta, np.dtype(npdt).name, cap, cap_p,
-            has_def)
-    data, validity = _flat_finish(dense, validity, nums, cap)
+            upload(), def_tab, meta, np.dtype(npdt).name, cap, cap_p,
+            def_form)
+    data, validity = _flat_finish(dense, validity, nums, cap, dense_is_rows)
     return ColumnVector(dtype, data, validity)
 
 
@@ -1128,16 +1265,21 @@ def decode_chunk_device(chunk: bytes, dtype: DataType, num_rows: int,
     if is_dec_flba and not 1 <= flba_len <= 16:
         raise _Unsupported(f"FLBA decimal byte length {flba_len}")
     npdt = np.dtype(np.int32) if is_string else physical_np_dtype(dtype)
-    with OBS.span("scan.upload", bytes=len(chunk)):
-        chunk_dev = jnp.asarray(np.frombuffer(chunk, dtype=np.uint8))
+
+    @functools.cache
+    def upload():
+        """The chunk on the device, sent once, on first use."""
+        with OBS.span("scan.upload", bytes=len(chunk)):
+            return jnp.asarray(np.frombuffer(chunk, dtype=np.uint8))
 
     if not is_string and not is_dec_flba:
-        flat = _try_flat_fixed(chunk, chunk_dev, pages, dtype, num_rows,
+        flat = _try_flat_fixed(chunk, upload, pages, dtype, num_rows,
                                max_def, cap, npdt,
                                encoded_ok=encoded_ok,
                                max_dict_fraction=max_dict_fraction)
         if flat is not None:
             return flat
+    chunk_dev = upload()
 
     dict_vals = None          # fixed-width dictionary values (device)
     str_dict = None           # (bytes_dev, offs_dev, lens_dev) for strings

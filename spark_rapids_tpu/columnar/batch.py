@@ -398,7 +398,18 @@ class HostColumnarBatch:
         GpuDeviceManager.scala:200-206). Per-dtype rather than one uint8
         buffer because a device-side u8[n, itemsize] bitcast pads the
         minor dim to the 128-lane tile on TPU — a 32x HBM blowup that
-        OOMed real-chip uploads at 64M rows."""
+        OOMed real-chip uploads at 64M rows.
+
+        Two steps, for a caller that can run the first ahead of its
+        admission permit (the device parquet scan's host half,
+        io/scan.py): `stage_upload()` is host work on host data,
+        `StagedUpload.upload()` puts it on the device."""
+        return self.stage_upload().upload()
+
+    def stage_upload(self) -> "StagedUpload":
+        """The host half of `to_device`: nulls zeroed, columns padded to
+        the capacity bucket and packed into one host buffer a dtype. No
+        jax call, no device state."""
         from spark_rapids_tpu.columnar.encoded import HostDictionaryColumn
 
         n = self.num_rows
@@ -415,7 +426,7 @@ class HostColumnarBatch:
                 codes[:n] = np.where(hc.validity[:n], hc.data[:n], 0)
                 parts.append(("int32", codes, False))
                 parts.append(("uint8", validity.view(np.uint8), True))
-                specs.append(("dict", hc.dictionary))
+                specs.append(("dict", hc.dtype, hc.dictionary))
             elif hc.dtype is DataType.STRING:
                 encoded = [
                     s.encode("utf-8") if isinstance(s, str) else bytes(s)
@@ -453,12 +464,36 @@ class HostColumnarBatch:
                 parts.append(("uint8", validity.view(np.uint8), True))
                 specs.append(("fixed", hc.dtype,
                               host_value_range(hc.dtype, data[:n])))
-        if not parts:
+        return StagedUpload(n, specs, *_group_parts(parts))
+
+
+class StagedUpload:
+    """A HostColumnarBatch packed for its upload (`stage_upload`): one
+    host buffer a dtype, where each column's segments lie in them, and
+    what kind of column each is. Host data only until `upload()`."""
+
+    __slots__ = ("num_rows", "specs", "bufs", "layout")
+
+    def __init__(self, num_rows: int, specs: list, bufs: tuple,
+                 layout: tuple):
+        self.num_rows = num_rows
+        self.specs = specs
+        self.bufs = bufs
+        self.layout = layout
+
+    def upload(self) -> "ColumnarBatch":
+        """The device half of `to_device`: one transfer a dtype group,
+        then the segments sliced back out in one jitted program. No
+        device-side bitcasts: u8[n, itemsize] bitcasting pads the minor
+        dim to the 128-lane tile on TPU (32x HBM)."""
+        n = self.num_rows
+        if not self.layout:
             return ColumnarBatch([], n, owned=True)
-        arrays = _upload_grouped(parts)
+        arrays = _slice_grouped(tuple(jnp.asarray(b) for b in self.bufs),
+                                self.layout)
         cols = []
         ai = 0
-        for hc, spec in zip(self.columns, specs):
+        for spec in self.specs:
             if spec[0] == "string":
                 offsets, buf, validity = arrays[ai], arrays[ai + 1], \
                     arrays[ai + 2]
@@ -472,12 +507,12 @@ class HostColumnarBatch:
 
                 data, validity = arrays[ai], arrays[ai + 1]
                 ai += 2
-                cols.append(DictionaryColumn(hc.dtype, data, validity,
-                                             spec[1]))
+                cols.append(DictionaryColumn(spec[1], data, validity,
+                                             spec[2]))
             else:
                 data, validity = arrays[ai], arrays[ai + 1]
                 ai += 2
-                cols.append(ColumnVector(hc.dtype, data, validity,
+                cols.append(ColumnVector(spec[1], data, validity,
                                          vrange=spec[2]))
         # a fresh upload is consume-once by construction (donation-eligible
         # until some path stores it for re-read and clears the flag)
@@ -747,22 +782,21 @@ def to_host_many(batches: Sequence["ColumnarBatch"],
 # ---------------------------------------------------------------------------
 # Packed transfer helpers (one host<->device copy per batch)
 # ---------------------------------------------------------------------------
-def _upload_grouped(parts):
-    """Upload (group, np_seg, want_bool) parts with one host concatenate +
-    one device transfer PER DTYPE GROUP, then slice each segment back out
-    in one jitted program. No device-side bitcasts: u8[n, itemsize]
-    bitcasting pads the minor dim to the 128-lane tile on TPU (32x HBM)."""
+def _group_parts(parts):
+    """(group, np_seg, want_bool) parts as one host concatenate PER DTYPE
+    GROUP and the layout that slices each segment back out of them:
+    (buffers, ((buffer index, start, count, want_bool), ...))."""
     order: dict = {}
     for gname, seg, _want in parts:
         order.setdefault(gname, []).append(seg)
     keys = tuple(sorted(order))
-    bufs = tuple(jnp.asarray(np.concatenate(order[k])) for k in keys)
+    bufs = tuple(np.concatenate(order[k]) for k in keys)
     layout = []
     offs = {k: 0 for k in keys}
     for gname, seg, want in parts:
         layout.append((keys.index(gname), offs[gname], seg.shape[0], want))
         offs[gname] += seg.shape[0]
-    return _slice_grouped(bufs, tuple(layout))
+    return bufs, tuple(layout)
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
@@ -777,7 +811,8 @@ def _slice_grouped(bufs, layout):
 @jax.jit
 def _download_grouped(arrays):
     """Concatenate arrays into one buffer per dtype for the host transfer
-    (the download mirror of _upload_grouped; bools ride as uint8)."""
+    (the download mirror of `_group_parts` + `StagedUpload.upload`; bools
+    ride as uint8)."""
     order: dict = {}
     for i, a in enumerate(arrays):
         a = a.astype(jnp.uint8) if a.dtype == jnp.bool_ else a

@@ -103,11 +103,11 @@ def arrow_to_host_batch(table: pa.Table,
             data = _decimal_unscaled(arr, dt, validity)
         else:
             npdt = dt.to_np()
-            if dt is DataType.BOOL:
-                data = arr.fill_null(False).to_numpy(zero_copy_only=False)
-            else:
-                data = arr.fill_null(npdt.type(0).item()) \
-                    .to_numpy(zero_copy_only=False)
+            if arr.null_count:
+                # (fill_null copies the column even where none is null)
+                arr = arr.fill_null(False if dt is DataType.BOOL
+                                    else npdt.type(0).item())
+            data = arr.to_numpy(zero_copy_only=False)
             if data.dtype != npdt:
                 data = data.astype(npdt)
         cols.append(HostColumnVector(dt, data, validity))

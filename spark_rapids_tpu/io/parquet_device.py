@@ -321,6 +321,10 @@ def normalize_chunk(chunk: bytes, codec: str):
     dec = _get_codec(codec)
     if dec is None:
         raise _Unsupported(f"codec {codec}")
+    if codec == "SNAPPY":
+        native = _normalize_snappy_native(chunk, pages)
+        if native is not None:
+            return native
     out = bytearray()
     new_pages = []
     from dataclasses import replace as _replace
@@ -343,6 +347,50 @@ def normalize_chunk(chunk: bytes, codec: str):
                                   data_len=len(new_payload),
                                   uncompressed_len=len(new_payload),
                                   data_compressed=False))
+    return bytes(out), new_pages
+
+
+def _normalize_snappy_native(chunk: bytes, pages: List[PageInfo]):
+    """normalize_chunk's page loop for a SNAPPY chunk of v1 and dictionary
+    pages as ONE native call (native/srt_native.cpp srt_snappy_pages), or
+    None where the library is not built, a page is v2, or a page does not
+    decompress to its header's size (the per-page loop then says what
+    Arrow's codec makes of it). One call a chunk matters beside other
+    threads: every call that leaves the interpreter hands its lock over,
+    and on a busy host each hand-over waits its turn behind the other
+    threads (PERF.md, PR 29: 0.7 ms a chunk alone, 33 ms a chunk with
+    eight scans side by side, a page's `decompress` a hand-over each)."""
+    import ctypes
+    from dataclasses import replace as _replace
+
+    from spark_rapids_tpu.native import get_lib
+
+    lib = get_lib()
+    if lib is None or any(p.kind == PAGE_DATA_V2 for p in pages):
+        return None
+    n = len(pages)
+    src_off = np.fromiter((p.data_start for p in pages), np.int64, n)
+    src_len = np.fromiter((p.data_len for p in pages), np.int64, n)
+    dst_len = np.fromiter(
+        (0 if p.data_len == 0 else
+         p.uncompressed_len if p.uncompressed_len >= 0 else p.data_len
+         for p in pages), np.int64, n)
+    dst_off = np.zeros(n, np.int64)
+    np.cumsum(dst_len[:-1], out=dst_off[1:])
+    total = int(dst_len.sum())
+    out = bytearray(total)
+    i64 = ctypes.POINTER(ctypes.c_int64)
+    rc = lib.srt_snappy_pages(
+        chunk, len(chunk), n, src_off.ctypes.data_as(i64),
+        src_len.ctypes.data_as(i64), dst_off.ctypes.data_as(i64),
+        dst_len.ctypes.data_as(i64),
+        (ctypes.c_uint8 * total).from_buffer(out), total)
+    if rc != 0:
+        return None
+    new_pages = [
+        _replace(p, data_start=int(dst_off[i]), data_len=int(dst_len[i]),
+                 uncompressed_len=int(dst_len[i]), data_compressed=False)
+        for i, p in enumerate(pages)]
     return bytes(out), new_pages
 
 
@@ -990,10 +1038,35 @@ def _flat_finish(dense, validity, nums, cap: int, dense_is_rows: bool):
 _FIXED_ENC_DTYPES = (DataType.INT64, DataType.DATE, DataType.TIMESTAMP)
 
 
+@dataclass
+class _FlatPlan:
+    """What the host reads off a fixed-width chunk's pages for the
+    whole-chunk decode (`_plan_flat_fixed`): host arrays only, so the scan
+    may make it ahead of the admission permit (io/scan.py). Every table is
+    padded and packed as the programs take it; `_issue_flat_fixed` only
+    uploads and dispatches."""
+
+    cap_p: int                 # capacity bucket of the present values
+    nums: np.ndarray           # int32 [num_rows, present]
+    dense_is_rows: bool        # every row present: nothing to spread
+    def_form: str              # _ONES | _RUNS
+    def_tab: Optional[tuple]   # packed run table (numpy), _RUNS only
+    dict_page: Optional[PageInfo] = None   # dictionary chunks
+    bw: int = 1
+    val_form: str = _RUNS      # _PACKED | _RUNS
+    planes: Optional[np.ndarray] = None    # _PACKED: the payload's planes
+    val_tab: Optional[tuple] = None        # _RUNS: packed run table
+    runs: object = None        # host RunTable of an all-RLE value stream
+    plain_meta: Optional[np.ndarray] = None  # PLAIN chunks: page table
+
+
+_UNPLANNED = object()  # decode_chunk_device(flat=...): plan it here
+
+
 def _try_flat_fixed(chunk: bytes, upload, pages, dtype: DataType,
                     num_rows: int, max_def: int, cap: int, npdt,
                     encoded_ok: bool = False,
-                    max_dict_fraction: float = 1.0):
+                    max_dict_fraction: float = 1.0, plan=_UNPLANNED):
     """Whole-chunk fixed-width decode with ZERO per-page device work:
     host computes every page's present count (bit-popcount over def-level
     bytes), all pages' run tables concatenate into one flat table (output
@@ -1001,6 +1074,11 @@ def _try_flat_fixed(chunk: bytes, upload, pages, dtype: DataType,
     and 2-3 jitted dispatches decode the entire chunk. Returns a
     ColumnVector, or None when the chunk's shape needs the general
     per-page path (mixed/exotic encodings, strings, bools, FLBA).
+
+    Two steps: `_plan_flat_fixed` is the host's (pages walked, run tables
+    built, the packed payload copied: no jax call) and may have been run
+    ahead by the caller (`plan`, from `stage_chunk`); `_issue_flat_fixed`
+    uploads what the plan holds and dispatches the programs.
 
     What the run tables state is not recomputed on the device: definition
     levels the host counted as all present are not expanded and nothing
@@ -1028,7 +1106,19 @@ def _try_flat_fixed(chunk: bytes, upload, pages, dtype: DataType,
     (GpuParquetScan.scala:536-556); round 4's per-page loop paid one
     sync + ~9 eager dispatches per page
     (tools/decode_census.py: 648 syncs + 6015 eager ops per iteration)."""
-    from spark_rapids_tpu.columnar.batch import ColumnVector
+    if plan is _UNPLANNED:
+        plan = _plan_flat_fixed(chunk, pages, dtype, num_rows, max_def,
+                                npdt)
+    if plan is None:
+        return None
+    return _issue_flat_fixed(plan, chunk, upload, dtype, num_rows, cap, npdt,
+                             encoded_ok, max_dict_fraction)
+
+
+def _plan_flat_fixed(chunk: bytes, pages, dtype: DataType, num_rows: int,
+                     max_def: int, npdt) -> Optional[_FlatPlan]:
+    """HOST step of `_try_flat_fixed`; None where the chunk needs the
+    per-page path."""
     from spark_rapids_tpu.columnar.dtypes import is_decimal
 
     if dtype in (DataType.STRING, DataType.BOOL):
@@ -1101,43 +1191,71 @@ def _try_flat_fixed(chunk: bytes, upload, pages, dtype: DataType,
             plain_pos.append(pos)
         rows += p.num_values
         present += n_present
-    cap_p = bucket_capacity(max(present, 1))
     # every row present (a required column, or a nullable one whose def
     # levels the host counted as all 1): no validity to expand, and the
     # dense position is the row position
     dense_is_rows = present == rows
     def_form = _ONES if dense_is_rows else _RUNS
-    def_tab = tuple(jnp.asarray(a) for a in _pack_flat_tabs(def_tabs)) \
+    plan = _FlatPlan(
+        cap_p=bucket_capacity(max(present, 1)),
+        nums=np.asarray([num_rows, present], np.int32),
+        dense_is_rows=dense_is_rows, def_form=def_form,
+        def_tab=_pack_flat_tabs(def_tabs) if def_form == _RUNS else None)
+    if not dict_mode:
+        meta = np.zeros((2, len(plain_pos)), np.int64)
+        meta[0] = plain_dense_end
+        meta[1] = plain_pos
+        if int(meta.max()) * np.dtype(npdt).itemsize < (1 << 31):
+            meta = meta.astype(np.int32)
+        plan.plain_meta = meta
+        return plan
+    dp = plan.dict_page = dict_pages[0]
+    plan.bw = int(bw or 1)
+    # the packed form reads nothing of the uploaded chunk (the
+    # dictionary page is read on the host too, so it has to lie
+    # inside the chunk, where the device's clipped read would not
+    # mind), so it is taken where the def levels need no chunk
+    # either: none goes up twice
+    dict_whole = dp.data_start + dp.num_values * \
+        np.dtype(npdt).itemsize <= len(chunk)
+    if def_form == _ONES and dict_whole:
+        plan.planes = _pack_value_stream(chunk, val_pages, plan.bw,
+                                         plan.cap_p)
+    if plan.planes is not None:
+        plan.val_form = _PACKED
+    else:
+        plan.val_tab = _pack_flat_tabs(val_tabs)
+    # host run table: only when the whole chunk is present (run
+    # output offsets == row offsets — a nullable schema still
+    # qualifies as long as no NULL actually occurs) and every value
+    # run is RLE
+    if dense_is_rows:
+        plan.runs = _rle_run_table(val_tabs, num_rows)
+    return plan
+
+
+def _issue_flat_fixed(plan: _FlatPlan, chunk: bytes, upload,
+                      dtype: DataType, num_rows: int, cap: int, npdt,
+                      encoded_ok: bool, max_dict_fraction: float):
+    """DEVICE step of `_try_flat_fixed`: the plan's tables and payload
+    uploaded, the chunk's programs dispatched."""
+    from spark_rapids_tpu.columnar.batch import ColumnVector
+
+    cap_p, nums, dense_is_rows = plan.cap_p, plan.nums, plan.dense_is_rows
+    def_form, val_form, bw = plan.def_form, plan.val_form, plan.bw
+    def_tab = tuple(jnp.asarray(a) for a in plan.def_tab) \
         if def_form == _RUNS else _EMPTY_TAB()
-    nums = np.asarray([num_rows, present], np.int32)
-    if dict_mode:
-        dp = dict_pages[0]
-        bw = int(bw or 1)
-        # the packed form reads nothing of the uploaded chunk (the
-        # dictionary page is read on the host too, so it has to lie
-        # inside the chunk, where the device's clipped read would not
-        # mind), so it is taken where the def levels need no chunk
-        # either: none goes up twice
-        dict_whole = dp.data_start + dp.num_values * \
-            np.dtype(npdt).itemsize <= len(chunk)
-        planes = _pack_value_stream(chunk, val_pages, bw, cap_p) \
-            if def_form == _ONES and dict_whole else None
-        if planes is not None:
-            val_form, val_tab = _PACKED, _EMPTY_TAB()
-            with OBS.span("scan.upload", bytes=planes.nbytes):
-                src = jnp.asarray(planes)
+    if plan.dict_page is not None:
+        dp = plan.dict_page
+        if val_form == _PACKED:
+            val_tab = _EMPTY_TAB()
+            with OBS.span("scan.upload", bytes=plan.planes.nbytes):
+                src = jnp.asarray(plan.planes)
         else:
-            val_form = _RUNS
-            val_tab = tuple(jnp.asarray(a)
-                            for a in _pack_flat_tabs(val_tabs))
+            val_tab = tuple(jnp.asarray(a) for a in plan.val_tab)
             src = upload()
         OBS.annotate(expand=val_form)
-        # host run table: only when the whole chunk is present (run
-        # output offsets == row offsets — a nullable schema still
-        # qualifies as long as no NULL actually occurs) and every value
-        # run is RLE
-        runs = _rle_run_table(val_tabs, num_rows) if present == rows \
-            else None
+        runs = plan.runs
         if encoded_ok and dtype in _FIXED_ENC_DTYPES:
             from spark_rapids_tpu.columnar.encoded import (
                 DeviceDictionary,
@@ -1191,18 +1309,12 @@ def _try_flat_fixed(chunk: bytes, upload, pages, dtype: DataType,
         out = ColumnVector(dtype, data, validity)
         out.runs = runs_out
         return out
-    else:
-        meta = np.zeros((2, len(plain_pos)), np.int64)
-        meta[0] = plain_dense_end
-        meta[1] = plain_pos
-        if int(meta.max()) * np.dtype(npdt).itemsize < (1 << 31):
-            meta = meta.astype(np.int32)
-        # PLAIN values are still gathered a lane (page lookup, byte
-        # gather): never `packed`, which is the gather-free form's name
-        OBS.annotate(expand="plain" if def_form == _ONES else _RUNS)
-        dense, validity = _flat_plain_kernel(
-            upload(), def_tab, meta, np.dtype(npdt).name, cap, cap_p,
-            def_form)
+    # PLAIN values are still gathered a lane (page lookup, byte
+    # gather): never `packed`, which is the gather-free form's name
+    OBS.annotate(expand="plain" if def_form == _ONES else _RUNS)
+    dense, validity = _flat_plain_kernel(
+        upload(), def_tab, plan.plain_meta, np.dtype(npdt).name, cap, cap_p,
+        def_form)
     data, validity = _flat_finish(dense, validity, nums, cap, dense_is_rows)
     return ColumnVector(dtype, data, validity)
 
@@ -1223,11 +1335,45 @@ def _EMPTY_TAB():
     return _EMPTY_TAB_CACHE
 
 
+def stage_chunk(chunk: bytes, codec: str, dtype: Optional[DataType] = None,
+                num_rows: int = 0, max_def: int = 0, flba_len: int = 0):
+    """Host half of `decode_chunk_device`: a raw column chunk's pages
+    decompressed and their headers walked, and, where the caller says
+    what the column is (`dtype`, `num_rows`, `max_def`, `flba_len`), the
+    whole-chunk decode planned (`_plan_flat_fixed`: run tables, present
+    counts, the packed payload). Returns (normalised chunk bytes, pages
+    with offsets into them, the plan: None where the chunk needs the
+    per-page loop, `_UNPLANNED` without a `dtype`). Pure host work on
+    host data: the scan runs it ahead of the admission permit
+    (io/scan.py: `_stage_split`) and hands the three to
+    `decode_chunk_device(pages=..., flat=...)`. Raises _Unsupported for a
+    codec or page type outside scope."""
+    from spark_rapids_tpu.columnar.dtypes import is_decimal
+
+    with OBS.span("scan.parse") as sp:
+        if codec != "UNCOMPRESSED":
+            chunk, pages = normalize_chunk(chunk, codec)
+        else:
+            pages = parse_pages(chunk)
+        if sp is not None:
+            sp.attrs["bytes_out"] = len(chunk)
+        if dtype is None:
+            flat = _UNPLANNED
+        elif is_decimal(dtype) and flba_len > 0:
+            flat = None  # FLBA decimals fold on the per-page loop
+        else:
+            flat = _plan_flat_fixed(chunk, pages, dtype, num_rows, max_def,
+                                    physical_np_dtype(dtype))
+    return chunk, pages, flat
+
+
 def decode_chunk_device(chunk: bytes, dtype: DataType, num_rows: int,
                         max_def: int, cap: Optional[int] = None,
                         codec: str = "UNCOMPRESSED", flba_len: int = 0,
                         encoded_ok: bool = False,
-                        max_dict_fraction: float = 1.0):
+                        max_dict_fraction: float = 1.0,
+                        pages: Optional[List[PageInfo]] = None,
+                        flat=_UNPLANNED):
     """Decode one raw column chunk into a device ColumnVector.
 
     Fixed-width columns: PLAIN / dictionary pages, v1 or v2. STRING
@@ -1242,17 +1388,14 @@ def decode_chunk_device(chunk: bytes, dtype: DataType, num_rows: int,
     the host first (normalize_chunk); the device data plane is identical.
 
     max_def: 1 for nullable columns (def levels present), 0 for required.
-    Raises _Unsupported for shapes outside scope (caller falls back to the
-    Arrow host path)."""
+    `pages`, `flat`: `stage_chunk`'s, where the caller ran it ahead;
+    `chunk` is then the normalised bytes they index, and `codec` only
+    names what the file held. Raises _Unsupported for shapes outside
+    scope (caller falls back to the Arrow host path)."""
     from spark_rapids_tpu.columnar.batch import ColumnVector
 
-    with OBS.span("scan.parse") as sp:
-        if codec != "UNCOMPRESSED":
-            chunk, pages = normalize_chunk(chunk, codec)
-        else:
-            pages = parse_pages(chunk)
-        if sp is not None:
-            sp.attrs["bytes_out"] = len(chunk)
+    if pages is None:
+        chunk, pages, flat = stage_chunk(chunk, codec)
     OBS.annotate(pages=len(pages))  # on the caller's scan.decode
     from spark_rapids_tpu.columnar.dtypes import is_decimal
 
@@ -1276,7 +1419,8 @@ def decode_chunk_device(chunk: bytes, dtype: DataType, num_rows: int,
         flat = _try_flat_fixed(chunk, upload, pages, dtype, num_rows,
                                max_def, cap, npdt,
                                encoded_ok=encoded_ok,
-                               max_dict_fraction=max_dict_fraction)
+                               max_dict_fraction=max_dict_fraction,
+                               plan=flat)
         if flat is not None:
             return flat
     chunk_dev = upload()

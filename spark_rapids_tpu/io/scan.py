@@ -296,13 +296,15 @@ def plan_splits(fmt: str, paths: List[str], options: Dict[str, Any],
     return splits
 
 
-def read_split(split: FileSplit,
-               attrs: List[AttributeReference]) -> pa.Table:
+def read_split(split: FileSplit, attrs: List[AttributeReference],
+               pf=None) -> pa.Table:
+    """The split's rows of `attrs` as one Arrow table (`pf`: the split's
+    parquet file where the caller has it open already)."""
     names = [a.name for a in attrs]
     if split.fmt == "parquet":
         import pyarrow.parquet as pq
 
-        pf = pq.ParquetFile(split.path)
+        pf = pf or pq.ParquetFile(split.path)
         groups = list(split.row_groups) if split.row_groups is not None \
             else list(range(pf.metadata.num_row_groups))
         return pf.read_row_groups(groups, columns=names)
@@ -407,14 +409,13 @@ class _FileScanBase(PhysicalExec):
     def node_name(self):
         return f"{type(self).__name__}({self.fmt}, {len(self.splits)} splits)"
 
-    def _read_host_iter(self, pidx: int, conf):
+    def _read_host_iter(self, split: FileSplit, conf):
         """Generator form of the host decode: the Arrow read runs on first
         pull, so a prefetch wrapper (io/prefetch.py) moves the WHOLE decode
         onto its worker thread — batch k+1 of the query decodes while
         batch k computes downstream."""
         from spark_rapids_tpu import conf as C
 
-        split = self.splits[pidx]
         pv = dict(split.partition_values)
         data_attrs = [a for a in self.attrs if a.name not in pv]
         table = read_split(split, data_attrs)
@@ -430,14 +431,13 @@ class _FileScanBase(PhysicalExec):
         for i in range(0, batch.num_rows, max_rows):
             yield batch.slice(i, max_rows)
 
-    def _host_batches_prefetched(self, pidx: int, conf):
+    def _host_batches_prefetched(self, split: FileSplit, conf):
         """Host decode iterator with the configured double-buffering depth
         (rapids.tpu.io.prefetchBatches; per-read option overrides)."""
         from spark_rapids_tpu.io.prefetch import maybe_prefetch, prefetch_depth
 
-        return maybe_prefetch(
-            self._read_host_iter(pidx, conf),
-            prefetch_depth(conf, self.splits[pidx]))
+        return maybe_prefetch(self._read_host_iter(split, conf),
+                              prefetch_depth(conf, split))
 
 
 class CpuFileScanExec(_FileScanBase, CpuExec):
@@ -447,7 +447,7 @@ class CpuFileScanExec(_FileScanBase, CpuExec):
         def factory(pidx: int):
             return count_output(
                 self.metrics,
-                self._host_batches_prefetched(pidx, ctx.conf))
+                self._host_batches_prefetched(self.splits[pidx], ctx.conf))
 
         return PartitionedBatches(len(self.splits), factory)
 
@@ -458,7 +458,21 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
     GpuParquetScan.scala:536-556); everything else host-decodes via Arrow
     and uploads. The admission semaphore is acquired exactly where the
     reference acquires it: before bytes go on the device
-    (GpuParquetScan.scala:554)."""
+    (GpuParquetScan.scala:554).
+
+    Neither parquet path holds a permit through host work on host data.
+    The host decoder's split is one Arrow read on the scan prefetcher's
+    reader thread (io/prefetch.py, `rapids.tpu.io.prefetchBatches`;
+    `_read_host_iter` / `to_device`). The device decoder's split is
+    staged on the task thread — the footer, each chunk's read,
+    decompression and page walk, Arrow's decode of the columns the device
+    decoder does not take — before the task asks for the permit
+    (`_stage_split`), and only uploads and program issue run under it
+    (`_decode_staged`). A reader thread a task was tried there and lost
+    to this order on the chip's host, where the interpreter's lock, not
+    the cores, bounds the host half (PERF.md section 6, PR 29). Staging
+    is host memory only: a split's staged row groups, as the host path
+    holds a split's Arrow table."""
 
     placement = "tpu"
 
@@ -474,17 +488,16 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
             from spark_rapids_tpu.engine.retry import with_retry
 
             def gen():
+                if device_decode:
+                    # a generator over the split's row groups; False where
+                    # no column qualified, and nothing was yielded
+                    if (yield from self._read_device(self.splits[pidx],
+                                                     ctx.conf)):
+                        return
                 # device decodes are pure over (split bytes, conf): a
                 # retryable OOM/transient error re-reads and re-decodes the
                 # split after the spill (with_retry); exhaustion propagates
                 # for task retry / query-level CPU fallback
-                if device_decode:
-                    batches = with_retry(
-                        lambda: self._read_device(self.splits[pidx],
-                                                  ctx.conf), site="scan")
-                    if batches is not None:
-                        yield from batches
-                        return
                 if device_csv:
                     batches = with_retry(
                         lambda: self._read_device_csv(self.splits[pidx],
@@ -501,18 +514,22 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
                     if batches is not None:
                         yield from batches
                         return
-                # host path: decode double-buffers on the prefetch worker;
-                # the upload ISSUES here (asynchronously — jax returns an
-                # unblocked device future) under this task's admission
-                # permit, so batch k+1's decode and upload overlap batch
-                # k's downstream compute
-                for hb in self._host_batches_prefetched(pidx, ctx.conf):
-                    TpuSemaphore.get().acquire_if_necessary(current_task_id())
-                    yield with_retry(lambda: hb.to_device(), site="scan")
+                yield from self._read_host(self.splits[pidx], ctx.conf)
 
             return count_output(self.metrics, gen())
 
         return PartitionedBatches(len(self.splits), factory)
+
+    def _read_host(self, split: FileSplit, conf):
+        """Host path: decode double-buffers on the prefetch worker; the
+        upload ISSUES here (asynchronously — jax returns an unblocked
+        device future) under this task's admission permit, so batch k+1's
+        decode and upload overlap batch k's downstream compute."""
+        from spark_rapids_tpu.engine.retry import with_retry
+
+        for hb in self._host_batches_prefetched(split, conf):
+            TpuSemaphore.get().acquire_if_necessary(current_task_id())
+            yield with_retry(lambda: hb.to_device(), site="scan")
 
     def _read_device_csv(self, split: FileSplit, conf):
         """Device CSV parse for one split; None -> structure/columns not
@@ -796,7 +813,29 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
         """Combine device-decoded columns with a host-decoded partial batch
         (+ partition-value columns) into output batches, sliced to
         MAX_READ_BATCH_SIZE_ROWS. Shared by the parquet and CSV device read
-        paths — their mixed-batch assembly must never diverge."""
+        paths — their mixed-batch assembly must never diverge. Two steps,
+        for the parquet scan to run the first ahead of its permit."""
+        return self._assemble_staged(
+            dev_cols, self._stage_host_part(hb, rest, pv, rows), rest, pv,
+            rows, conf)
+
+    def _stage_host_part(self, hb, rest, pv, rows):
+        """HOST step of the assembly: the host-decoded columns, with the
+        partition-value columns beside them, packed for their upload
+        (`HostColumnarBatch.stage_upload`); None where the batch has
+        neither."""
+        if hb is None and pv:
+            hb = HostColumnarBatch([], rows)
+        if hb is None:
+            return None
+        if pv:
+            hb = _with_partition_columns(
+                hb, rest + [a for a in self.attrs if a.name in pv], pv)
+        return hb.stage_upload()
+
+    def _assemble_staged(self, dev_cols, staged, rest, pv, rows, conf):
+        """DEVICE step of the assembly: the staged host columns uploaded
+        and set beside the device-decoded ones."""
         from spark_rapids_tpu import conf as C2
         from spark_rapids_tpu.columnar.batch import (
             ColumnarBatch,
@@ -805,19 +844,14 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
 
         host_part = None
         host_names: List[str] = []
-        if hb is None and pv:
-            hb = HostColumnarBatch([], rows)
-        if hb is not None:
-            if pv:
-                hb = _with_partition_columns(
-                    hb, rest + [a for a in self.attrs if a.name in pv], pv)
+        if staged is not None:
             host_names = [a.name for a in rest] + \
                 [a.name for a in self.attrs if a.name in pv]
             # the host-decoded columns go up at their full width (the
             # device decoder's chunks go up compressed-size, in
             # io/parquet_device.py, under the same span name)
             with obs_span("scan.upload", columns=len(host_names)) as sp:
-                host_part = hb.to_device()
+                host_part = staged.upload()
                 if sp is not None:
                     sp.attrs["bytes"] = host_part.device_memory_size()
         cols = []
@@ -973,133 +1007,253 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
         return out
 
     def _read_device(self, split: FileSplit, conf):
-        """Device decode for one split; None -> no column qualified (caller
-        uses the host path). Mixed batches combine device-decoded columns
-        with host-decoded/partition-value columns at the same capacity.
+        """Device decode for one split: a generator of its batches, one row
+        group at a time, that returns False where no column qualified
+        (nothing was yielded; the caller uses the host path) and True
+        otherwise. Mixed batches combine device-decoded columns with
+        host-decoded/partition-value columns at the same capacity.
 
-        Spans (docs/observability.md): `scan.split` for the split's
-        planning (footer, schema maps, eligibility), then per row group,
-        as SIBLINGS of it, the admission wait and `scan.rowgroup` > per
-        column `scan.read`, `scan.decode` (> `scan.parse`, `scan.upload`,
-        in io/parquet_device.py), `scan.host_decode` and `scan.upload`
-        for the columns Arrow decodes. The wait is a sibling so that a
-        task queued in `Acquire TPU Semaphore` sits shallower in the tree
-        than any step of the task that holds the permit — its scan, and
-        the sink's `DeviceToHost` further down the same task."""
+        Two halves, both on the task thread. The HOST half
+        (`_stage_split`) stages the whole split — host work on host data —
+        BEFORE the task asks for its admission permit, so the tasks that
+        have no permit yet do their host work side by side with the ones
+        that hold one, and a permit is never held through a read, a page
+        walk or Arrow's decode. The DEVICE half (`_decode_staged`) then
+        runs under the permit, a row group at a time: where the first
+        byte goes onto the device, and not before. A staged item is host
+        data only, so a retryable device error re-issues the device half
+        of that row group from it (with_retry). It yields a batch a row
+        group instead of returning the split's list: no more of a split
+        than the row group in flight is held on the device, and a staged
+        row group's host buffers go as the task takes the next.
+
+        A page shape outside the device decoder's scope (`_Unsupported`)
+        sends what is LEFT of the split — from the host half the whole
+        split, from the device half the row groups not yet gone
+        downstream, so each row exactly once — through the host decoder,
+        and the query's cpuFallbackEvents says so, once a split. Anything
+        else the decoder raises (a compiler or runtime error of the
+        device) propagates to with_retry and the query, like any other
+        operator's.
+
+        Spans (docs/observability.md), all siblings under the task: from
+        the host half `scan.split` (footer, schema maps, eligibility),
+        per (row group, column) `scan.read` > `scan.parse`, per row group
+        `scan.host_decode`; from the device half the admission wait and
+        per row group `scan.rowgroup` > per column `scan.decode` >
+        `scan.upload`, and `scan.upload` for the columns Arrow decoded.
+        The wait is a sibling so that a task queued in `Acquire TPU
+        Semaphore` sits shallower in the tree than any step of the task
+        that holds the permit — its device half, and the sink's
+        `DeviceToHost` further down the same task. No span stays open
+        across a `yield`."""
+        from collections import deque
+
+        from spark_rapids_tpu.engine.retry import with_retry
+        from spark_rapids_tpu.io import parquet_device as PD
+
+        plan = _SplitPlan(split, dict(split.partition_values))
+        refused = None
+        try:
+            items = deque(self._stage_split(plan))
+        except PD._Unsupported as e:
+            refused, items = e, None
+        if refused is None and not plan.eligible:
+            return False
+        while items:
+            TpuSemaphore.get().acquire_if_necessary(current_task_id())
+            item = items.popleft()
+            try:
+                batches = with_retry(
+                    lambda: self._decode_staged(plan, item, conf),
+                    site="scan")
+            except PD._Unsupported as e:
+                refused = e
+                break
+            yield from batches
+            plan.done += 1
+        if refused is None:
+            return True
+        import logging
+
+        items = None  # what was staged of the rest is the host decoder's
+
+        M.record_cpu_fallback()
+        if plan.span is not None:
+            plan.span.attrs["fallback"] = str(refused)
+        logging.getLogger(__name__).warning(
+            "device parquet decode refused a column of %s (%s); row groups "
+            "%s of the split are decoded on the host", split.path, refused,
+            plan.groups[plan.done:])
+        yield from self._read_host(
+            FileSplit(split.path, "parquet", tuple(plan.groups[plan.done:]),
+                      split.options, split.partition_values), conf)
+        return True
+
+    def _stage_split(self, plan: "_SplitPlan"):
+        """HOST half of the device decode: a generator of one
+        `_StagedRowGroup` a row group of `plan.split`. It touches no
+        device state, takes no permit and makes no jax call (but to ask
+        which backend there is: how wide a DOUBLE goes up), so a task
+        runs it without a permit: the footer work (filling `plan` before
+        the first item), per device-eligible column chunk its bytes read,
+        decompressed, page-walked and its decode planned
+        (`PD.stage_chunk`), and for the `rest` columns Arrow's decode and
+        their packing for the upload (`_stage_host_part`). Yields nothing
+        where no column qualified (`plan.eligible` is empty)."""
         import pyarrow.parquet as pq
 
         from spark_rapids_tpu.io import parquet_device as PD
-        from spark_rapids_tpu.io.arrow_convert import arrow_to_host_batch
 
-        from spark_rapids_tpu import conf as C3
-        from spark_rapids_tpu.columnar import encoded as ENC
-
-        encoded_ok = conf.get(C3.ENCODED_ENABLED)
-        fixed_ok = encoded_ok and conf.get(C3.ENCODED_FIXED_DICTIONARIES)
-        max_frac = conf.get(C3.ENCODED_MAX_DICT_FRACTION)
-        pv = dict(split.partition_values)
-        data_attrs = [a for a in self.attrs if a.name not in pv]
-        with obs_span("scan.split", path=split.path) as split_span:
+        split = plan.split
+        data_attrs = [a for a in self.attrs if a.name not in plan.pv]
+        with obs_span("scan.split", path=split.path) as plan.span:
             pf = pq.ParquetFile(split.path)
             md = pf.metadata
             schema_index = {md.row_group(0).column(ci).path_in_schema: ci
                             for ci in range(md.num_columns)}
-            # required columns carry NO definition levels in v1 data
-            # pages — max_def must match or the value stream is misparsed
-            max_def = {pf.schema.column(ci).name:
-                       pf.schema.column(ci).max_definition_level
-                       for ci in range(len(pf.schema.names))}
-            # FLBA byte length per column (decimals; 0 for other physicals)
-            flba_len = {pf.schema.column(ci).name:
-                        (getattr(pf.schema.column(ci), "length", 0) or 0)
-                        for ci in range(len(pf.schema.names))}
-            eligible = []
+            for ci in range(len(pf.schema.names)):
+                sc = pf.schema.column(ci)
+                # required columns carry NO definition levels in v1 data
+                # pages — max_def must match or the value stream is
+                # misparsed
+                plan.max_def[sc.name] = sc.max_definition_level
+                # FLBA byte length (decimals; 0 for other physicals)
+                plan.flba_len[sc.name] = getattr(sc, "length", 0) or 0
             for a in data_attrs:
                 ci = schema_index.get(a.name)
                 if ci is not None and PD.column_eligible(
                         md.row_group(0).column(ci), a.data_type):
-                    eligible.append(a)
-        if not eligible:
-            return None
-        groups = list(split.row_groups) if split.row_groups is not None \
-            else list(range(md.num_row_groups))
-        if split_span is not None:
-            split_span.attrs["row_groups"] = len(groups)
-            split_span.attrs["device_columns"] = len(eligible)
-        rest = [a for a in data_attrs if a not in eligible]
-        out = []
-        for rg in groups:
+                    plan.eligible.append(a)
+            if not plan.eligible:
+                return
+            plan.groups = list(split.row_groups) \
+                if split.row_groups is not None \
+                else list(range(md.num_row_groups))
+            plan.rest = [a for a in data_attrs if a not in plan.eligible]
+            if plan.span is not None:
+                plan.span.attrs["row_groups"] = len(plan.groups)
+                plan.span.attrs["device_columns"] = len(plan.eligible)
+        rest_table, rest_at = None, 0
+        for rg in plan.groups:
             rows = md.row_group(rg).num_rows
-            TpuSemaphore.get().acquire_if_necessary(current_task_id())
-            with obs_span("scan.rowgroup", path=split.path, rg=rg, rows=rows):
-                dev_cols = {}
-                for a in eligible:
-                    col = md.row_group(rg).column(schema_index[a.name])
-                    try:
-                        dev_cols[a.name] = self._decode_chunk(
-                            split.path, col, a, rows,
-                            max_def.get(a.name, 1),
-                            flba_len.get(a.name, 0),
-                            (encoded_ok
-                             and a.data_type is DataType.STRING)
-                            or (fixed_ok and a.data_type in (
-                                DataType.INT64, DataType.DATE,
-                                DataType.TIMESTAMP)),
-                            max_frac)
-                    except PD._Unsupported as e:
-                        # a page shape outside the device decoder's scope:
-                        # the whole split decodes on the host, and the
-                        # query's cpuFallbackEvents says so. Anything else
-                        # the decoder raises (a compiler or runtime error
-                        # of the device) propagates to with_retry and the
-                        # query, like any other operator's
-                        import logging
-
-                        M.record_cpu_fallback()
-                        if split_span is not None:
-                            split_span.attrs["fallback"] = f"{a.name}: {e}"
-                        logging.getLogger(__name__).warning(
-                            "device parquet decode refused column %r "
-                            "of %s (%s); the split is decoded on the "
-                            "host", a.name, split.path, e)
-                        return None
-                    if ENC.is_encoded(dev_cols[a.name]):
-                        ENC.record_scan_emission(dev_cols[a.name], rows)
-                    # footer statistics -> value range: device-decoded
-                    # columns never pass through a host array, so the
-                    # upload-time min/max pass (columnar.batch.
-                    # host_value_range) can't see them; the writer's chunk
-                    # stats carry the same proof for free
-                    dev_cols[a.name].vrange = _pq_stats_vrange(
-                        a.data_type, col)
-                verify_footer_vranges(dev_cols)
+            chunks = {}
+            for a in plan.eligible:
+                col = md.row_group(rg).column(schema_index[a.name])
+                try:
+                    with obs_span("scan.read", column=a.name, rg=rg) as sp:
+                        chunk = PD.read_chunk_bytes(split.path, col)
+                        if sp is not None:
+                            sp.attrs["bytes"] = len(chunk)
+                        staged = PD.stage_chunk(
+                            chunk, col.compression, a.data_type, rows,
+                            plan.max_def.get(a.name, 1),
+                            plan.flba_len.get(a.name, 0))
+                except PD._Unsupported as e:
+                    raise PD._Unsupported(f"{a.name}: {e}") from e
+                # footer statistics -> value range: device-decoded
+                # columns never pass through a host array, so the
+                # upload-time min/max pass (columnar.batch.
+                # host_value_range) can't see them; the writer's chunk
+                # stats carry the same proof for free
+                chunks[a.name] = _StagedChunk(
+                    *staged, col.compression,
+                    _pq_stats_vrange(a.data_type, col))
+            with obs_span("scan.host_decode", columns=len(plan.rest), rg=rg):
                 hb = None
-                if rest or pv:
-                    sub = FileSplit(split.path, "parquet", (rg,),
-                                    split.options, split.partition_values)
-                    with obs_span("scan.host_decode", columns=len(rest)):
-                        table = read_split(sub, rest)
-                        hb = arrow_to_host_batch(table, rest)
-                out.extend(self._assemble_device_batch(
-                    dev_cols, hb, rest, pv, rows, conf))
-        return out
+                if plan.rest:
+                    if rest_table is None:
+                        # read the way the host path reads a split: ONE
+                        # threaded Arrow read, then a slice a row group
+                        rest_table = read_split(split, plan.rest, pf)
+                    hb = arrow_to_host_batch(
+                        rest_table.slice(rest_at, rows), plan.rest)
+                    rest_at += rows
+                host = self._stage_host_part(hb, plan.rest, plan.pv, rows)
+            yield _StagedRowGroup(rg, rows, chunks, host)
 
-    @staticmethod
-    def _decode_chunk(path: str, col, attr, rows: int, max_def: int,
-                      flba_len: int, encoded_ok: bool, max_frac: float):
-        """One column chunk: its bytes read (`scan.read`) and handed to
-        the device decoder (`scan.decode`)."""
+    def _decode_staged(self, plan: "_SplitPlan", item: "_StagedRowGroup",
+                       conf):
+        """DEVICE half: one staged row group uploaded, its decode programs
+        issued, its batches assembled. The caller holds the permit. Pure
+        over (item, conf), so with_retry may run it again."""
+        from spark_rapids_tpu import conf as C3
+        from spark_rapids_tpu.columnar import encoded as ENC
         from spark_rapids_tpu.columnar.batch import bucket_capacity
         from spark_rapids_tpu.io import parquet_device as PD
 
-        with obs_span("scan.read", column=attr.name) as sp:
-            chunk = PD.read_chunk_bytes(path, col)
-            if sp is not None:
-                sp.attrs["bytes"] = len(chunk)
-        with obs_span("scan.decode", column=attr.name,
-                      codec=col.compression):
-            return PD.decode_chunk_device(
-                chunk, attr.data_type, rows, max_def=max_def,
-                cap=bucket_capacity(max(rows, 1)), codec=col.compression,
-                flba_len=flba_len, encoded_ok=encoded_ok,
-                max_dict_fraction=max_frac)
+        encoded_ok = conf.get(C3.ENCODED_ENABLED)
+        fixed_ok = encoded_ok and conf.get(C3.ENCODED_FIXED_DICTIONARIES)
+        max_frac = conf.get(C3.ENCODED_MAX_DICT_FRACTION)
+        rows = item.rows
+        with obs_span("scan.rowgroup", path=plan.split.path, rg=item.rg,
+                      rows=rows):
+            dev_cols = {}
+            for a in plan.eligible:
+                staged_chunk = item.chunks[a.name]
+                try:
+                    with obs_span("scan.decode", column=a.name,
+                                  codec=staged_chunk.codec):
+                        cv = PD.decode_chunk_device(
+                            staged_chunk.data, a.data_type, rows,
+                            max_def=plan.max_def.get(a.name, 1),
+                            cap=bucket_capacity(max(rows, 1)),
+                            codec=staged_chunk.codec,
+                            flba_len=plan.flba_len.get(a.name, 0),
+                            encoded_ok=(
+                                encoded_ok
+                                and a.data_type is DataType.STRING)
+                            or (fixed_ok and a.data_type in (
+                                DataType.INT64, DataType.DATE,
+                                DataType.TIMESTAMP)),
+                            max_dict_fraction=max_frac,
+                            pages=staged_chunk.pages, flat=staged_chunk.flat)
+                except PD._Unsupported as e:
+                    raise PD._Unsupported(f"{a.name}: {e}") from e
+                if ENC.is_encoded(cv):
+                    ENC.record_scan_emission(cv, rows)
+                cv.vrange = staged_chunk.vrange
+                dev_cols[a.name] = cv
+            verify_footer_vranges(dev_cols)
+            return self._assemble_staged(
+                dev_cols, item.host, plan.rest, plan.pv, rows, conf)
+
+
+@dataclass
+class _SplitPlan:
+    """What the host half of the device decode (`_stage_split`) learns of
+    a split before its first row group, and the device half reads after
+    it has the first item (or the end) in hand."""
+
+    split: FileSplit
+    pv: Dict[str, Optional[str]]     # the split's partition values
+    span: Any = None                 # the `scan.split` span, tracing on
+    eligible: List[AttributeReference] = field(default_factory=list)
+    rest: List[AttributeReference] = field(default_factory=list)
+    groups: List[int] = field(default_factory=list)
+    max_def: Dict[str, int] = field(default_factory=dict)
+    flba_len: Dict[str, int] = field(default_factory=dict)
+    done: int = 0                    # row groups gone downstream
+
+
+@dataclass
+class _StagedChunk:
+    """One device-eligible column chunk as the host half leaves it."""
+
+    data: bytes                      # decompressed (`PD.stage_chunk`)
+    pages: list                      # PageInfo, offsets into `data`
+    flat: Any                        # the whole-chunk decode's plan
+    codec: str                       # what the file held
+    vrange: Optional[Tuple[int, int]]  # from the footer's statistics
+
+
+@dataclass
+class _StagedRowGroup:
+    """One row group as the host half leaves it: host data only."""
+
+    rg: int
+    rows: int
+    chunks: Dict[str, _StagedChunk]
+    # the columns Arrow decoded (and the partition values), packed for
+    # their upload: columnar.batch.StagedUpload, None where there is none
+    host: Any

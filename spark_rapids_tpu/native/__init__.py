@@ -51,6 +51,10 @@ def get_lib() -> Optional[ctypes.CDLL]:
     """The loaded native library, building it on first use; None when
     unavailable (callers use their Python fallback)."""
     global _lib, _tried
+    if _lib is not None:
+        # per-page callers come here thousands of times a scan, from
+        # every task thread: no lock once the library is loaded
+        return _lib
     with _lock:
         if _lib is not None or _tried:
             return _lib
@@ -66,6 +70,15 @@ def get_lib() -> Optional[ctypes.CDLL]:
             return None
         try:
             _bind(lib)
+            # the per-page helpers run for microseconds and are called a
+            # page at a time beside other Python threads: through PyDLL
+            # they keep the interpreter's lock instead of handing it over
+            # and queueing for it again at every call (the whole-chunk
+            # and whole-file ones stay on CDLL and run beside Python)
+            quick = ctypes.PyDLL(_SO)
+            _bind(quick)
+            for name in _PER_PAGE:
+                setattr(lib, name, getattr(quick, name))
         except AttributeError as e:
             # stale cached .so predating a newly added symbol (mtime-equal
             # copies skip the rebuild): fall back to pure Python
@@ -73,6 +86,9 @@ def get_lib() -> Optional[ctypes.CDLL]:
             return None
         _lib = lib
         return _lib
+
+
+_PER_PAGE = ("srt_parse_runs", "srt_parse_pages", "srt_plain_strings")
 
 
 def _bind(lib) -> None:
@@ -96,6 +112,13 @@ def _bind(lib) -> None:
         ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
         ctypes.c_int64, ctypes.POINTER(ctypes.c_int32),
         ctypes.POINTER(ctypes.c_int32),
+    ]
+    lib.srt_snappy_pages.restype = ctypes.c_int64
+    lib.srt_snappy_pages.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64,
     ]
     lib.srt_csv_plan.restype = ctypes.c_int64
     lib.srt_csv_plan.argtypes = [

@@ -6,8 +6,8 @@
 // the device parser, GpuBatchScanExec.scala:322-520). Here the TPU
 // framework keeps the same split: the device data plane is XLA, and these
 // byte-level host loops — RLE/bit-packed run-table extraction, thrift
-// page-header walking, and CSV field-boundary scanning — run natively
-// instead of interpreting bytes in Python.
+// page-header walking, a chunk's SNAPPY pages, and CSV field-boundary
+// scanning — run natively instead of interpreting bytes in Python.
 //
 // Built as a plain shared object; Python binds via ctypes
 // (spark_rapids_tpu/native/__init__.py) and falls back to the pure-Python
@@ -310,6 +310,109 @@ int64_t srt_plain_strings(const uint8_t* buf, int64_t pos, int64_t end,
     pos += (int64_t)ln;
   }
   return n;
+}
+
+// One snappy block (the raw format parquet's SNAPPY codec stores a page
+// in: a varint uncompressed length, then literal and copy elements)
+// expanded into dst[0, dst_len). Returns 0, or a negative code where the
+// block is malformed or does not fill dst exactly.
+static int snappy_block(const uint8_t* src, int64_t n, uint8_t* dst,
+                        int64_t dst_len) {
+    int64_t pos = 0;
+    uint64_t ulen = 0;
+    for (int shift = 0;; shift += 7) {
+        if (pos >= n || shift > 35) return -1;
+        const uint8_t b = src[pos++];
+        ulen |= (uint64_t)(b & 0x7F) << shift;
+        if (!(b & 0x80)) break;
+    }
+    if ((int64_t)ulen != dst_len) return -2;
+    int64_t out = 0;
+    while (pos < n) {
+        const uint8_t tag = src[pos++];
+        int64_t len, off;
+        switch (tag & 3) {
+        case 0: {  // literal: length - 1 in the tag, or in 1-4 bytes after
+            len = tag >> 2;
+            if (len >= 60) {
+                const int nb = (int)len - 59;
+                if (pos + nb > n) return -1;
+                len = 0;
+                for (int i = 0; i < nb; ++i)
+                    len |= (int64_t)src[pos + i] << (8 * i);
+                pos += nb;
+            }
+            len += 1;
+            if (len > n - pos || len > dst_len - out) return -1;
+            if (len <= 16 && n - pos >= 16 && dst_len - out >= 16)
+                std::memcpy(dst + out, src + pos, 16);  // fixed size: inlined
+            else
+                std::memcpy(dst + out, src + pos, (size_t)len);
+            pos += len;
+            out += len;
+            continue;
+        }
+        case 1:  // copy, 1-byte offset: 3 bits of length, 11 of offset
+            if (pos + 1 > n) return -1;
+            len = 4 + ((tag >> 2) & 7);
+            off = ((int64_t)(tag >> 5) << 8) | src[pos];
+            pos += 1;
+            break;
+        case 2:  // copy, 2-byte offset
+            if (pos + 2 > n) return -1;
+            len = 1 + (tag >> 2);
+            off = (int64_t)src[pos] | ((int64_t)src[pos + 1] << 8);
+            pos += 2;
+            break;
+        default:  // copy, 4-byte offset
+            if (pos + 4 > n) return -1;
+            len = 1 + (tag >> 2);
+            off = (int64_t)src[pos] | ((int64_t)src[pos + 1] << 8) |
+                  ((int64_t)src[pos + 2] << 16) |
+                  ((int64_t)src[pos + 3] << 24);
+            pos += 4;
+            break;
+        }
+        if (off <= 0 || off > out || len > dst_len - out) return -1;
+        if (len <= 16 && off >= 8 && dst_len - out >= 16) {
+            // two fixed 8-byte moves (inlined); writing past len is fine,
+            // the next element overwrites it
+            std::memcpy(dst + out, dst + out - off, 8);
+            std::memcpy(dst + out + 8, dst + out - off + 8, 8);
+        } else if (off >= len) {
+            std::memcpy(dst + out, dst + out - off, (size_t)len);
+        } else {  // the copy overlaps what it writes: a run, byte by byte
+            for (int64_t i = 0; i < len; ++i)
+                dst[out + i] = dst[out + i - off];
+        }
+        out += len;
+    }
+    return out == dst_len ? 0 : -3;
+}
+
+// Every SNAPPY page payload of one column chunk decompressed in ONE call
+// (io/parquet_device.py normalize_chunk): page i's payload
+// chunk[src_off[i], +src_len[i]) into out[dst_off[i], +dst_len[i]). One
+// call a chunk, not one a page, because the caller runs beside other
+// Python threads and every call out of the interpreter is a hand-over of
+// its lock. Returns 0, or -(i + 1) for the first page that does not
+// decompress to its header's size.
+int64_t srt_snappy_pages(const uint8_t* chunk, int64_t chunk_len,
+                         int64_t n_pages, const int64_t* src_off,
+                         const int64_t* src_len, const int64_t* dst_off,
+                         const int64_t* dst_len, uint8_t* out,
+                         int64_t out_len) {
+    for (int64_t i = 0; i < n_pages; ++i) {
+        if (src_off[i] < 0 || src_len[i] < 0 ||
+            src_off[i] > chunk_len - src_len[i] || dst_off[i] < 0 ||
+            dst_len[i] < 0 || dst_off[i] > out_len - dst_len[i])
+            return -(i + 1);
+        if (src_len[i] == 0 && dst_len[i] == 0) continue;
+        if (snappy_block(chunk + src_off[i], src_len[i], out + dst_off[i],
+                         dst_len[i]) != 0)
+            return -(i + 1);
+    }
+    return 0;
 }
 
 }  // extern "C"

@@ -223,11 +223,15 @@ def test_traced_write_span_tree_and_accounting(session, tmp_path, encoder):
 
 
 def test_traced_parquet_scan_spans(tmp_path):
-    """One scan.read and one scan.decode a (row group, column); the bytes
-    read are the footers' compressed chunk sizes; a task waiting for the
-    admission permit is SHALLOWER in the tree than a permit holder's read
-    and no deeper than its row group (the benchmark labels an idle gap
-    with the deepest open span: the worker's step, not the waiters)."""
+    """One scan.read (the host half's, PR 29: before the task asks for
+    its permit) and one scan.decode (the device half's, under its
+    scan.rowgroup) a (row group, column); the bytes read are the footers'
+    compressed chunk sizes; a split's reads all end before the first of
+    its row groups begins; a task waiting for the admission permit is
+    SHALLOWER in the tree than a permit holder's decode and no deeper than
+    its row group
+    (the benchmark labels an idle gap with the deepest open span: the
+    worker's step, not the waiters)."""
     import pyarrow.parquet as pq
 
     # one permit: with two splits the second task waits in the semaphore
@@ -258,39 +262,62 @@ def test_traced_parquet_scan_spans(tmp_path):
             walk(c, d + 1)
 
     walk(trace.root, 0)
-    reads, decodes = {}, {}
-    splits = trace.find("scan.split")
-    assert len(splits) == 2
-    for split in splits:
+    reads, parsed, decodes = {}, {}, {}
+    tasks = [sp for sp in trace.spans()
+             if any(c.name == "scan.split" for c in sp.children)]
+    assert len(tasks) == 2
+    for task in tasks:
+        # both halves' spans are the task's children
+        (split,) = [c for c in task.children if c.name == "scan.split"]
         assert "fallback" not in split.attrs
         assert split.attrs["row_groups"] == 2
         assert split.attrs["device_columns"] == 3
-    for rg_span in trace.find("scan.rowgroup"):
-        for c in rg_span.children:
-            key = (rg_span.attrs["path"], rg_span.attrs["rg"],
-                   c.attrs.get("column"))
-            if c.name == "scan.read":
-                assert key not in reads
-                reads[key] = c.attrs["bytes"]
-            elif c.name == "scan.decode":
+        path = split.attrs["path"]
+        for c in task.children:
+            if c.name != "scan.read":
+                continue
+            key = (path, c.attrs["rg"], c.attrs["column"])
+            assert key not in reads
+            reads[key] = c.attrs["bytes"]
+            assert c.tid == task.tid
+            # decompression and the page walk moved with the read
+            (parse,) = c.children
+            assert parse.name == "scan.parse"
+            parsed[key] = parse.attrs["bytes_out"]
+        rowgroups = [c for c in task.children if c.name == "scan.rowgroup"]
+        assert [sp.attrs["rg"] for sp in rowgroups] == [0, 1]
+        # the whole split is staged before the task asks for its permit
+        (asked,) = [c for c in task.children
+                    if c.name == "Acquire TPU Semaphore"]
+        assert max(c.end_ns for c in task.children
+                   if c.name in ("scan.read", "scan.host_decode")) \
+            <= asked.start_ns <= asked.end_ns <= rowgroups[0].start_ns
+        for rg_span in rowgroups:
+            assert rg_span.attrs["path"] == path
+            assert rg_span.tid == task.tid
+            for c in rg_span.children:
+                assert c.name == "scan.decode"
+                key = (path, rg_span.attrs["rg"], c.attrs["column"])
                 assert key not in decodes
                 decodes[key] = c
     assert reads == expected
     assert set(decodes) == set(expected)
-    for sp in decodes.values():
+    assert len(trace.find("scan.rowgroup")) == 4
+    for key, sp in decodes.items():
         assert sp.attrs["codec"] == "SNAPPY" and sp.attrs["pages"] >= 1
-        assert [c.name for c in sp.children] == ["scan.parse", "scan.upload"]
-        parse, upload = sp.children
+        (upload,) = sp.children
+        assert upload.name == "scan.upload"
         # what goes up is the decompressed chunk, or (PR 26) the payload
         # of its bit-packed index stream alone where the chunk is `packed`
         if sp.attrs["expand"] == "packed":
-            assert parse.attrs["bytes_out"] > upload.attrs["bytes"] > 0
+            assert parsed[key] > upload.attrs["bytes"] > 0
         else:
-            assert parse.attrs["bytes_out"] == upload.attrs["bytes"] > 0
+            assert parsed[key] == upload.attrs["bytes"] > 0
     waits = trace.find("Acquire TPU Semaphore")
     assert waits
     deepest_wait = max(depth[id(s)] for s in waits)
-    assert deepest_wait < min(depth[id(s)] for s in trace.find("scan.read"))
+    assert deepest_wait < min(depth[id(s)]
+                              for s in trace.find("scan.decode"))
     assert deepest_wait <= min(depth[id(s)]
                                for s in trace.find("scan.rowgroup"))
 

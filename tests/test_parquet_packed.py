@@ -507,9 +507,16 @@ def test_scan_decode_span_says_which_form(tmp_path):
     assert by_column["r"].attrs["expand"] == "runs"
     # a string column takes the per-page loop: no form to report
     assert "expand" not in by_column["s"].attrs
+    # the page walk is the host half's (PR 29): under the chunk's
+    # `scan.read`, ahead of the permit; the upload stays the decode's
+    parses = {}
+    for sp in trace.find("scan.read"):
+        (parse,) = sp.children
+        assert parse.name == "scan.parse"
+        parses[sp.attrs["column"]] = parse
     # the packed form uploads its payload alone, under the chunk's bytes
-    parse, upload = by_column["p"].children
-    assert (parse.name, upload.name) == ("scan.parse", "scan.upload")
-    assert 0 < upload.attrs["bytes"] < parse.attrs["bytes_out"]
-    parse, upload = by_column["r"].children
-    assert upload.attrs["bytes"] == parse.attrs["bytes_out"]
+    (upload,) = by_column["p"].children
+    assert upload.name == "scan.upload"
+    assert 0 < upload.attrs["bytes"] < parses["p"].attrs["bytes_out"]
+    (upload,) = by_column["r"].children
+    assert upload.attrs["bytes"] == parses["r"].attrs["bytes_out"]

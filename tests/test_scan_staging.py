@@ -1,0 +1,512 @@
+"""The device parquet scan in two halves (PR 29, io/scan.py): a HOST half
+that a task runs for its whole split before it asks for the admission
+permit (`TpuFileScanExec._stage_split`: footer, chunk reads,
+decompression and page walk, Arrow's decode of the columns the device
+decoder does not take) and a DEVICE half that alone runs under it
+(`_decode_staged`).
+
+Pinned here: the host half touches neither jax nor the semaphore and runs
+while another task holds the permit; the rows equal the host decoder's at
+every prefetch depth (the host decoder's own knob); a page shape the
+decoder refuses in the middle of a split sends what is LEFT of the split
+to the host decoder, each row once; a device error is retried from the
+staged item without re-running the host half; an abandoned or cancelled
+scan leaves no reader thread behind.
+"""
+
+import logging
+
+import numpy as np
+import pyarrow as pa
+import pyarrow.parquet as pq
+import pytest
+
+import spark_rapids_tpu as srt
+from spark_rapids_tpu import conf as C
+from spark_rapids_tpu.columnar.batch import StagedUpload
+from spark_rapids_tpu.engine import cancel as CX
+from spark_rapids_tpu.engine import retry as R
+from spark_rapids_tpu.io import parquet_device as PD
+from spark_rapids_tpu.io import scan as SCAN
+from spark_rapids_tpu.io.arrow_convert import schema_attrs
+from spark_rapids_tpu.io.prefetch import live_reader_count
+from spark_rapids_tpu.memory.semaphore import TpuSemaphore
+from spark_rapids_tpu.plan import functions as F
+from spark_rapids_tpu.utils import metrics as M
+
+PREFETCH = C.IO_PREFETCH_BATCHES.key
+DEVICE_DECODE = C.PARQUET_DEVICE_DECODE.key
+ROWS = 2048  # a row group
+
+
+def _write_files(root, files=2, row_groups=3, seed=3):
+    """lineitem-like files: an id that names every row, a dictionary INT64
+    with nulls, an INT32, a DOUBLE and a low-cardinality string."""
+    rng = np.random.default_rng(seed)
+    root.mkdir(parents=True, exist_ok=True)
+    paths = []
+    for i in range(files):
+        n = ROWS * row_groups
+        table = pa.table({
+            "id": np.arange(i * n, (i + 1) * n, dtype=np.int64),
+            "q": pa.array(rng.integers(1, 51, n).astype(np.int64),
+                          mask=rng.random(n) < 0.05),
+            "d": rng.integers(8000, 10500, n).astype(np.int32),
+            "x": rng.integers(0, 11, n) / 100.0,
+            "s": [f"flag{j % 5}" for j in range(n)]})
+        paths.append(str(root / f"f{i}.parquet"))
+        pq.write_table(table, paths[-1], row_group_size=ROWS,
+                       compression="snappy")
+    return paths
+
+
+def _new_session(**conf):
+    return srt.new_session({"rapids.tpu.sql.spmd.meshDevices": 1, **conf})
+
+
+@pytest.fixture
+def doubles_on_host(monkeypatch):
+    """The chip's column split on the CPU backend: the device decoder
+    refuses DOUBLE (a TPU has no f64), so `x` is Arrow's: the `rest`."""
+    monkeypatch.setattr(PD, "device_float64_supported", lambda: False)
+
+
+def _rows(session, root, **read_opts):
+    reader = session.read
+    for k, v in read_opts.items():
+        reader = reader.option(k, v)
+    return sorted(reader.parquet(str(root)).collect(),
+                  key=lambda r: r[0])
+
+
+# ---------------------------------------------------------------------------
+# the host half: host data in, host data out
+# ---------------------------------------------------------------------------
+class _Forbidden:
+    """Stands in for `jnp` / `jax` / the semaphore while the host half
+    runs. The one thing it may ask jax is which backend it has (a cached
+    name: how wide a DOUBLE goes up, `physical_np_dtype`)."""
+
+    def __init__(self, what):
+        self._what = what
+
+    def __getattr__(self, name):
+        if (self._what, name) == ("jax", "default_backend"):
+            return lambda: "cpu"
+        raise AssertionError(f"the host half touched {self._what}.{name}")
+
+
+def test_host_half_makes_no_jax_call_and_takes_no_permit(
+        tmp_path, monkeypatch, doubles_on_host):
+    (path,) = _write_files(tmp_path, files=1)
+    conf = C.TpuConf()
+    attrs = schema_attrs(pq.read_schema(path))
+    (split,) = SCAN.plan_splits("parquet", [path], {}, conf)
+    scan = SCAN.TpuFileScanExec(attrs, [split], "parquet")
+    plan = SCAN._SplitPlan(split, {})
+    import spark_rapids_tpu.columnar.batch as B
+
+    for mod in (PD, B):
+        monkeypatch.setattr(mod, "jnp", _Forbidden("jnp"))
+        monkeypatch.setattr(mod, "jax", _Forbidden("jax"))
+    monkeypatch.setattr(TpuSemaphore, "get",
+                        classmethod(lambda cls: _Forbidden("TpuSemaphore")))
+    items = list(scan._stage_split(plan))
+    monkeypatch.undo()
+
+    assert [a.name for a in plan.eligible] == ["id", "q", "d", "s"]
+    assert [a.name for a in plan.rest] == ["x"]
+    assert plan.groups == [0, 1, 2] and [it.rg for it in items] == [0, 1, 2]
+    md = pq.ParquetFile(path).metadata
+    for it in items:
+        assert it.rows == ROWS
+        # Arrow's column, packed for its upload: host arrays only
+        assert isinstance(it.host, StagedUpload)
+        assert it.host.num_rows == ROWS
+        assert all(isinstance(b, np.ndarray) for b in it.host.bufs)
+        want = pq.ParquetFile(path).read_row_group(
+            it.rg, columns=["x"]).column("x").to_numpy()
+        (f64,) = [b for b in it.host.bufs if b.dtype == np.float64]
+        assert np.array_equal(f64[:ROWS], want)
+        assert sorted(it.chunks) == ["d", "id", "q", "s"]
+        for ci in range(md.num_columns):
+            col = md.row_group(it.rg).column(ci)
+            if col.path_in_schema == "x":
+                continue
+            chunk = it.chunks[col.path_in_schema]
+            assert chunk.codec == "SNAPPY"
+            # decompressed: what the decoder's own first block would give
+            data, pages = PD.normalize_chunk(
+                PD.read_chunk_bytes(path, col), "SNAPPY")
+            assert chunk.data == data and chunk.pages == pages
+            assert isinstance(chunk.data, bytes)
+            # the whole-chunk decode is planned here too, in host arrays:
+            # `id` and `d` bit-packed throughout, `q` with real nulls,
+            # the string on the per-page loop
+            name = col.path_in_schema
+            if name == "s":
+                assert chunk.flat is None
+                continue
+            assert chunk.flat.val_form == \
+                ("runs" if name == "q" else "packed")
+            assert (chunk.flat.planes is None) == (name == "q")
+            for held in (chunk.flat.def_tab or ()) + \
+                    (chunk.flat.val_tab or ()) + (chunk.flat.nums,):
+                assert isinstance(held, np.ndarray)
+
+
+def test_a_staged_chunk_decodes_as_an_unstaged_one(tmp_path):
+    """The decoder's seam: `decode_chunk_device` given `stage_chunk`'s
+    pages and plan issues the same programs on the same tables as when
+    it parses and plans for itself."""
+    import jax
+
+    (path,) = _write_files(tmp_path, files=1, row_groups=1)
+    pf = pq.ParquetFile(path)
+    kinds = {"id": "long", "q": "long", "d": "int", "x": "double",
+             "s": "string"}
+    for ci, attr in enumerate(schema_attrs(pf.schema_arrow)):
+        col = pf.metadata.row_group(0).column(ci)
+        assert col.path_in_schema == attr.name and attr.name in kinds
+        max_def = pf.schema.column(ci).max_definition_level
+        raw = PD.read_chunk_bytes(path, col)
+        alone = PD.decode_chunk_device(raw, attr.data_type, ROWS,
+                                       max_def=max_def, codec="SNAPPY")
+        data, pages, flat = PD.stage_chunk(raw, "SNAPPY", attr.data_type,
+                                           ROWS, max_def)
+        assert (flat is None) == (attr.name == "s")
+        staged = PD.decode_chunk_device(data, attr.data_type, ROWS,
+                                        max_def=max_def, codec="SNAPPY",
+                                        pages=pages, flat=flat)
+        for a, b in zip(jax.tree_util.tree_leaves(alone),
+                        jax.tree_util.tree_leaves(staged)):
+            assert np.array_equal(np.asarray(a), np.asarray(b)), attr.name
+        # without a dtype the plan is left to the decoder
+        assert PD.stage_chunk(raw, "SNAPPY")[2] is PD._UNPLANNED
+
+
+def test_no_eligible_column_stages_nothing(tmp_path, monkeypatch):
+    """Where the decoder takes no column of the file the host half says so
+    before any read, and the scan is the host path's: no fallback event."""
+    (path,) = _write_files(tmp_path, files=1)
+    monkeypatch.setattr(PD, "column_eligible", lambda col, dt: False)
+    reads = []
+    monkeypatch.setattr(PD, "read_chunk_bytes",
+                        lambda *a: reads.append(a) or b"")
+    session = _new_session(**{C.OBS_TRACING.key: True})
+    try:
+        rows = _rows(session, tmp_path)
+        metrics = dict(session.last_query_metrics)
+        trace = session.last_query_trace
+    finally:
+        session.stop()
+    assert [r[0] for r in rows] == list(range(3 * ROWS))
+    assert metrics[M.CPU_FALLBACK_EVENTS] == 0 and not reads
+    assert not trace.find("scan.rowgroup")
+    (split,) = trace.find("scan.split")
+    assert "fallback" not in split.attrs and "row_groups" not in split.attrs
+
+
+# ---------------------------------------------------------------------------
+# one task holds the permit, the other's host half runs all the same
+# ---------------------------------------------------------------------------
+def test_host_half_runs_while_another_task_holds_the_permit(
+        tmp_path, doubles_on_host):
+    _write_files(tmp_path, files=2, row_groups=2)
+    session = _new_session(**{C.CONCURRENT_TPU_TASKS.key: 1,
+                              C.OBS_TRACING.key: True})
+    try:
+        session.read.parquet(str(tmp_path)) \
+            .agg(F.sum("x"), F.sum("q"), F.sum("d")).collect()
+        trace = session.last_query_trace
+        assert session.last_query_metrics[M.CPU_FALLBACK_EVENTS] == 0
+    finally:
+        session.stop()
+
+    tasks = [sp for sp in trace.spans()
+             if sp.kind == "task" and any(c.name == "scan.split"
+                                          for c in sp.children)]
+    assert len(tasks) == 2
+
+    def wait_of(task):
+        (wait,) = [c for c in task.children
+                   if c.name == "Acquire TPU Semaphore"]
+        return wait
+
+    holder, queued = sorted(tasks, key=lambda t: wait_of(t).duration_ns)
+    wait = wait_of(queued)
+    # the queued task waited for the whole of the holder's device half
+    # (the first query: its programs compile under the permit)
+    held = [c for c in holder.children if c.name == "scan.rowgroup"]
+    assert wait.start_ns < held[0].end_ns and wait.end_ns >= held[-1].end_ns
+    for task in tasks:
+        asked = wait_of(task)
+        host_half = [c for c in task.children
+                     if c.name in ("scan.split", "scan.read",
+                                   "scan.host_decode")]
+        # 1 split, 2 row groups x (`q` and `d` read, `x` decoded by Arrow:
+        # the plan prunes the rest): the WHOLE split staged, on the task's
+        # own thread, before the task asked for its permit, so no permit
+        # is held through host work
+        assert len(host_half) == 1 + 2 * 3
+        for sp in host_half:
+            assert sp.end_ns <= asked.start_ns and sp.tid == task.tid
+        device_half = [c for c in task.children if c.name == "scan.rowgroup"]
+        assert [sp.attrs["rg"] for sp in device_half] == [0, 1]
+        for sp in device_half:
+            assert sp.start_ns >= asked.end_ns and sp.tid == task.tid
+    # the queued task's host half did not wait for the holder's permit: it
+    # was done before the holder's device half was
+    assert max(c.end_ns for c in queued.children
+               if c.name == "scan.host_decode") < held[-1].end_ns
+    assert not trace.find("prefetch:scan-stage")
+
+
+def test_the_rest_columns_are_one_read_a_split(tmp_path, monkeypatch,
+                                               doubles_on_host):
+    """The columns Arrow decodes are read the way the host path reads a
+    split — `read_split`, once, on the file the host half has open — and
+    sliced a row group."""
+    (path,) = _write_files(tmp_path, files=1)
+    conf = C.TpuConf()
+    attrs = schema_attrs(pq.read_schema(path))
+    (split,) = SCAN.plan_splits("parquet", [path], {}, conf)
+    scan = SCAN.TpuFileScanExec(attrs, [split], "parquet")
+    reads = []
+    read_split = SCAN.read_split
+
+    def counting(split, attrs, pf=None):
+        reads.append(([a.name for a in attrs], pf))
+        return read_split(split, attrs, pf)
+
+    monkeypatch.setattr(SCAN, "read_split", counting)
+    items = list(scan._stage_split(SCAN._SplitPlan(split, {})))
+    assert len(items) == 3
+    ((names, pf),) = reads
+    assert names == ["x"] and pf is not None
+    x = pq.read_table(path).column("x").to_numpy()
+    for i, it in enumerate(items):
+        (up,) = [b for b in it.host.bufs if b.dtype == np.float64]
+        np.testing.assert_array_equal(up[:ROWS], x[i * ROWS:(i + 1) * ROWS])
+
+
+def test_read_split_on_an_open_file(tmp_path):
+    (path,) = _write_files(tmp_path, files=1)
+    attrs = [a for a in schema_attrs(pq.read_schema(path))
+             if a.name in ("id", "x")]
+    split = SCAN.FileSplit(path, "parquet", (1, 2), (), ())
+    want = SCAN.read_split(split, attrs)
+    assert want.num_rows == 2 * ROWS and want.column_names == ["id", "x"]
+    assert SCAN.read_split(split, attrs, pq.ParquetFile(path)).equals(want)
+
+
+# ---------------------------------------------------------------------------
+# the same rows as the host decoder, at every depth
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("depth", [0, 1, 3])
+def test_rows_equal_the_host_decoders_at_every_depth(
+        tmp_path, depth, doubles_on_host):
+    _write_files(tmp_path / "k=1", files=2, seed=1)
+    _write_files(tmp_path / "k=2", files=1, seed=2)
+    session = _new_session(**{PREFETCH: depth, C.OBS_TRACING.key: True})
+    try:
+        session.set_conf(DEVICE_DECODE, False)
+        want = _rows(session, tmp_path)
+        assert not session.last_query_trace.find("scan.rowgroup")
+        session.set_conf(DEVICE_DECODE, True)
+        got = _rows(session, tmp_path)
+        trace = session.last_query_trace
+        assert session.last_query_metrics[M.CPU_FALLBACK_EVENTS] == 0
+        # the per-read option overrides the session's depth
+        again = _rows(session, tmp_path, prefetchBatches=3 - depth)
+    finally:
+        session.stop()
+    assert len(want) == 3 * 3 * ROWS
+    assert got == want and again == want
+    spans = trace.find("scan.rowgroup")
+    assert len(spans) == 9
+    # the device scan stages on the task thread whatever the depth: the
+    # knob is the host decoder's
+    assert {sp.tid for sp in trace.find("scan.read")} <= \
+        {sp.tid for sp in spans}
+    assert not trace.find("prefetch:scan-stage")
+    assert live_reader_count() == 0
+
+
+# ---------------------------------------------------------------------------
+# a refused page shape in the middle of a split
+# ---------------------------------------------------------------------------
+def _refuse_second(monkeypatch, half):
+    """`_Unsupported` at the second row group's `q` chunk, raised by the
+    host half (`stage_chunk`) or by the device half (`decode_chunk_device`)."""
+    seen = []
+    if half == "host":
+        real = PD.stage_chunk
+
+        def stage_chunk(chunk, codec, *what):
+            seen.append(len(seen))
+            # chunks are staged in column order: id, q, d, s a row group
+            if len(seen) == 4 + 2:
+                raise PD._Unsupported("test: refused page")
+            return real(chunk, codec, *what)
+
+        monkeypatch.setattr(PD, "stage_chunk", stage_chunk)
+    else:
+        real = PD.decode_chunk_device
+
+        def decode_chunk_device(chunk, dtype, rows, **kw):
+            seen.append(len(seen))
+            if len(seen) == 4 + 2:
+                raise PD._Unsupported("test: refused page")
+            return real(chunk, dtype, rows, **kw)
+
+        monkeypatch.setattr(PD, "decode_chunk_device", decode_chunk_device)
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+@pytest.mark.parametrize("half", ["host", "device"])
+def test_unsupported_at_the_second_row_group_yields_every_row_once(
+        tmp_path, monkeypatch, caplog, half, depth, doubles_on_host):
+    (path,) = _write_files(tmp_path, files=1)
+    _refuse_second(monkeypatch, half)
+    session = _new_session(**{C.OBS_TRACING.key: True, PREFETCH: depth})
+    try:
+        with caplog.at_level(logging.WARNING, logger=SCAN.__name__):
+            got = _rows(session, tmp_path)
+        metrics = dict(session.last_query_metrics)
+        trace = session.last_query_trace
+        monkeypatch.undo()
+        session.set_conf(DEVICE_DECODE, False)
+        want = _rows(session, tmp_path)
+    finally:
+        session.stop()
+    # every row exactly once, whichever decoder it came through
+    assert [r[0] for r in got] == list(range(3 * ROWS))
+    assert got == want
+    assert metrics[M.CPU_FALLBACK_EVENTS] == 1
+    (split,) = trace.find("scan.split")
+    assert split.attrs["fallback"] == "q: test: refused page"
+    assert split.attrs["row_groups"] == 3
+    # a refusal of the host half comes before the split's first device
+    # half: the whole split is the host decoder's. One of the device half
+    # comes from inside its row group's span: the first row group went
+    # through the device decoder and downstream, the other two are the
+    # host decoder's (one read of what is left)
+    assert [sp.attrs["rg"] for sp in trace.find("scan.rowgroup")] == \
+        ([] if half == "host" else [0, 1])
+    warned = [r for r in caplog.records if "refused" in r.getMessage()]
+    assert len(warned) == 1 and \
+        ("[0, 1, 2]" if half == "host" else "[1, 2]") in \
+        warned[0].getMessage()
+    assert live_reader_count() == 0
+
+
+# ---------------------------------------------------------------------------
+# a device error is retried from the staged item
+# ---------------------------------------------------------------------------
+def test_device_error_is_retried_from_the_staged_item(
+        tmp_path, monkeypatch, doubles_on_host):
+    (path,) = _write_files(tmp_path, files=1)
+    reads, halves = [], []
+    read_chunk_bytes = PD.read_chunk_bytes
+    decode_staged = SCAN.TpuFileScanExec._decode_staged
+
+    def counting_read(path, col):
+        reads.append(col.path_in_schema)
+        return read_chunk_bytes(path, col)
+
+    def failing_once(self, plan, item, conf):
+        halves.append(item)
+        if len(halves) == 2:
+            raise R.TpuRetryOOM("RESOURCE_EXHAUSTED: injected at rg 1")
+        return decode_staged(self, plan, item, conf)
+
+    monkeypatch.setattr(PD, "read_chunk_bytes", counting_read)
+    monkeypatch.setattr(SCAN.TpuFileScanExec, "_decode_staged", failing_once)
+    session = _new_session()
+    try:
+        got = _rows(session, tmp_path)
+        metrics = dict(session.last_query_metrics)
+    finally:
+        session.stop()
+    assert [r[0] for r in got] == list(range(3 * ROWS))
+    table = pq.read_table(path)
+    assert [r[3] for r in got] == table.column("x").to_pylist()
+    assert [r[1] for r in got] == table.column("q").to_pylist()
+    # the failed row group's device half ran again, on the very item the
+    # host half staged: no chunk was read twice
+    assert [it.rg for it in halves] == [0, 1, 1, 2]
+    assert halves[1] is halves[2]
+    assert sorted(reads) == sorted(["id", "q", "d", "s"] * 3)
+    assert metrics[M.RETRIES] == 1
+    assert metrics[M.CPU_FALLBACK_EVENTS] == 0
+
+
+def test_host_half_error_is_the_tasks_before_any_row_went_downstream(
+        tmp_path, monkeypatch, doubles_on_host):
+    """An IO error of the host half is the task's, and comes before the
+    split's first device half: nothing went downstream, and the
+    task-level retry reads the split again."""
+    (path,) = _write_files(tmp_path, files=1)
+    read_chunk_bytes = PD.read_chunk_bytes
+    calls = []
+
+    def flaky_read(path, col):
+        calls.append(col.path_in_schema)
+        if len(calls) == 4 + 1:
+            raise OSError("test: disk hiccup at rg 1")
+        return read_chunk_bytes(path, col)
+
+    monkeypatch.setattr(PD, "read_chunk_bytes", flaky_read)
+    session = _new_session()
+    try:
+        got = _rows(session, tmp_path)
+        metrics = dict(session.last_query_metrics)
+    finally:
+        session.stop()
+    assert [r[0] for r in got] == list(range(3 * ROWS))
+    assert metrics[M.CPU_FALLBACK_EVENTS] == 0
+    # one row group and a chunk before the error, then the whole split
+    assert len(calls) == 4 + 1 + 3 * 4
+    assert live_reader_count() == 0
+
+
+# ---------------------------------------------------------------------------
+# nothing is left behind
+# ---------------------------------------------------------------------------
+def test_abandoned_scan_leaves_no_reader(tmp_path, doubles_on_host):
+    _write_files(tmp_path, files=2, row_groups=4)
+    session = _new_session(**{PREFETCH: 1})
+    try:
+        rows = session.read.parquet(str(tmp_path)).limit(5).collect()
+        assert len(rows) == 5
+        assert live_reader_count() == 0
+        CX.assert_reclaimed()
+    finally:
+        session.stop()
+
+
+def test_cancelled_scan_leaves_no_reader(tmp_path, monkeypatch,
+                                         doubles_on_host):
+    """A deadline that fires while a task is in its device half and the
+    others hold staged row groups: no thread is left behind when the
+    error reaches the caller, and the permits are back."""
+    _write_files(tmp_path, files=2, row_groups=4)
+    entered = []
+
+    def grinding(self, plan, item, conf):
+        entered.append(item.rg)
+        CX.cancel_aware_sleep(60.0, site="test.scan")
+        raise AssertionError("the sleep outlived the deadline")
+
+    monkeypatch.setattr(SCAN.TpuFileScanExec, "_decode_staged", grinding)
+    session = _new_session(**{PREFETCH: 2})
+    try:
+        with pytest.raises(CX.TpuDeadlineExceeded):
+            session.read.parquet(str(tmp_path)).collect(timeout=0.5)
+        assert entered
+        assert session.last_query_metrics["cancelledQueries"] == 1
+        assert live_reader_count() == 0
+        CX.assert_reclaimed()
+    finally:
+        session.stop()

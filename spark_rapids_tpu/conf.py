@@ -259,11 +259,17 @@ IO_PREFETCH_BATCHES = _conf("rapids.tpu.io.prefetchBatches").doc(
 PARQUET_READ_ENABLED = _conf("rapids.tpu.sql.format.parquet.read.enabled").boolean(True)
 PARQUET_DEVICE_DECODE = _conf(
     "rapids.tpu.sql.format.parquet.deviceDecode.enabled").doc(
-    "Decode eligible parquet columns ON the device: raw dictionary/RLE "
-    "chunk bytes upload and a jitted kernel expands runs + gathers the "
-    "dictionary (reference decodes on the accelerator the same way, "
-    "GpuParquetScan.scala:536-556). Ineligible columns/pages fall back to "
-    "the host Arrow decoder per column."
+    "Decode parquet STRING columns ON the device: the raw (decompressed) "
+    "BYTE_ARRAY chunk uploads, jitted kernels expand the definition-level "
+    "and dictionary-index runs, and the column comes out as dictionary "
+    "codes + dictionary where rapids.tpu.sql.encoded.* admits it "
+    "(reference decodes on the accelerator, GpuParquetScan.scala:536-556). "
+    "Strings only: every fixed-width column (ints, floats, bools, dates, "
+    "timestamps, decimals) is decoded by Arrow on the host, one threaded "
+    "read a split, and uploaded — on a TPU v5e that read ran 1.8x (TPC-H "
+    "Q6) and 1.4x (a parquet write) ahead of a device decode of the same "
+    "columns (PERF.md). Off: strings take Arrow too and arrive decoded. "
+    "Pages the decoder refuses fall back to Arrow for the split."
 ).boolean(True)
 PARQUET_WRITE_ENABLED = _conf("rapids.tpu.sql.format.parquet.write.enabled").boolean(True)
 PARQUET_DEVICE_ENCODE = _conf(
@@ -1170,18 +1176,6 @@ ENCODED_MAX_DICT_FRACTION = _conf("rapids.tpu.sql.encoded.maxDictFraction").doc(
     "near-unique column gains nothing from codes and would pay the "
     "dictionary residency twice)."
 ).check(lambda v: None if 0.0 < v <= 1.0 else "must be in (0,1]").double(0.5)
-
-ENCODED_FIXED_DICTIONARIES = _conf(
-    "rapids.tpu.sql.encoded.fixedDictionaries.enabled").doc(
-    "Admit INT64 / DATE / TIMESTAMP dictionary-encoded parquet chunks as "
-    "ENCODED columns under the same maxDictFraction eligibility as "
-    "strings: codes stay int32 in HBM with a shared fixed-value "
-    "dictionary, group-bys run on codes, sorts / range bounds / min-max "
-    "and comparison predicates run in rank space through the "
-    "order-preserving sorted dictionary, and materialize() is one "
-    "value-table gather. Off limits encoded emission to STRING columns "
-    "(the PR 9 behavior)."
-).boolean(True)
 
 RUN_AWARE_ENABLED = _conf("rapids.tpu.sql.runAware.enabled").doc(
     "Run-granular aggregate fast path (columnar/runs.py): when every "

@@ -12,9 +12,10 @@ Reference parity:
 - per-format enable confs (RapidsConf.scala:433-469) -> tagged in
   plan/overrides.py.
 
-Phase 1 decodes on the host with Arrow C++ (the correctness oracle the
-SURVEY.md build plan keeps); phase 2+ moves Parquet dictionary/RLE decode
-into Pallas kernels fed by raw column chunks.
+A parquet column that is not a string is decoded by Arrow C++ on the host
+(one threaded read a split) and uploaded; a string column is decoded on
+the device from its raw chunk (io/parquet_device.py), which leaves it as
+dictionary codes where it can.
 """
 
 from __future__ import annotations
@@ -66,8 +67,10 @@ HIVE_NULL = "__HIVE_DEFAULT_PARTITION__"
 
 def _orc_stats_vrange(attr, meta) -> Optional[Tuple[int, int]]:
     """(lo, hi) for an ORC column from the file footer's IntegerStatistics
-    (parsed in orc_device.parse_file_meta), INT64 columns only — the same
-    narrowing proof _pq_stats_vrange supplies for parquet."""
+    (parsed in orc_device.parse_file_meta), INT64 columns only: the
+    device-decoded column never passes through a host array, so the
+    upload-time min/max pass (columnar.batch.host_value_range) cannot
+    see it, and the writer's stats carry the same proof for free."""
     from spark_rapids_tpu.columnar.batch import (
         int64_narrowing_enabled,
         quantize_vrange,
@@ -163,31 +166,6 @@ def verify_footer_vranges(dev_cols: Dict[str, "ColumnVector"]) -> List[str]:
             cv.vrange = None
             dropped.append(name)
     return dropped
-
-
-def _pq_stats_vrange(dt: DataType, col_meta) -> Optional[Tuple[int, int]]:
-    """(lo, hi) from a parquet column-chunk's footer statistics, for the
-    int32-narrowing proof (columnar.batch module docstring). INT64 logical
-    columns only — TIMESTAMP never fits int32 and narrower ints gain
-    nothing; None when stats are absent/untrusted."""
-    from spark_rapids_tpu.columnar.batch import (
-        int64_narrowing_enabled,
-        quantize_vrange,
-    )
-
-    if dt is not DataType.INT64 or not int64_narrowing_enabled():
-        return None
-    try:
-        st = col_meta.statistics
-        if st is None or not st.has_min_max:
-            return None
-        lo, hi = st.min, st.max
-        if isinstance(lo, (int, np.integer)) and \
-                isinstance(hi, (int, np.integer)):
-            return quantize_vrange((int(lo), int(hi)))
-    except Exception:
-        pass
-    return None
 
 
 def partition_values_of(path: str, roots: List[str]):
@@ -418,8 +396,13 @@ class _FileScanBase(PhysicalExec):
 
         pv = dict(split.partition_values)
         data_attrs = [a for a in self.attrs if a.name not in pv]
-        table = read_split(split, data_attrs)
-        batch = arrow_to_host_batch(table, data_attrs)
+        # on the prefetcher's thread, which carries the task's context
+        # and span (io/prefetch.py)
+        with obs_span("scan.host_decode", columns=len(data_attrs)) as sp:
+            table = read_split(split, data_attrs)
+            batch = arrow_to_host_batch(table, data_attrs)
+            if sp is not None:
+                sp.attrs["rows"] = batch.num_rows
         if pv:
             # append partition-value constant columns (reference:
             # ColumnarPartitionReaderWithPartitionValues)
@@ -453,34 +436,45 @@ class CpuFileScanExec(_FileScanBase, CpuExec):
 
 
 class TpuFileScanExec(_FileScanBase, TpuExec):
-    """Parquet columns that qualify decode ON DEVICE from raw chunk bytes
-    (io/parquet_device.py — the reference's accelerator-side decode,
-    GpuParquetScan.scala:536-556); everything else host-decodes via Arrow
-    and uploads. The admission semaphore is acquired exactly where the
-    reference acquires it: before bytes go on the device
-    (GpuParquetScan.scala:554).
+    """A parquet column that is not a string reaches the device one way:
+    Arrow decodes it on the host, in the split's one threaded read, and
+    `to_device` moves it (`_read_host`). On the chip that read alone ran
+    1.8x (Q6) and 1.4x (a parquet write) ahead of a device decode of the
+    same columns, measured twice (PERF.md section 6, PR 30), so there is
+    no other decoder for them. STRING columns decode ON DEVICE from raw
+    chunk bytes (io/parquet_device.py — the reference's accelerator-side
+    decode, GpuParquetScan.scala:536-556), the one input whose device
+    form, dictionary codes + dictionary (columnar/encoded.py), Arrow's
+    read does not hand over; `_read_device` serves a scan that has one,
+    with Arrow decoding the columns beside it. The admission semaphore
+    is acquired exactly where the reference acquires it: before bytes go
+    on the device (GpuParquetScan.scala:554).
 
-    Neither parquet path holds a permit through host work on host data.
-    The host decoder's split is one Arrow read on the scan prefetcher's
+    Neither path holds a permit through host work on host data. The
+    host decoder's split is one Arrow read on the scan prefetcher's
     reader thread (io/prefetch.py, `rapids.tpu.io.prefetchBatches`;
-    `_read_host_iter` / `to_device`). The device decoder's split is
-    staged on the task thread — the footer, each chunk's read,
-    decompression and page walk, Arrow's decode of the columns the device
-    decoder does not take — before the task asks for the permit
-    (`_stage_split`), and only uploads and program issue run under it
-    (`_decode_staged`). A reader thread a task was tried there and lost
-    to this order on the chip's host, where the interpreter's lock, not
-    the cores, bounds the host half (PERF.md section 6, PR 29). Staging
-    is host memory only: a split's staged row groups, as the host path
-    holds a split's Arrow table."""
+    `_read_host_iter` / `to_device`). A split with a string column is
+    staged on the task thread — the footer, each string chunk's read,
+    decompression and page walk, Arrow's decode of the other columns —
+    before the task asks for the permit (`_stage_split`), and only
+    uploads and program issue run under it (`_decode_staged`). A reader
+    thread a task was tried there and lost to this order on the chip's
+    host, where the interpreter's lock, not the cores, bounds the host
+    half (PERF.md section 6, PR 29). Staging is host memory only: a
+    split's staged row groups, as the host path holds a split's Arrow
+    table."""
 
     placement = "tpu"
 
     def execute(self, ctx: ExecContext) -> PartitionedBatches:
         from spark_rapids_tpu import conf as C
 
+        # the attributes alone say whether a split can hold a column the
+        # device decodes (parquet_device.column_eligible: strings): a
+        # scan without one opens no footer to find that out
         device_decode = self.fmt == "parquet" and \
-            ctx.conf.get(C.PARQUET_DEVICE_DECODE)
+            ctx.conf.get(C.PARQUET_DEVICE_DECODE) and \
+            any(a.data_type is DataType.STRING for a in self.attrs)
         device_csv = self.fmt == "csv" and ctx.conf.get(C.CSV_DEVICE_PARSE)
         device_orc = self.fmt == "orc" and ctx.conf.get(C.ORC_DEVICE_DECODE)
 
@@ -525,11 +519,22 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
         upload ISSUES here (asynchronously — jax returns an unblocked
         device future) under this task's admission permit, so batch k+1's
         decode and upload overlap batch k's downstream compute."""
-        from spark_rapids_tpu.engine.retry import with_retry
-
         for hb in self._host_batches_prefetched(split, conf):
             TpuSemaphore.get().acquire_if_necessary(current_task_id())
-            yield with_retry(lambda: hb.to_device(), site="scan")
+            yield self._upload(hb)
+
+    @staticmethod
+    def _upload(hb: HostColumnarBatch):
+        """One host batch onto the device, under the caller's permit (a
+        step of its own so that the generator's frame keeps no reference
+        to a batch that has gone downstream)."""
+        from spark_rapids_tpu.engine.retry import with_retry
+
+        with obs_span("scan.upload", columns=len(hb.columns)) as sp:
+            batch = with_retry(lambda: hb.to_device(), site="scan")
+            if sp is not None:
+                sp.attrs["bytes"] = batch.device_memory_size()
+        return batch
 
     def _read_device_csv(self, split: FileSplit, conf):
         """Device CSV parse for one split; None -> structure/columns not
@@ -848,7 +853,7 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
             host_names = [a.name for a in rest] + \
                 [a.name for a in self.attrs if a.name in pv]
             # the host-decoded columns go up at their full width (the
-            # device decoder's chunks go up compressed-size, in
+            # device decoder's string chunks go up decompressed, in
             # io/parquet_device.py, under the same span name)
             with obs_span("scan.upload", columns=len(host_names)) as sp:
                 host_part = staged.upload()
@@ -890,10 +895,8 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
              and conf.get(C3.PARQUET_DEVICE_DECODE))
             or (self.fmt == "orc" and conf.get(C3.ORC_DEVICE_DECODE)))
         frac = conf.get(C3.ENCODED_MAX_DICT_FRACTION)
-        fixed_conf = conf.get(C3.ENCODED_FIXED_DICTIONARIES)
         cached = getattr(self, "_encoded_plan_cache", None)
-        if cached is not None and cached[0] == (enabled, frac,
-                                                fixed_conf):
+        if cached is not None and cached[0] == (enabled, frac):
             return cached[1]
         out: Dict[str, str] = {}
         if enabled and self.fmt == "orc":
@@ -949,17 +952,13 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
                                     out[want.pop(cid)] = "possible"
             except Exception:
                 out = {}
-            self._encoded_plan_cache = ((enabled, frac, fixed_conf), out)
+            self._encoded_plan_cache = ((enabled, frac), out)
             return out
-        if enabled:
+        str_attrs = [a for a in self.attrs
+                     if a.data_type is DataType.STRING]
+        if enabled and str_attrs:
             import pyarrow.parquet as pq
 
-            fixed_ok = fixed_conf
-            str_attrs = [a for a in self.attrs
-                         if a.data_type is DataType.STRING
-                         or (fixed_ok and a.data_type in (
-                             DataType.INT64, DataType.DATE,
-                             DataType.TIMESTAMP))]
             # per column: 'certain' only when EVERY row group of every
             # split is a provably dict-only chunk clearing the heuristic;
             # 'possible' when ANY group might encode (the savings
@@ -1003,15 +1002,16 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
                         else "possible"
             except Exception:
                 out = {}
-        self._encoded_plan_cache = ((enabled, frac, fixed_conf), out)
+        self._encoded_plan_cache = ((enabled, frac), out)
         return out
 
     def _read_device(self, split: FileSplit, conf):
-        """Device decode for one split: a generator of its batches, one row
-        group at a time, that returns False where no column qualified
-        (nothing was yielded; the caller uses the host path) and True
-        otherwise. Mixed batches combine device-decoded columns with
-        host-decoded/partition-value columns at the same capacity.
+        """Device decode for one split of a scan that has a STRING column:
+        a generator of its batches, one row group at a time, that returns
+        False where no column qualified (nothing was yielded; the caller
+        uses the host path) and True otherwise. Batches combine the
+        device-decoded string columns with the host-decoded and
+        partition-value columns at the same capacity.
 
         Two halves, both on the task thread. The HOST half
         (`_stage_split`) stages the whole split — host work on host data —
@@ -1097,10 +1097,10 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
         which backend there is: how wide a DOUBLE goes up), so a task
         runs it without a permit: the footer work (filling `plan` before
         the first item), per device-eligible column chunk its bytes read,
-        decompressed, page-walked and its decode planned
-        (`PD.stage_chunk`), and for the `rest` columns Arrow's decode and
-        their packing for the upload (`_stage_host_part`). Yields nothing
-        where no column qualified (`plan.eligible` is empty)."""
+        decompressed and page-walked (`PD.stage_chunk`), and for the
+        `rest` columns Arrow's decode and their packing for the upload
+        (`_stage_host_part`). Yields nothing where no column qualified
+        (`plan.eligible` is empty)."""
         import pyarrow.parquet as pq
 
         from spark_rapids_tpu.io import parquet_device as PD
@@ -1118,8 +1118,6 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
                 # pages — max_def must match or the value stream is
                 # misparsed
                 plan.max_def[sc.name] = sc.max_definition_level
-                # FLBA byte length (decimals; 0 for other physicals)
-                plan.flba_len[sc.name] = getattr(sc, "length", 0) or 0
             for a in data_attrs:
                 ci = schema_index.get(a.name)
                 if ci is not None and PD.column_eligible(
@@ -1145,20 +1143,11 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
                         chunk = PD.read_chunk_bytes(split.path, col)
                         if sp is not None:
                             sp.attrs["bytes"] = len(chunk)
-                        staged = PD.stage_chunk(
-                            chunk, col.compression, a.data_type, rows,
-                            plan.max_def.get(a.name, 1),
-                            plan.flba_len.get(a.name, 0))
+                        chunks[a.name] = _StagedChunk(
+                            *PD.stage_chunk(chunk, col.compression),
+                            col.compression)
                 except PD._Unsupported as e:
                     raise PD._Unsupported(f"{a.name}: {e}") from e
-                # footer statistics -> value range: device-decoded
-                # columns never pass through a host array, so the
-                # upload-time min/max pass (columnar.batch.
-                # host_value_range) can't see them; the writer's chunk
-                # stats carry the same proof for free
-                chunks[a.name] = _StagedChunk(
-                    *staged, col.compression,
-                    _pq_stats_vrange(a.data_type, col))
             with obs_span("scan.host_decode", columns=len(plan.rest), rg=rg):
                 hb = None
                 if plan.rest:
@@ -1183,7 +1172,6 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
         from spark_rapids_tpu.io import parquet_device as PD
 
         encoded_ok = conf.get(C3.ENCODED_ENABLED)
-        fixed_ok = encoded_ok and conf.get(C3.ENCODED_FIXED_DICTIONARIES)
         max_frac = conf.get(C3.ENCODED_MAX_DICT_FRACTION)
         rows = item.rows
         with obs_span("scan.rowgroup", path=plan.split.path, rg=item.rg,
@@ -1199,22 +1187,14 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
                             max_def=plan.max_def.get(a.name, 1),
                             cap=bucket_capacity(max(rows, 1)),
                             codec=staged_chunk.codec,
-                            flba_len=plan.flba_len.get(a.name, 0),
-                            encoded_ok=(
-                                encoded_ok
-                                and a.data_type is DataType.STRING)
-                            or (fixed_ok and a.data_type in (
-                                DataType.INT64, DataType.DATE,
-                                DataType.TIMESTAMP)),
+                            encoded_ok=encoded_ok,
                             max_dict_fraction=max_frac,
-                            pages=staged_chunk.pages, flat=staged_chunk.flat)
+                            pages=staged_chunk.pages)
                 except PD._Unsupported as e:
                     raise PD._Unsupported(f"{a.name}: {e}") from e
                 if ENC.is_encoded(cv):
                     ENC.record_scan_emission(cv, rows)
-                cv.vrange = staged_chunk.vrange
                 dev_cols[a.name] = cv
-            verify_footer_vranges(dev_cols)
             return self._assemble_staged(
                 dev_cols, item.host, plan.rest, plan.pv, rows, conf)
 
@@ -1232,7 +1212,6 @@ class _SplitPlan:
     rest: List[AttributeReference] = field(default_factory=list)
     groups: List[int] = field(default_factory=list)
     max_def: Dict[str, int] = field(default_factory=dict)
-    flba_len: Dict[str, int] = field(default_factory=dict)
     done: int = 0                    # row groups gone downstream
 
 
@@ -1242,9 +1221,7 @@ class _StagedChunk:
 
     data: bytes                      # decompressed (`PD.stage_chunk`)
     pages: list                      # PageInfo, offsets into `data`
-    flat: Any                        # the whole-chunk decode's plan
     codec: str                       # what the file held
-    vrange: Optional[Tuple[int, int]]  # from the footer's statistics
 
 
 @dataclass

@@ -1335,18 +1335,15 @@ class _Analyzer:
                 decoded_bytes_per_row,
             )
 
-            # per-claim decoded estimate: the string estimate for STRING
-            # columns, physical width + validity for fixed dictionary
-            # columns — the measured encodedBytesSaved metric's own
-            # formula (columnar/encoded.record_scan_emission)
-            dt_by_name = {a.name: a.data_type for a in node.output}
-            per_rows = {n: max(0, decoded_bytes_per_row(
-                dt_by_name.get(n, DataType.STRING)) - CODE_BYTES_PER_ROW)
-                for n in enc}
-            cert_saved = sum(per_rows[n] for n, s in enc.items()
-                             if s == "certain")
-            all_saved = sum(per_rows.values())
+            # per-claim decoded estimate: a scan emits STRING columns
+            # encoded and no others (parquet_device.column_eligible), so
+            # the string estimate — the measured encodedBytesSaved
+            # metric's own formula (columnar/encoded.record_scan_emission)
+            per_row = max(0, decoded_bytes_per_row(DataType.STRING)
+                          - CODE_BYTES_PER_ROW)
             n_cert = sum(1 for s in enc.values() if s == "certain")
+            cert_saved = per_row * n_cert
+            all_saved = per_row * len(enc)
             r = self.report
             r.encoded_cols += len(enc)
             r.encoded_saved = r.encoded_saved.add(

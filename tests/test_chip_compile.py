@@ -221,33 +221,44 @@ def test_q1_aggregate_update_kernel_lowers_in_f32(smoke_programs, one_chip):
 
 def test_parquet_decode_programs(device_flavour, one_chip,
                                  no_persistent_cache, tmp_path):
-    """Device decode of one SF1-sized row group: a DOUBLE price column
-    (PLAIN, narrowed to f32 at decode) and a dictionary DATE column."""
+    """Device decode of one SF1-sized row group of what the device
+    decoder takes, dictionary strings: a flag column kept as codes
+    (l_returnflag's shape) and a mode column gathered through its
+    dictionary. (Fixed-width columns are Arrow's: no decode program.)"""
     import pyarrow as pa
     import pyarrow.parquet as pq
 
+    from spark_rapids_tpu.columnar import encoded as ENC
     from spark_rapids_tpu.columnar.dtypes import DataType
     from spark_rapids_tpu.io import parquet_device as PD
 
     rows = ROW_GROUP_CAP - 1234
     rng = np.random.default_rng(0)
     path = str(tmp_path / "rg.parquet")
+    modes = np.array(["AIR", "FOB", "MAIL", "RAIL", "REG AIR", "SHIP",
+                      "TRUCK"], dtype=object)
     pq.write_table(pa.table({
-        "price": (rng.random(rows) * 100_000).round(2),
-        "shipdate": pa.array(rng.integers(8036, 10562, rows)
-                             .astype(np.int32)).cast(pa.date32()),
+        "returnflag": np.array(["A", "N", "R"],
+                               dtype=object)[rng.integers(0, 3, rows)],
+        "shipmode": pa.array(modes[rng.integers(0, 7, rows)],
+                             mask=rng.random(rows) < 0.01),
     }), path, compression="snappy", row_group_size=ROW_GROUP_CAP)
     pf = pq.ParquetFile(path)
     rg = pf.metadata.row_group(0)
     start = len(device_flavour.calls)
-    for ci, dt in ((0, DataType.FLOAT64), (1, DataType.DATE)):
+    for ci, encoded_ok in ((0, True), (1, False)):
         col = rg.column(ci)
+        assert PD.column_eligible(col, DataType.STRING)
         cv = PD.decode_chunk_device(
-            PD.read_chunk_bytes(path, col), dt, rows,
+            PD.read_chunk_bytes(path, col), DataType.STRING, rows,
             max_def=pf.schema.column(ci).max_definition_level,
-            cap=ROW_GROUP_CAP, codec=col.compression)
-        assert cv.data.shape == (ROW_GROUP_CAP,)
-        assert cv.data.dtype == (np.float32 if ci == 0 else np.int32)
+            cap=ROW_GROUP_CAP, codec=col.compression,
+            encoded_ok=encoded_ok)
+        assert ENC.is_encoded(cv) == encoded_ok
+        assert cv.validity.shape == (ROW_GROUP_CAP,)
+        if encoded_ok:
+            assert cv.data.shape == (ROW_GROUP_CAP,)
+            assert cv.data.dtype == np.int32
     decode = [c for c in device_flavour.calls[start:]
               if c[0].__code__.co_filename.endswith("io/parquet_device.py")]
     assert len(decode) >= 2

@@ -144,10 +144,11 @@ def test_device_decode_error_reaches_the_script(smoke, monkeypatch):
     """A device decoder that fails (as a compiler or runtime error on the
     chip would make it) is not turned into a host decode of the split:
     with the fallback keys as the script sets them the error ends the
-    query."""
+    query. q1 reads the decoder's columns, its two strings (q6 reads
+    none: fixed-width columns are Arrow's)."""
     _failing_decoder(monkeypatch, RuntimeError("injected decode fault"))
     with pytest.raises(Exception, match="injected decode fault"):
-        chip_smoke.run_query("q6", smoke.dev, smoke.dev_tables,
+        chip_smoke.run_query("q1", smoke.dev, smoke.dev_tables,
                              smoke.ref_tables)
 
 
@@ -161,14 +162,14 @@ def test_refused_page_shape_is_counted_and_fails_the_smoke(
     from spark_rapids_tpu.io import parquet_device as PD
 
     _failing_decoder(monkeypatch, PD._Unsupported("injected page shape"))
-    rows = tpch.q6(smoke.dev_tables).collect()
+    rows = tpch.q1(smoke.dev_tables).collect()
     metrics = dict(smoke.dev.last_query_metrics)
-    want = tpch.q6(smoke.ref_tables).collect()
+    want = tpch.q1(smoke.ref_tables).collect()
     chip_smoke._harness().assert_rows_equal(
         want, rows, approx_float=chip_smoke.FLOAT_TOLERANCE)
     assert metrics["cpuFallbackEvents"] >= chip_smoke.FILES_PER_TABLE
     with pytest.raises(chip_smoke.SmokeFailure, match="cpuFallbackEvents"):
-        chip_smoke.run_query("q6", smoke.dev, smoke.dev_tables,
+        chip_smoke.run_query("q1", smoke.dev, smoke.dev_tables,
                              smoke.ref_tables)
     with pytest.raises(chip_smoke.SmokeFailure, match="cpuFallbackEvents"):
         chip_smoke.run_write(smoke.dev, smoke.dev_tables, smoke.ref_tables,

@@ -331,13 +331,10 @@ def test_max_dict_fraction_gates_encoding(session, tmp_path):
     tbl = pa.table({"u": uniq, "v": rng.integers(0, 10, size=n)})
     path = str(tmp_path / "uniq.parquet")
     pq.write_table(tbl, path, use_dictionary=True)
-    # fixed dictionaries off: the low-cardinality INT column would
-    # (correctly) encode and mask the string heuristic this test pins
+    # (the low-cardinality INT column beside it is Arrow's: a scan emits
+    # string columns encoded and no others)
     run_on_tpu(session, lambda s: s.read.parquet(path)
-               .filter(F.col("v") >= F.lit(0)),
-               extra_conf={
-                   "rapids.tpu.sql.encoded.fixedDictionaries.enabled":
-                   False})
+               .filter(F.col("v") >= F.lit(0)))
     assert session.last_query_metrics["encodedColumns"] == 0
 
 
@@ -668,9 +665,11 @@ HOST_LOOP = {"rapids.tpu.sql.spmd.enabled": False}
 
 
 def _write_sorted_lowcard(tmp_path, seed=0, n=4000, name="rr.parquet",
-                          nulls=False):
-    """Sorted / low-cardinality columns: RLE-friendly (run tables attach)
-    AND dictionary-encoded — the run-aware + rank-space flagship shape."""
+                          nulls=False, tier=False):
+    """Sorted / low-cardinality columns: RLE-friendly (run tables attach
+    to the string ones, which the device decodes) AND dictionary-encoded
+    — the run-aware + rank-space flagship shape. `tier`: `grp` as a
+    string beside it, a second key that carries runs."""
     rng = np.random.default_rng(seed)
     status = np.sort(rng.choice(["open", "closed", "pending"],
                                 size=n)).astype(object)
@@ -679,9 +678,12 @@ def _write_sorted_lowcard(tmp_path, seed=0, n=4000, name="rr.parquet",
     if nulls:
         flag = np.where(rng.random(n) < 0.05, None, flag)
     v = rng.integers(0, 10_000, size=n)
-    tbl = pa.table({"status": status, "grp": grp, "flag": flag, "v": v})
+    cols = {"status": status, "grp": grp, "flag": flag, "v": v}
+    if tier:
+        cols["tier"] = np.array([f"t{g}" for g in grp], dtype=object)
     path = str(tmp_path / name)
-    pq.write_table(tbl, path, use_dictionary=True, row_group_size=2500)
+    pq.write_table(pa.table(cols), path, use_dictionary=True,
+                   row_group_size=2500)
     return path
 
 
@@ -811,30 +813,6 @@ def test_sort_and_range_bounds_decode_pragmas_gone():
               "exchange.py").read_text()
     assert "range bounds need VALUES" not in ex_src
     assert "codes order is not value order" not in ex_src
-
-
-def test_int64_dictionary_chunks(session, tmp_path):
-    """INT64 dictionary-encoded chunks emit encoded columns (ROADMAP
-    item 5): group-by on codes, min/max + comparisons in rank space,
-    oracle-equal; fixedDictionaries.enabled=False restores PR 9
-    behavior."""
-    path = _write_sorted_lowcard(tmp_path, seed=4)
-
-    def q(s):
-        return s.read.parquet(path) \
-            .filter(F.col("grp") >= F.lit(2)) \
-            .groupBy("grp").agg(F.count("*").alias("c"),
-                                F.min("grp").alias("mn"))
-
-    assert_tpu_and_cpu_are_equal_collect(session, q, ignore_order=True,
-                                         extra_conf=HOST_LOOP)
-    m_on = dict(session.last_query_metrics)
-    assert m_on["encodedColumns"] > 0
-    off = run_on_tpu(session, q, extra_conf={
-        **HOST_LOOP,
-        "rapids.tpu.sql.encoded.fixedDictionaries.enabled": False})
-    cpu = run_on_cpu(session, q)
-    assert sorted(off) == sorted(cpu)
 
 
 def test_orc_dictionary_emission(session, tmp_path):
@@ -988,17 +966,17 @@ def test_run_tables_attach_and_survive_concat(session, tmp_path):
 @pytest.mark.parametrize("seed", SEEDS[:2])
 def test_run_collapsed_aggregate_oracle_equal(session, tmp_path, seed):
     """Sorted/low-cardinality scan -> the update batch collapses to one
-    row per merged run: counts become run-length sums, integral sums
-    become value x run_length, min/max/filters evaluate per run —
-    oracle-equal with runCollapsedRows > 0."""
-    path = _write_sorted_lowcard(tmp_path, seed=seed)
+    row per merged run: counts become run-length sums, min/max/filters
+    evaluate per run — oracle-equal with runCollapsedRows > 0. Both keys
+    are strings: the columns the scan leaves as codes with a run table."""
+    path = _write_sorted_lowcard(tmp_path, seed=seed, tier=True)
 
     def q(s):
         return s.read.parquet(path) \
             .filter(F.col("status") != F.lit("zzz")) \
-            .groupBy("status", "grp").agg(
-                F.count("*").alias("c"), F.sum("grp").alias("t"),
-                F.min("grp").alias("mn"), F.max("status").alias("mx"))
+            .groupBy("status", "tier").agg(
+                F.count("*").alias("c"), F.count("tier").alias("t"),
+                F.min("tier").alias("mn"), F.max("status").alias("mx"))
 
     assert_tpu_and_cpu_are_equal_collect(session, q, ignore_order=True,
                                          extra_conf=HOST_LOOP)

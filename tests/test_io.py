@@ -187,10 +187,31 @@ class TestPartitionedReads:
             ignore_order=True)
 
 
+@pytest.fixture
+def device_chunks(monkeypatch):
+    """The (dtype, codec) of every column chunk that reaches the device
+    parquet decoder in the test. Only STRING chunks may (PR 30: a
+    fixed-width column is Arrow's, whatever its pages look like)."""
+    from spark_rapids_tpu.columnar.dtypes import DataType
+    from spark_rapids_tpu.io import parquet_device as PD
+
+    seen = []
+    orig = PD.decode_chunk_device
+
+    def spy(chunk, dtype, *a, **k):
+        seen.append((dtype, k.get("codec", "UNCOMPRESSED")))
+        return orig(chunk, dtype, *a, **k)
+
+    monkeypatch.setattr(PD, "decode_chunk_device", spy)
+    yield seen
+    assert all(dt is DataType.STRING for dt, _ in seen), seen
+
+
 class TestDeviceParquetDecode:
-    """Device-side parquet decode (io/parquet_device.py) vs the Arrow oracle
-    (reference: GpuParquetScan decodes on the accelerator,
-    GpuParquetScan.scala:536-556)."""
+    """The parquet scan vs the Arrow oracle: fixed-width columns through
+    Arrow's read, the string column through the device decoder
+    (io/parquet_device.py; reference: GpuParquetScan decodes on the
+    accelerator, GpuParquetScan.scala:536-556)."""
 
     def _write(self, tmp_path, name="d.parquet", compression="NONE",
                n=3000, row_group_size=None):
@@ -213,67 +234,55 @@ class TestDeviceParquetDecode:
                        row_group_size=row_group_size or n)
         return path
 
-    def test_device_decode_equivalence(self, session, tmp_path):
+    def test_device_decode_equivalence(self, session, tmp_path,
+                                       device_chunks):
         from tests.harness import assert_tpu_and_cpu_are_equal_collect
 
         path = self._write(tmp_path)
         assert_tpu_and_cpu_are_equal_collect(
             session, lambda s: s.read.parquet(path), ignore_order=True)
 
-    def test_device_decode_multi_row_groups(self, session, tmp_path):
+    def test_device_decode_multi_row_groups(self, session, tmp_path,
+                                            device_chunks):
         from tests.harness import assert_tpu_and_cpu_are_equal_collect
 
         path = self._write(tmp_path, row_group_size=700)
         assert_tpu_and_cpu_are_equal_collect(
             session, lambda s: s.read.parquet(path), ignore_order=True)
 
-    def test_snappy_decodes_on_device(self, session, tmp_path, monkeypatch):
-        # real-world parquet is snappy: the device decode path must engage
-        # (host page decompression feeding the same device expansion), not
-        # silently fall back to Arrow
+    def test_snappy_decodes_on_device(self, session, tmp_path,
+                                      device_chunks):
+        # real-world parquet is snappy: the string column's device decode
+        # must engage (host page decompression feeding the same device
+        # expansion), not silently fall back to Arrow; the fixed-width
+        # columns beside it never reach the decoder
         from tests.harness import assert_tpu_and_cpu_are_equal_collect
-        from spark_rapids_tpu.io import parquet_device as PD
+        from spark_rapids_tpu.columnar.dtypes import DataType
 
-        calls = []
-        orig = PD.decode_chunk_device
-
-        def spy(*a, **k):
-            out = orig(*a, **k)
-            calls.append(k.get("codec", "UNCOMPRESSED"))
-            return out
-
-        monkeypatch.setattr(PD, "decode_chunk_device", spy)
         path = self._write(tmp_path, name="snappy.parquet",
                            compression="SNAPPY")
         assert_tpu_and_cpu_are_equal_collect(
             session, lambda s: s.read.parquet(path), ignore_order=True)
-        assert "SNAPPY" in calls, calls
+        assert device_chunks == [(DataType.STRING, "SNAPPY")]
 
-    def test_gzip_decodes_on_device(self, session, tmp_path):
+    def test_gzip_decodes_on_device(self, session, tmp_path, device_chunks):
         from tests.harness import assert_tpu_and_cpu_are_equal_collect
+        from spark_rapids_tpu.columnar.dtypes import DataType
 
         path = self._write(tmp_path, name="gz.parquet", compression="GZIP")
         assert_tpu_and_cpu_are_equal_collect(
             session, lambda s: s.read.parquet(path), ignore_order=True)
+        assert device_chunks == [(DataType.STRING, "GZIP")]
 
-    def test_v2_pages_decode_on_device(self, session, tmp_path, monkeypatch):
+    def test_v2_pages_decode_on_device(self, session, tmp_path,
+                                       device_chunks):
         # v2 data pages: unprefixed def levels ahead of the data section
         import numpy as np
         import pyarrow as pa
         import pyarrow.parquet as pq
 
         from tests.harness import assert_tpu_and_cpu_are_equal_collect
-        from spark_rapids_tpu.io import parquet_device as PD
 
-        calls = []
-        orig = PD.decode_chunk_device
-
-        def spy(*a, **k):
-            out = orig(*a, **k)
-            calls.append(1)
-            return out
-
-        monkeypatch.setattr(PD, "decode_chunk_device", spy)
         n = 3000
         rng = np.random.default_rng(5)
         t = pa.table({
@@ -286,10 +295,10 @@ class TestDeviceParquetDecode:
             path = str(tmp_path / f"v2_{comp}.parquet")
             pq.write_table(t, path, compression=comp, use_dictionary=True,
                            data_page_version="2.0")
-            calls.clear()
+            device_chunks.clear()
             assert_tpu_and_cpu_are_equal_collect(
                 session, lambda s: s.read.parquet(path), ignore_order=True)
-            assert calls, comp
+            assert device_chunks, comp
 
     def test_unsupported_codec_falls_back_correctly(self, session, tmp_path):
         # parquet LZ4's framing differs from Arrow's lz4 codec: stays on the
@@ -302,10 +311,9 @@ class TestDeviceParquetDecode:
         assert_tpu_and_cpu_are_equal_collect(
             session, lambda s: s.read.parquet(path), ignore_order=True)
 
-    def test_decode_kernel_matches_arrow_directly(self, tmp_path):
-        import numpy as np
+    def test_fixed_width_chunk_matches_arrow_and_is_not_the_decoders(
+            self, session, tmp_path):
         import pyarrow.parquet as pq
-        import jax
 
         from spark_rapids_tpu.columnar.dtypes import DataType
         from spark_rapids_tpu.io import parquet_device as PD
@@ -315,17 +323,15 @@ class TestDeviceParquetDecode:
         md = pf.metadata
         want = pf.read().column("i32n").to_pylist()
         col = md.row_group(0).column(1)
-        assert PD.column_eligible(col, DataType.INT32)
-        chunk = PD.read_chunk_bytes(path, col)
-        cv = PD.decode_chunk_device(
-            chunk, DataType.INT32, md.row_group(0).num_rows, max_def=1)
-        got = np.asarray(jax.device_get(cv.data))
-        gv = np.asarray(jax.device_get(cv.validity))
-        for i, w in enumerate(want):
-            if w is None:
-                assert not gv[i]
-            else:
-                assert gv[i] and got[i] == w
+        # the rule (column_eligible) and the decoder's own refusal
+        assert not PD.column_eligible(col, DataType.INT32)
+        with pytest.raises(PD._Unsupported):
+            PD.decode_chunk_device(
+                PD.read_chunk_bytes(path, col), DataType.INT32,
+                md.row_group(0).num_rows, max_def=1)
+        got = [r[0] for r in
+               session.read.parquet(path).select("i32n").collect()]
+        assert got == want
 
     def test_string_dictionary_decodes_on_device(self, tmp_path):
         # BYTE_ARRAY dictionary chunk -> device string column: host parses
@@ -599,7 +605,8 @@ class TestDeviceParquetDecode:
         assert_tpu_and_cpu_are_equal_collect(
             session, lambda s: s.read.orc(path), ignore_order=True)
 
-    def test_required_columns_decode(self, session, tmp_path):
+    def test_required_columns_decode(self, session, tmp_path,
+                                     device_chunks):
         # required (non-nullable) columns carry no def levels (max_def=0)
         import numpy as np
         import pyarrow as pa
@@ -620,7 +627,8 @@ class TestDeviceParquetDecode:
         assert_tpu_and_cpu_are_equal_collect(
             session, lambda s: s.read.parquet(path), ignore_order=True)
 
-    def test_device_decode_respects_batch_size_rows(self, session, tmp_path):
+    def test_device_decode_respects_batch_size_rows(self, session, tmp_path,
+                                                    device_chunks):
         from tests.harness import assert_tpu_and_cpu_are_equal_collect
 
         path = self._write(tmp_path, name="big.parquet", n=2000)
@@ -1008,23 +1016,14 @@ class TestDeviceOrcMoreTypes:
             ignore_order=True)
 
 
-def test_parquet_bool_decodes_on_device(session, tmp_path, monkeypatch):
-    """BOOLEAN columns decode on device: PLAIN LSB-first bit-packing (v1)
-    and length-prefixed RLE (v2)."""
+def test_parquet_bool_decodes_on_device(session, tmp_path, device_chunks):
+    """BOOLEAN columns, PLAIN LSB-first bit-packing (v1) and
+    length-prefixed RLE (v2): Arrow's, like every fixed-width column; the
+    device decoder is not entered."""
     import numpy as np
     import pyarrow as pa
     import pyarrow.parquet as pq
 
-    from spark_rapids_tpu.io import parquet_device as PD
-
-    calls = []
-    orig = PD.decode_chunk_device
-
-    def spy(*a, **k):
-        calls.append(1)
-        return orig(*a, **k)
-
-    monkeypatch.setattr(PD, "decode_chunk_device", spy)
     rng = np.random.default_rng(25)
     n = 5000
     bools = [bool(x) if i % 9 else None
@@ -1037,20 +1036,19 @@ def test_parquet_bool_decodes_on_device(session, tmp_path, monkeypatch):
         path = str(tmp_path / f"pb_{ver}.parquet")
         pq.write_table(t, path, compression="SNAPPY",
                        data_page_version=ver)
-        calls.clear()
         assert_tpu_and_cpu_are_equal_collect(
             session,
             lambda s: s.read.parquet(path)
             .groupBy("b").agg(F.count("*").alias("n"),
                               F.sum("k").alias("sk")),
             ignore_order=True)
-        assert calls, ver
+        assert not device_chunks, ver
 
 
 class TestParquetDeltaBinaryPacked:
-    """DELTA_BINARY_PACKED integral pages decode on device: miniblock bit
-    unpack + ONE cumsum (reference decodes delta pages in cuDF behind
-    GpuParquetScan.scala:536-556)."""
+    """DELTA_BINARY_PACKED integral pages: Arrow's, like every
+    fixed-width column (the delta kernel serves DELTA_LENGTH_BYTE_ARRAY
+    strings' lengths)."""
 
     def _write(self, tmp_path, name, n=5000, nulls=False, comp="NONE"):
         import numpy as np
@@ -1078,25 +1076,16 @@ class TestParquetDeltaBinaryPacked:
             data_page_version="2.0", version="2.6")
         return path
 
-    def test_delta_decodes_on_device(self, session, tmp_path, monkeypatch):
+    def test_delta_decodes_on_device(self, session, tmp_path,
+                                     device_chunks):
         from tests.harness import assert_tpu_and_cpu_are_equal_collect
-        from spark_rapids_tpu.io import parquet_device as PD
 
-        calls = []
-        orig = PD._expand_delta
-
-        def spy(*a, **k):
-            calls.append(1)
-            return orig(*a, **k)
-
-        monkeypatch.setattr(PD, "_expand_delta", spy)
         for comp, nulls in (("NONE", False), ("SNAPPY", True)):
             path = self._write(tmp_path, f"delta_{comp}.parquet",
                                nulls=nulls, comp=comp)
-            calls.clear()
             assert_tpu_and_cpu_are_equal_collect(
                 session, lambda s: s.read.parquet(path), ignore_order=True)
-            assert calls, f"{comp}: delta device decode did not engage"
+            assert not device_chunks, comp
 
     def test_delta_agg_equivalence(self, session, tmp_path):
         from tests.harness import assert_tpu_and_cpu_are_equal_collect
@@ -1116,10 +1105,9 @@ class TestParquetDeltaBinaryPacked:
 
 
 class TestParquetDeltaLengthAndBSS:
-    """DELTA_LENGTH_BYTE_ARRAY strings (lengths ride the delta cumsum
-    kernel, starts are a device exclusive-sum) and BYTE_STREAM_SPLIT
-    fixed-width columns (strided plane gathers + bitcast) decode on
-    device."""
+    """DELTA_LENGTH_BYTE_ARRAY strings decode on device (lengths ride the
+    delta cumsum kernel, starts are a device exclusive-sum); the
+    BYTE_STREAM_SPLIT fixed-width columns beside them are Arrow's."""
 
     def _write(self, tmp_path, name, comp="NONE", n=4000):
         import numpy as np
@@ -1144,27 +1132,30 @@ class TestParquetDeltaLengthAndBSS:
             data_page_version="2.0", version="2.6")
         return path
 
-    def test_decodes_on_device(self, session, tmp_path, monkeypatch):
+    def test_decodes_on_device(self, session, tmp_path, monkeypatch,
+                               device_chunks):
         from tests.harness import assert_tpu_and_cpu_are_equal_collect
+        from spark_rapids_tpu.columnar.dtypes import DataType
         from spark_rapids_tpu.io import parquet_device as PD
 
         calls = []
-        for fname in ("_expand_delta", "_decode_bss"):
-            orig = getattr(PD, fname)
+        orig = PD._expand_delta
 
-            def spy(*a, _orig=orig, _f=fname, **k):
-                calls.append(_f)
-                return _orig(*a, **k)
+        def spy(*a, **k):
+            calls.append(1)
+            return orig(*a, **k)
 
-            monkeypatch.setattr(PD, fname, spy)
-        for comp in ("NONE", "SNAPPY"):
+        monkeypatch.setattr(PD, "_expand_delta", spy)
+        for comp, codec in (("NONE", "UNCOMPRESSED"), ("SNAPPY", "SNAPPY")):
             path = self._write(tmp_path, f"dlba_{comp}.parquet", comp=comp)
             calls.clear()
+            device_chunks.clear()
             assert_tpu_and_cpu_are_equal_collect(
                 session, lambda s: s.read.parquet(path), ignore_order=True,
                 approx_float=1e-6)
-            assert "_expand_delta" in calls, f"{comp}: delta-length strings"
-            assert "_decode_bss" in calls, f"{comp}: byte-stream-split"
+            assert calls, f"{comp}: delta-length strings"
+            # the byte-stream-split columns did not reach the decoder
+            assert device_chunks == [(DataType.STRING, codec)]
 
     def test_string_ops_after_delta_length_scan(self, session, tmp_path):
         from tests.harness import assert_tpu_and_cpu_are_equal_collect
@@ -1182,9 +1173,9 @@ class TestParquetDeltaLengthAndBSS:
 
 
 class TestParquetDecimalDeviceDecode:
-    """FLBA-physical decimal columns decode on device: big-endian unscaled
-    fold (plain + dictionary pages), precision <= 18 guarantees the value
-    fits int64."""
+    """FLBA-physical decimal columns (plain + dictionary pages; precision
+    <= 18, so the value fits int64): Arrow's, like every fixed-width
+    column."""
 
     def _write(self, tmp_path, name, comp="NONE", use_dict=True, n=2500):
         from decimal import Decimal
@@ -1210,24 +1201,15 @@ class TestParquetDecimalDeviceDecode:
 
     @pytest.mark.parametrize("use_dict,comp", [
         (True, "NONE"), (False, "NONE"), (True, "SNAPPY")])
-    def test_decimal_decodes_on_device(self, session, tmp_path, monkeypatch,
-                                       use_dict, comp):
+    def test_decimal_decodes_on_device(self, session, tmp_path,
+                                       device_chunks, use_dict, comp):
         from tests.harness import assert_tpu_and_cpu_are_equal_collect
-        from spark_rapids_tpu.io import parquet_device as PD
 
-        calls = []
-        orig = PD._fold_flba_be
-
-        def spy(*a, **k):
-            calls.append(1)
-            return orig(*a, **k)
-
-        monkeypatch.setattr(PD, "_fold_flba_be", spy)
         path = self._write(tmp_path, f"dec_{use_dict}_{comp}.parquet",
                            comp=comp, use_dict=use_dict)
         assert_tpu_and_cpu_are_equal_collect(
             session, lambda s: s.read.parquet(path), ignore_order=True)
-        assert calls, "FLBA decimal device decode did not engage"
+        assert not device_chunks
 
     def test_decimal_agg_after_device_scan(self, session, tmp_path):
         from tests.harness import assert_tpu_and_cpu_are_equal_collect

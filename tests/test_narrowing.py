@@ -446,24 +446,26 @@ class TestParquetStatsVrange:
             write_statistics=stats)
         return path
 
-    def test_stats_attach_vrange(self, tmp_path):
-        import pyarrow.parquet as pq
+    def _scanned_vrange(self, path, dt):
+        """The column as the parquet scan hands it to the device: Arrow's
+        read of the split, `arrow_to_host_batch`, `to_device` (io/scan.py
+        `_read_host`). The range is proven from the VALUES at upload
+        (columnar.batch.host_value_range), whatever the footer says."""
+        from spark_rapids_tpu.io.arrow_convert import arrow_to_host_batch
+        from spark_rapids_tpu.io.scan import FileSplit, read_split
+        from spark_rapids_tpu.ops.base import AttributeReference
 
-        from spark_rapids_tpu.io.scan import _pq_stats_vrange
+        attrs = [AttributeReference("a", dt)]
+        table = read_split(FileSplit(path, "parquet"), attrs)
+        return arrow_to_host_batch(table, attrs).to_device().columns[0].vrange
 
+    def test_scan_attaches_vrange(self, tmp_path):
         path = self._write(tmp_path, [5, -2, 100])
-        col = pq.ParquetFile(path).metadata.row_group(0).column(0)
-        assert _pq_stats_vrange(DataType.INT64, col) == (-2, 127)
-        assert _pq_stats_vrange(DataType.INT32, col) is None
+        assert self._scanned_vrange(path, DataType.INT64) == (-2, 127)
 
-    def test_no_stats_no_vrange(self, tmp_path):
-        import pyarrow.parquet as pq
-
-        from spark_rapids_tpu.io.scan import _pq_stats_vrange
-
+    def test_no_stats_same_vrange(self, tmp_path):
         path = self._write(tmp_path, [5, -2, 100], stats=False)
-        col = pq.ParquetFile(path).metadata.row_group(0).column(0)
-        assert _pq_stats_vrange(DataType.INT64, col) is None
+        assert self._scanned_vrange(path, DataType.INT64) == (-2, 127)
 
     def test_orc_footer_stats_vrange(self, tmp_path):
         import pyarrow as pa
@@ -486,7 +488,7 @@ class TestParquetStatsVrange:
 
     def test_device_scan_carries_vrange_and_is_exact(self, session,
                                                      tmp_path):
-        # end-to-end: device-decoded column + footer range + agg, vs oracle
+        # end-to-end: scanned column + its value range + agg, vs oracle
         vals = [int(x) for x in
                 np.random.default_rng(7).integers(-10**6, 10**6, 2000)]
         path = self._write(tmp_path, vals)

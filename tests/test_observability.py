@@ -85,9 +85,10 @@ def _write_source(session, num_partitions=3):
     return df.filter(F.col("d") >= 0).withColumn("w", F.col("v") * 2.0), n
 
 
-def _parquet_source(session, tmp_path, row_groups=2):
+def _parquet_source(session, tmp_path, row_groups=2, string=False):
     """lineitem-like files on disk (dictionary-encoded snappy, several
-    row groups) and the DataFrame that scans them on the device."""
+    row groups) and the DataFrame that scans them on the device.
+    `string`: with a flag column, the kind the device decodes."""
     import pyarrow as pa
     import pyarrow.parquet as pq
 
@@ -96,10 +97,13 @@ def _parquet_source(session, tmp_path, row_groups=2):
     root = tmp_path / "src"
     root.mkdir()
     for i in range(2):
-        table = pa.table({
+        cols = {
             "q": rng.integers(1, 51, rows * row_groups).astype(np.int64),
             "p": rng.integers(0, 1000, rows * row_groups).astype(np.int32),
-            "x": rng.integers(0, 11, rows * row_groups) / 100.0})
+            "x": rng.integers(0, 11, rows * row_groups) / 100.0}
+        if string:
+            cols["s"] = [f"flag{j % 3}" for j in range(rows * row_groups)]
+        table = pa.table(cols)
         pq.write_table(table, str(root / f"f{i}.parquet"),
                        row_group_size=rows, compression="snappy")
     return session.read.parquet(str(root)), root
@@ -222,14 +226,54 @@ def test_traced_write_span_tree_and_accounting(session, tmp_path, encoder):
                    for s in d2h)
 
 
+def test_traced_fixed_width_parquet_scan_spans(tmp_path):
+    """A scan without a string column is Arrow's (PR 30): a split leaves
+    one scan.host_decode (`columns`, `rows`), opened on the prefetcher's
+    thread under the task's span, and one scan.upload (`bytes`) a batch,
+    on the task's thread and after its permit; no span of the device
+    decoder. A task waiting for the admission permit is no deeper in the
+    tree than a permit holder's upload."""
+    # one permit: with two splits the second task waits in the semaphore
+    session = srt.new_session({C.CONCURRENT_TPU_TASKS.key: 1,
+                               "rapids.tpu.sql.spmd.meshDevices": 1,
+                               C.OBS_TRACING.key: True})
+    try:
+        df, _ = _parquet_source(session, tmp_path)
+        df.filter(F.col("p") < 500).agg(F.sum("x"), F.sum("q")).collect()
+        trace = session.last_query_trace
+        assert session.last_query_metrics[M.CPU_FALLBACK_EVENTS] == 0
+    finally:
+        session.stop()
+
+    for name in ("scan.split", "scan.read", "scan.parse", "scan.rowgroup",
+                 "scan.decode"):
+        assert not trace.find(name), name
+    tasks = [sp for sp in trace.spans()
+             if any(c.name == "scan.host_decode" for c in sp.children)]
+    assert len(tasks) == 2
+    for task in tasks:
+        (decode,) = [c for c in task.children if c.name == "scan.host_decode"]
+        assert decode.attrs == {"columns": 3, "rows": 2 * 4096}
+        assert decode.tid != task.tid  # the reader thread's
+        (asked,) = [c for c in task.children
+                    if c.name == "Acquire TPU Semaphore"]
+        (upload,) = [c for c in task.children if c.name == "scan.upload"]
+        assert upload.tid == task.tid and upload.attrs["columns"] == 3
+        # q and p as int32 (q narrows on its value range), x as f64 here,
+        # a validity byte each
+        assert upload.attrs["bytes"] >= 2 * 4096 * (4 + 4 + 8)
+        assert decode.end_ns <= upload.start_ns
+        assert asked.end_ns <= upload.start_ns
+
+
 def test_traced_parquet_scan_spans(tmp_path):
-    """One scan.read (the host half's, PR 29: before the task asks for
-    its permit) and one scan.decode (the device half's, under its
-    scan.rowgroup) a (row group, column); the bytes read are the footers'
-    compressed chunk sizes; a split's reads all end before the first of
-    its row groups begins; a task waiting for the admission permit is
-    SHALLOWER in the tree than a permit holder's decode and no deeper than
-    its row group
+    """A scan with a string column: one scan.read (the host half's, PR 29:
+    before the task asks for its permit) and one scan.decode (the device
+    half's, under its scan.rowgroup) a (row group, string column); the
+    bytes read are the footers' compressed chunk sizes; a split's reads
+    all end before the first of its row groups begins; a task waiting for
+    the admission permit is SHALLOWER in the tree than a permit holder's
+    decode and no deeper than its row group
     (the benchmark labels an idle gap with the deepest open span: the
     worker's step, not the waiters)."""
     import pyarrow.parquet as pq
@@ -239,8 +283,9 @@ def test_traced_parquet_scan_spans(tmp_path):
                                "rapids.tpu.sql.spmd.meshDevices": 1,
                                C.OBS_TRACING.key: True})
     try:
-        df, root = _parquet_source(session, tmp_path)
-        df.filter(F.col("p") < 500).agg(F.sum("x"), F.sum("q")).collect()
+        df, root = _parquet_source(session, tmp_path, string=True)
+        df.filter(F.col("p") < 500) \
+            .agg(F.sum("x"), F.sum("q"), F.count("s")).collect()
         trace = session.last_query_trace
         assert session.last_query_metrics[M.CPU_FALLBACK_EVENTS] == 0
     finally:
@@ -252,8 +297,8 @@ def test_traced_parquet_scan_spans(tmp_path):
         for rg in range(md.num_row_groups):
             for ci in range(md.num_columns):
                 col = md.row_group(rg).column(ci)
-                expected[(str(f), rg, col.path_in_schema)] = \
-                    col.total_compressed_size
+                if col.path_in_schema == "s":  # the device decoder's
+                    expected[(str(f), rg, "s")] = col.total_compressed_size
     depth = {}
 
     def walk(sp, d):
@@ -271,7 +316,7 @@ def test_traced_parquet_scan_spans(tmp_path):
         (split,) = [c for c in task.children if c.name == "scan.split"]
         assert "fallback" not in split.attrs
         assert split.attrs["row_groups"] == 2
-        assert split.attrs["device_columns"] == 3
+        assert split.attrs["device_columns"] == 1
         path = split.attrs["path"]
         for c in task.children:
             if c.name != "scan.read":
@@ -295,11 +340,13 @@ def test_traced_parquet_scan_spans(tmp_path):
         for rg_span in rowgroups:
             assert rg_span.attrs["path"] == path
             assert rg_span.tid == task.tid
-            for c in rg_span.children:
-                assert c.name == "scan.decode"
-                key = (path, rg_span.attrs["rg"], c.attrs["column"])
-                assert key not in decodes
-                decodes[key] = c
+            # the string's decode, then the upload of what Arrow decoded
+            decode, rest = rg_span.children
+            assert (decode.name, rest.name) == ("scan.decode", "scan.upload")
+            assert rest.attrs["columns"] == 3 and rest.attrs["bytes"] > 0
+            key = (path, rg_span.attrs["rg"], decode.attrs["column"])
+            assert key not in decodes
+            decodes[key] = decode
     assert reads == expected
     assert set(decodes) == set(expected)
     assert len(trace.find("scan.rowgroup")) == 4
@@ -307,12 +354,8 @@ def test_traced_parquet_scan_spans(tmp_path):
         assert sp.attrs["codec"] == "SNAPPY" and sp.attrs["pages"] >= 1
         (upload,) = sp.children
         assert upload.name == "scan.upload"
-        # what goes up is the decompressed chunk, or (PR 26) the payload
-        # of its bit-packed index stream alone where the chunk is `packed`
-        if sp.attrs["expand"] == "packed":
-            assert parsed[key] > upload.attrs["bytes"] > 0
-        else:
-            assert parsed[key] == upload.attrs["bytes"] > 0
+        # what goes up is the decompressed chunk
+        assert parsed[key] == upload.attrs["bytes"] > 0
     waits = trace.find("Acquire TPU Semaphore")
     assert waits
     deepest_wait = max(depth[id(s)] for s in waits)

@@ -1,9 +1,10 @@
 """The device parquet scan in two halves (PR 29, io/scan.py): a HOST half
 that a task runs for its whole split before it asks for the admission
-permit (`TpuFileScanExec._stage_split`: footer, chunk reads,
+permit (`TpuFileScanExec._stage_split`: footer, the string chunks' reads,
 decompression and page walk, Arrow's decode of the columns the device
-decoder does not take) and a DEVICE half that alone runs under it
-(`_decode_staged`).
+decoder does not take: every fixed-width one, since PR 30) and a DEVICE
+half that alone runs under it (`_decode_staged`). A scan without a STRING
+column has no halves: it is the host decoder's.
 
 Pinned here: the host half touches neither jax nor the semaphore and runs
 while another task holds the permit; the rows equal the host decoder's at
@@ -41,7 +42,8 @@ ROWS = 2048  # a row group
 
 def _write_files(root, files=2, row_groups=3, seed=3):
     """lineitem-like files: an id that names every row, a dictionary INT64
-    with nulls, an INT32, a DOUBLE and a low-cardinality string."""
+    with nulls, an INT32, a DOUBLE (the `rest`: Arrow's) and a
+    low-cardinality string (the device decoder's)."""
     rng = np.random.default_rng(seed)
     root.mkdir(parents=True, exist_ok=True)
     paths = []
@@ -62,13 +64,6 @@ def _write_files(root, files=2, row_groups=3, seed=3):
 
 def _new_session(**conf):
     return srt.new_session({"rapids.tpu.sql.spmd.meshDevices": 1, **conf})
-
-
-@pytest.fixture
-def doubles_on_host(monkeypatch):
-    """The chip's column split on the CPU backend: the device decoder
-    refuses DOUBLE (a TPU has no f64), so `x` is Arrow's: the `rest`."""
-    monkeypatch.setattr(PD, "device_float64_supported", lambda: False)
 
 
 def _rows(session, root, **read_opts):
@@ -97,7 +92,7 @@ class _Forbidden:
 
 
 def test_host_half_makes_no_jax_call_and_takes_no_permit(
-        tmp_path, monkeypatch, doubles_on_host):
+        tmp_path, monkeypatch):
     (path,) = _write_files(tmp_path, files=1)
     conf = C.TpuConf()
     attrs = schema_attrs(pq.read_schema(path))
@@ -114,13 +109,14 @@ def test_host_half_makes_no_jax_call_and_takes_no_permit(
     items = list(scan._stage_split(plan))
     monkeypatch.undo()
 
-    assert [a.name for a in plan.eligible] == ["id", "q", "d", "s"]
-    assert [a.name for a in plan.rest] == ["x"]
+    # the string is the device decoder's, every fixed-width column Arrow's
+    assert [a.name for a in plan.eligible] == ["s"]
+    assert [a.name for a in plan.rest] == ["id", "q", "d", "x"]
     assert plan.groups == [0, 1, 2] and [it.rg for it in items] == [0, 1, 2]
     md = pq.ParquetFile(path).metadata
     for it in items:
         assert it.rows == ROWS
-        # Arrow's column, packed for its upload: host arrays only
+        # Arrow's columns, packed for their upload: host arrays only
         assert isinstance(it.host, StagedUpload)
         assert it.host.num_rows == ROWS
         assert all(isinstance(b, np.ndarray) for b in it.host.bufs)
@@ -128,96 +124,108 @@ def test_host_half_makes_no_jax_call_and_takes_no_permit(
             it.rg, columns=["x"]).column("x").to_numpy()
         (f64,) = [b for b in it.host.bufs if b.dtype == np.float64]
         assert np.array_equal(f64[:ROWS], want)
-        assert sorted(it.chunks) == ["d", "id", "q", "s"]
-        for ci in range(md.num_columns):
-            col = md.row_group(it.rg).column(ci)
-            if col.path_in_schema == "x":
-                continue
-            chunk = it.chunks[col.path_in_schema]
-            assert chunk.codec == "SNAPPY"
-            # decompressed: what the decoder's own first block would give
-            data, pages = PD.normalize_chunk(
-                PD.read_chunk_bytes(path, col), "SNAPPY")
-            assert chunk.data == data and chunk.pages == pages
-            assert isinstance(chunk.data, bytes)
-            # the whole-chunk decode is planned here too, in host arrays:
-            # `id` and `d` bit-packed throughout, `q` with real nulls,
-            # the string on the per-page loop
-            name = col.path_in_schema
-            if name == "s":
-                assert chunk.flat is None
-                continue
-            assert chunk.flat.val_form == \
-                ("runs" if name == "q" else "packed")
-            assert (chunk.flat.planes is None) == (name == "q")
-            for held in (chunk.flat.def_tab or ()) + \
-                    (chunk.flat.val_tab or ()) + (chunk.flat.nums,):
-                assert isinstance(held, np.ndarray)
+        assert sorted(it.chunks) == ["s"]
+        (col,) = [md.row_group(it.rg).column(ci)
+                  for ci in range(md.num_columns)
+                  if md.row_group(it.rg).column(ci).path_in_schema == "s"]
+        chunk = it.chunks["s"]
+        assert chunk.codec == "SNAPPY"
+        # decompressed: what the decoder's own first block would give
+        data, pages = PD.normalize_chunk(
+            PD.read_chunk_bytes(path, col), "SNAPPY")
+        assert chunk.data == data and chunk.pages == pages
+        assert isinstance(chunk.data, bytes)
 
 
 def test_a_staged_chunk_decodes_as_an_unstaged_one(tmp_path):
     """The decoder's seam: `decode_chunk_device` given `stage_chunk`'s
-    pages and plan issues the same programs on the same tables as when
-    it parses and plans for itself."""
+    pages issues the same programs on the same tables as when it parses
+    for itself; a fixed-width chunk is refused either way."""
     import jax
 
     (path,) = _write_files(tmp_path, files=1, row_groups=1)
     pf = pq.ParquetFile(path)
-    kinds = {"id": "long", "q": "long", "d": "int", "x": "double",
-             "s": "string"}
     for ci, attr in enumerate(schema_attrs(pf.schema_arrow)):
         col = pf.metadata.row_group(0).column(ci)
-        assert col.path_in_schema == attr.name and attr.name in kinds
+        assert col.path_in_schema == attr.name
         max_def = pf.schema.column(ci).max_definition_level
         raw = PD.read_chunk_bytes(path, col)
+        data, pages = PD.stage_chunk(raw, "SNAPPY")
+        if attr.name != "s":
+            assert not PD.column_eligible(col, attr.data_type)
+            with pytest.raises(PD._Unsupported):
+                PD.decode_chunk_device(data, attr.data_type, ROWS,
+                                       max_def=max_def, codec="SNAPPY",
+                                       pages=pages)
+            continue
+        assert PD.column_eligible(col, attr.data_type)
         alone = PD.decode_chunk_device(raw, attr.data_type, ROWS,
                                        max_def=max_def, codec="SNAPPY")
-        data, pages, flat = PD.stage_chunk(raw, "SNAPPY", attr.data_type,
-                                           ROWS, max_def)
-        assert (flat is None) == (attr.name == "s")
         staged = PD.decode_chunk_device(data, attr.data_type, ROWS,
                                         max_def=max_def, codec="SNAPPY",
-                                        pages=pages, flat=flat)
+                                        pages=pages)
         for a, b in zip(jax.tree_util.tree_leaves(alone),
                         jax.tree_util.tree_leaves(staged)):
             assert np.array_equal(np.asarray(a), np.asarray(b)), attr.name
-        # without a dtype the plan is left to the decoder
-        assert PD.stage_chunk(raw, "SNAPPY")[2] is PD._UNPLANNED
 
 
-def test_no_eligible_column_stages_nothing(tmp_path, monkeypatch):
+@pytest.mark.parametrize("why", ["the_string_chunk_is_not_eligible",
+                                 "no_string_attribute"])
+def test_no_eligible_column_stages_nothing(tmp_path, monkeypatch, why):
     """Where the decoder takes no column of the file the host half says so
-    before any read, and the scan is the host path's: no fallback event."""
+    before any read, and the scan is the host path's: no fallback event.
+    A scan without a STRING attribute does not even open the footer to
+    find that out: the host half is never entered."""
     (path,) = _write_files(tmp_path, files=1)
-    monkeypatch.setattr(PD, "column_eligible", lambda col, dt: False)
-    reads = []
+    reads, staged = [], []
     monkeypatch.setattr(PD, "read_chunk_bytes",
                         lambda *a: reads.append(a) or b"")
+    stage_split = SCAN.TpuFileScanExec._stage_split
+    monkeypatch.setattr(
+        SCAN.TpuFileScanExec, "_stage_split",
+        lambda self, plan: staged.append(plan) or stage_split(self, plan))
+    if why == "no_string_attribute":
+        columns = ["id", "q", "d", "x"]
+    else:
+        columns = ["id", "q", "d", "x", "s"]
+        monkeypatch.setattr(PD, "column_eligible", lambda col, dt: False)
     session = _new_session(**{C.OBS_TRACING.key: True})
     try:
-        rows = _rows(session, tmp_path)
+        rows = sorted(session.read.parquet(str(tmp_path)).select(*columns)
+                      .collect(), key=lambda r: r[0])
         metrics = dict(session.last_query_metrics)
         trace = session.last_query_trace
     finally:
         session.stop()
     assert [r[0] for r in rows] == list(range(3 * ROWS))
+    assert [r[3] for r in rows] == \
+        pq.read_table(path).column("x").to_pylist()
     assert metrics[M.CPU_FALLBACK_EVENTS] == 0 and not reads
     assert not trace.find("scan.rowgroup")
-    (split,) = trace.find("scan.split")
-    assert "fallback" not in split.attrs and "row_groups" not in split.attrs
+    # the host decoder's spans, whichever way the scan got there
+    (decode,) = trace.find("scan.host_decode")
+    assert decode.attrs["rows"] == 3 * ROWS
+    assert decode.attrs["columns"] == len(columns)
+    assert sum(sp.attrs["bytes"] for sp in trace.find("scan.upload")) > 0
+    if why == "no_string_attribute":
+        assert not staged and not trace.find("scan.split")
+    else:
+        (split,) = trace.find("scan.split")
+        assert "fallback" not in split.attrs and \
+            "row_groups" not in split.attrs
 
 
 # ---------------------------------------------------------------------------
 # one task holds the permit, the other's host half runs all the same
 # ---------------------------------------------------------------------------
 def test_host_half_runs_while_another_task_holds_the_permit(
-        tmp_path, doubles_on_host):
+        tmp_path):
     _write_files(tmp_path, files=2, row_groups=2)
     session = _new_session(**{C.CONCURRENT_TPU_TASKS.key: 1,
                               C.OBS_TRACING.key: True})
     try:
         session.read.parquet(str(tmp_path)) \
-            .agg(F.sum("x"), F.sum("q"), F.sum("d")).collect()
+            .agg(F.sum("x"), F.sum("q"), F.count("s")).collect()
         trace = session.last_query_trace
         assert session.last_query_metrics[M.CPU_FALLBACK_EVENTS] == 0
     finally:
@@ -244,11 +252,11 @@ def test_host_half_runs_while_another_task_holds_the_permit(
         host_half = [c for c in task.children
                      if c.name in ("scan.split", "scan.read",
                                    "scan.host_decode")]
-        # 1 split, 2 row groups x (`q` and `d` read, `x` decoded by Arrow:
+        # 1 split, 2 row groups x (`s` read, `x` and `q` decoded by Arrow:
         # the plan prunes the rest): the WHOLE split staged, on the task's
         # own thread, before the task asked for its permit, so no permit
         # is held through host work
-        assert len(host_half) == 1 + 2 * 3
+        assert len(host_half) == 1 + 2 * 2
         for sp in host_half:
             assert sp.end_ns <= asked.start_ns and sp.tid == task.tid
         device_half = [c for c in task.children if c.name == "scan.rowgroup"]
@@ -262,8 +270,7 @@ def test_host_half_runs_while_another_task_holds_the_permit(
     assert not trace.find("prefetch:scan-stage")
 
 
-def test_the_rest_columns_are_one_read_a_split(tmp_path, monkeypatch,
-                                               doubles_on_host):
+def test_the_rest_columns_are_one_read_a_split(tmp_path, monkeypatch):
     """The columns Arrow decodes are read the way the host path reads a
     split — `read_split`, once, on the file the host half has open — and
     sliced a row group."""
@@ -283,7 +290,7 @@ def test_the_rest_columns_are_one_read_a_split(tmp_path, monkeypatch,
     items = list(scan._stage_split(SCAN._SplitPlan(split, {})))
     assert len(items) == 3
     ((names, pf),) = reads
-    assert names == ["x"] and pf is not None
+    assert names == ["id", "q", "d", "x"] and pf is not None
     x = pq.read_table(path).column("x").to_numpy()
     for i, it in enumerate(items):
         (up,) = [b for b in it.host.bufs if b.dtype == np.float64]
@@ -305,7 +312,7 @@ def test_read_split_on_an_open_file(tmp_path):
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("depth", [0, 1, 3])
 def test_rows_equal_the_host_decoders_at_every_depth(
-        tmp_path, depth, doubles_on_host):
+        tmp_path, depth):
     _write_files(tmp_path / "k=1", files=2, seed=1)
     _write_files(tmp_path / "k=2", files=1, seed=2)
     session = _new_session(**{PREFETCH: depth, C.OBS_TRACING.key: True})
@@ -337,18 +344,18 @@ def test_rows_equal_the_host_decoders_at_every_depth(
 # a refused page shape in the middle of a split
 # ---------------------------------------------------------------------------
 def _refuse_second(monkeypatch, half):
-    """`_Unsupported` at the second row group's `q` chunk, raised by the
+    """`_Unsupported` at the second row group's `s` chunk, raised by the
     host half (`stage_chunk`) or by the device half (`decode_chunk_device`)."""
     seen = []
     if half == "host":
         real = PD.stage_chunk
 
-        def stage_chunk(chunk, codec, *what):
+        def stage_chunk(chunk, codec):
             seen.append(len(seen))
-            # chunks are staged in column order: id, q, d, s a row group
-            if len(seen) == 4 + 2:
+            # one chunk a row group is staged: the string's
+            if len(seen) == 2:
                 raise PD._Unsupported("test: refused page")
-            return real(chunk, codec, *what)
+            return real(chunk, codec)
 
         monkeypatch.setattr(PD, "stage_chunk", stage_chunk)
     else:
@@ -356,7 +363,7 @@ def _refuse_second(monkeypatch, half):
 
         def decode_chunk_device(chunk, dtype, rows, **kw):
             seen.append(len(seen))
-            if len(seen) == 4 + 2:
+            if len(seen) == 2:
                 raise PD._Unsupported("test: refused page")
             return real(chunk, dtype, rows, **kw)
 
@@ -366,7 +373,7 @@ def _refuse_second(monkeypatch, half):
 @pytest.mark.parametrize("depth", [0, 1])
 @pytest.mark.parametrize("half", ["host", "device"])
 def test_unsupported_at_the_second_row_group_yields_every_row_once(
-        tmp_path, monkeypatch, caplog, half, depth, doubles_on_host):
+        tmp_path, monkeypatch, caplog, half, depth):
     (path,) = _write_files(tmp_path, files=1)
     _refuse_second(monkeypatch, half)
     session = _new_session(**{C.OBS_TRACING.key: True, PREFETCH: depth})
@@ -385,7 +392,7 @@ def test_unsupported_at_the_second_row_group_yields_every_row_once(
     assert got == want
     assert metrics[M.CPU_FALLBACK_EVENTS] == 1
     (split,) = trace.find("scan.split")
-    assert split.attrs["fallback"] == "q: test: refused page"
+    assert split.attrs["fallback"] == "s: test: refused page"
     assert split.attrs["row_groups"] == 3
     # a refusal of the host half comes before the split's first device
     # half: the whole split is the host decoder's. One of the device half
@@ -405,7 +412,7 @@ def test_unsupported_at_the_second_row_group_yields_every_row_once(
 # a device error is retried from the staged item
 # ---------------------------------------------------------------------------
 def test_device_error_is_retried_from_the_staged_item(
-        tmp_path, monkeypatch, doubles_on_host):
+        tmp_path, monkeypatch):
     (path,) = _write_files(tmp_path, files=1)
     reads, halves = [], []
     read_chunk_bytes = PD.read_chunk_bytes
@@ -437,13 +444,13 @@ def test_device_error_is_retried_from_the_staged_item(
     # host half staged: no chunk was read twice
     assert [it.rg for it in halves] == [0, 1, 1, 2]
     assert halves[1] is halves[2]
-    assert sorted(reads) == sorted(["id", "q", "d", "s"] * 3)
+    assert reads == ["s"] * 3
     assert metrics[M.RETRIES] == 1
     assert metrics[M.CPU_FALLBACK_EVENTS] == 0
 
 
 def test_host_half_error_is_the_tasks_before_any_row_went_downstream(
-        tmp_path, monkeypatch, doubles_on_host):
+        tmp_path, monkeypatch):
     """An IO error of the host half is the task's, and comes before the
     split's first device half: nothing went downstream, and the
     task-level retry reads the split again."""
@@ -453,7 +460,7 @@ def test_host_half_error_is_the_tasks_before_any_row_went_downstream(
 
     def flaky_read(path, col):
         calls.append(col.path_in_schema)
-        if len(calls) == 4 + 1:
+        if len(calls) == 2:
             raise OSError("test: disk hiccup at rg 1")
         return read_chunk_bytes(path, col)
 
@@ -467,14 +474,14 @@ def test_host_half_error_is_the_tasks_before_any_row_went_downstream(
     assert [r[0] for r in got] == list(range(3 * ROWS))
     assert metrics[M.CPU_FALLBACK_EVENTS] == 0
     # one row group and a chunk before the error, then the whole split
-    assert len(calls) == 4 + 1 + 3 * 4
+    assert len(calls) == 1 + 1 + 3
     assert live_reader_count() == 0
 
 
 # ---------------------------------------------------------------------------
 # nothing is left behind
 # ---------------------------------------------------------------------------
-def test_abandoned_scan_leaves_no_reader(tmp_path, doubles_on_host):
+def test_abandoned_scan_leaves_no_reader(tmp_path):
     _write_files(tmp_path, files=2, row_groups=4)
     session = _new_session(**{PREFETCH: 1})
     try:
@@ -486,8 +493,7 @@ def test_abandoned_scan_leaves_no_reader(tmp_path, doubles_on_host):
         session.stop()
 
 
-def test_cancelled_scan_leaves_no_reader(tmp_path, monkeypatch,
-                                         doubles_on_host):
+def test_cancelled_scan_leaves_no_reader(tmp_path, monkeypatch):
     """A deadline that fires while a task is in its device half and the
     others hold staged row groups: no thread is left behind when the
     error reaches the caller, and the permits are back."""

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import itertools
 import threading
 from typing import Any, List, Optional, Sequence, Tuple
 
@@ -409,62 +410,53 @@ class HostColumnarBatch:
     def stage_upload(self) -> "StagedUpload":
         """The host half of `to_device`: nulls zeroed, columns padded to
         the capacity bucket and packed into one host buffer a dtype. No
-        jax call, no device state."""
+        jax call, no device state.
+
+        One pass: the segments are sized first, each dtype group's buffer
+        is allocated once (zeroed: the padding, the null lanes) and every
+        column is written once, cast on the copy, into its place. The
+        buffers are fresh and nothing writes them afterwards: `jnp.asarray`
+        may return before a transfer has finished, and on the CPU backend
+        the device array may alias the host one, so a staging buffer that
+        is pooled or patched after `upload()` is a race on the chip."""
         from spark_rapids_tpu.columnar.encoded import HostDictionaryColumn
 
         n = self.num_rows
         cap = bucket_capacity(n)
-        parts: List[Tuple[str, np.ndarray, bool]] = []  # (group, seg, want_bool)
-        specs = []  # per column: ("fixed", dtype) | ("string",) | ("dict",)
+        # a column: (its segments, in layout order, each (group, count,
+        # want_bool), and `fill(*segments) -> spec` that writes them)
+        plan = []
         for hc in self.columns:
-            validity = np.zeros(cap, dtype=bool)
-            validity[:n] = hc.validity[:n]
-            if isinstance(hc, HostDictionaryColumn):
-                # codes upload as fixed int32; the dictionary is interned
-                # and uploads (at most) once per process, not per batch
-                codes = np.zeros(cap, dtype=np.int32)
-                codes[:n] = np.where(hc.validity[:n], hc.data[:n], 0)
-                parts.append(("int32", codes, False))
-                parts.append(("uint8", validity.view(np.uint8), True))
-                specs.append(("dict", hc.dtype, hc.dictionary))
-            elif hc.dtype is DataType.STRING:
-                encoded = [
-                    s.encode("utf-8") if isinstance(s, str) else bytes(s)
-                    for s in hc.data[:n]
-                ]
-                lengths = np.fromiter(
-                    (len(b) if validity[i] else 0
-                     for i, b in enumerate(encoded)),
-                    dtype=np.int32, count=n,
-                )
-                offsets = np.zeros(cap + 1, dtype=np.int32)
-                np.cumsum(lengths, out=offsets[1:n + 1])
-                offsets[n + 1:] = offsets[n]
-                nbytes = int(offsets[n])
-                byte_cap = bucket_capacity(max(nbytes, 1))
-                buf = np.zeros(byte_cap, dtype=np.uint8)
-                if nbytes:
-                    joined = b"".join(
-                        b if validity[i] else b""
-                        for i, b in enumerate(encoded))
-                    buf[:nbytes] = np.frombuffer(joined, dtype=np.uint8)
-                parts.append(("int32", offsets, False))
-                parts.append(("uint8", buf, False))
-                parts.append(("uint8", validity.view(np.uint8), True))
-                specs.append(("string",
-                              len_bucket(int(lengths.max()) if n else 1)))
-            else:
-                npdt = physical_np_dtype(hc.dtype)
-                data = np.zeros(cap, dtype=npdt)
-                data[:n] = np.where(hc.validity[:n], hc.data[:n], 0)
-                if npdt == np.dtype(np.bool_):
-                    parts.append(("uint8", data.view(np.uint8), True))
-                else:
-                    parts.append((npdt.name, data, False))
-                parts.append(("uint8", validity.view(np.uint8), True))
-                specs.append(("fixed", hc.dtype,
-                              host_value_range(hc.dtype, data[:n])))
-        return StagedUpload(n, specs, *_group_parts(parts))
+            # a dictionary column's codes upload as fixed int32; the
+            # dictionary is interned and uploads (at most) once per
+            # process, not per batch
+            coded = isinstance(hc, HostDictionaryColumn)
+            if hc.dtype is DataType.STRING and not coded:
+                plan.append(_plan_string(hc, n, cap))
+                continue
+            npdt = np.dtype(np.int32) if coded \
+                else physical_np_dtype(hc.dtype)
+            # a BOOL column rides the uint8 group, as every validity does
+            data_seg = ("uint8", cap, True) if npdt == np.bool_ \
+                else (npdt.name, cap, False)
+            plan.append(([data_seg, ("uint8", cap, True)],
+                         functools.partial(_fill_fixed, hc, n, npdt)))
+        keys = tuple(sorted({g for segs, _ in plan for g, _, _ in segs}))
+        sizes = dict.fromkeys(keys, 0)
+        layout = []
+        for segs, _ in plan:
+            for group, count, want_bool in segs:
+                layout.append((keys.index(group), sizes[group], count,
+                               want_bool))
+                sizes[group] += count
+        bufs = tuple(np.zeros(sizes[k], dtype=k) for k in keys)
+        # per column: ("fixed", dtype, vrange) | ("string", max_len) |
+        # ("dict", dtype, dictionary)
+        segments = (bufs[bi][start:start + count]
+                    for bi, start, count, _ in layout)
+        specs = [fill(*itertools.islice(segments, len(segs)))
+                 for segs, fill in plan]
+        return StagedUpload(n, specs, bufs, tuple(layout))
 
 
 class StagedUpload:
@@ -782,21 +774,62 @@ def to_host_many(batches: Sequence["ColumnarBatch"],
 # ---------------------------------------------------------------------------
 # Packed transfer helpers (one host<->device copy per batch)
 # ---------------------------------------------------------------------------
-def _group_parts(parts):
-    """(group, np_seg, want_bool) parts as one host concatenate PER DTYPE
-    GROUP and the layout that slices each segment back out of them:
-    (buffers, ((buffer index, start, count, want_bool), ...))."""
-    order: dict = {}
-    for gname, seg, _want in parts:
-        order.setdefault(gname, []).append(seg)
-    keys = tuple(sorted(order))
-    bufs = tuple(np.concatenate(order[k]) for k in keys)
-    layout = []
-    offs = {k: 0 for k in keys}
-    for gname, seg, want in parts:
-        layout.append((keys.index(gname), offs[gname], seg.shape[0], want))
-        offs[gname] += seg.shape[0]
-    return bufs, tuple(layout)
+def _fill_validity(dst, validity, n) -> np.ndarray:
+    """`validity[:n]` into the head of a zeroed uint8 segment (the padding
+    stays False); the bool view of what was written."""
+    valid = dst[:n].view(np.bool_)
+    np.copyto(valid, validity[:n], casting="unsafe")
+    return valid
+
+
+def _fill_data(dst, src, valid) -> None:
+    """`src` into a zeroed segment of the upload's dtype, cast on the
+    copy, null lanes left zero. The masked copy runs only where the
+    column has a null. (`unsafe` is what the assignment it replaces
+    did: a BOOL column held as uint8, an INT32 one held as int64.)"""
+    if valid.all():
+        np.copyto(dst, src, casting="unsafe")
+    else:
+        np.copyto(dst, src, casting="unsafe", where=valid)
+
+
+def _fill_fixed(hc, n, npdt, data, validity):
+    """A fixed-width column (a dictionary column: its codes) into its
+    data and validity segments; its spec."""
+    valid = _fill_validity(validity, hc.validity, n)
+    data = data[:n].view(npdt)
+    _fill_data(data, hc.data[:n], valid)
+    dictionary = getattr(hc, "dictionary", None)
+    if dictionary is not None:
+        return ("dict", hc.dtype, dictionary)
+    return ("fixed", hc.dtype, host_value_range(hc.dtype, data))
+
+
+def _plan_string(hc, n, cap):
+    """A STRING column's segments (offsets, bytes, validity) and their
+    fill. The byte segment's size is the data's, so the encoding runs
+    here, ahead of the allocation."""
+    valid = np.array(hc.validity[:n], dtype=bool)
+    encoded = [
+        (s.encode("utf-8") if isinstance(s, str) else bytes(s))
+        if valid[i] else b""
+        for i, s in enumerate(hc.data[:n])
+    ]
+    lengths = np.fromiter(map(len, encoded), dtype=np.int32, count=n)
+    ends = np.cumsum(lengths, dtype=np.int32)
+    nbytes = int(ends[-1]) if n else 0
+
+    def fill(offsets, buf, validity):
+        offsets[1:n + 1] = ends
+        offsets[n + 1:] = nbytes
+        if nbytes:
+            buf[:nbytes] = np.frombuffer(b"".join(encoded), dtype=np.uint8)
+        _fill_validity(validity, valid, n)
+        return ("string", len_bucket(int(lengths.max()) if n else 1))
+
+    return [("int32", cap + 1, False),
+            ("uint8", bucket_capacity(max(nbytes, 1)), False),
+            ("uint8", cap, True)], fill
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
@@ -811,7 +844,7 @@ def _slice_grouped(bufs, layout):
 @jax.jit
 def _download_grouped(arrays):
     """Concatenate arrays into one buffer per dtype for the host transfer
-    (the download mirror of `_group_parts` + `StagedUpload.upload`; bools
+    (the download mirror of `stage_upload` + `StagedUpload.upload`; bools
     ride as uint8)."""
     order: dict = {}
     for i, a in enumerate(arrays):

@@ -5,10 +5,13 @@ mid-query waits on the DEVICE; this module removes the symmetric stall on
 the HOST side of a scan: with a prefetch depth of k, a daemon reader
 thread decodes batch n+1..n+k while the consumer computes on batch n —
 Arrow/pyarrow decode releases the GIL for its I/O and parse work, so the
-overlap is real parallelism, not just interleaving. The consumer then
-uploads on ITS OWN thread (admission-semaphore acquisition is per task
-id, and JAX uploads are asynchronous anyway, so the upload also overlaps
-compute without the prefetcher touching device state).
+overlap is real parallelism, not just interleaving. For the device scan
+the reader also packs each batch for its upload (io/scan.py
+`_read_host_iter(stage=True)`: numpy on host data, so an item of the
+queue is a `StagedUpload`). The consumer then uploads on ITS OWN thread
+(admission-semaphore acquisition is per task id, and JAX uploads are
+asynchronous anyway, so the upload also overlaps compute without the
+prefetcher touching device state).
 
 Depth is `rapids.tpu.io.prefetchBatches` (0 = off, decode inline), with a
 per-read override via `spark.read.option("prefetchBatches", k)`.
@@ -103,7 +106,9 @@ class PrefetchIterator:
         # reserved slot because every put retries with a timeout. Total
         # decoded batches live per consumer: depth (queue) + 1 in the
         # worker's hand + the consumer's current one — the (2 + depth)
-        # the resource analyzer charges scan leaves
+        # the resource analyzer charges scan leaves (an upper bound for
+        # the device scan, whose items are packed buffers: no wider than
+        # the decoded batch, a DOUBLE at f32 width on a TPU)
         self._queue: "queue.Queue" = queue.Queue(self._depth)
         self._closed = threading.Event()
         # queue-occupancy telemetry (docs/observability.md): the staged

@@ -27,7 +27,11 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 import pyarrow as pa
 
-from spark_rapids_tpu.columnar.batch import HostColumnarBatch, HostColumnVector
+from spark_rapids_tpu.columnar.batch import (
+    HostColumnarBatch,
+    HostColumnVector,
+    StagedUpload,
+)
 from spark_rapids_tpu.columnar.dtypes import DataType
 from spark_rapids_tpu.exec.base import (
     CpuExec,
@@ -40,7 +44,7 @@ from spark_rapids_tpu.exec.base import (
 from spark_rapids_tpu.exec.transitions import current_task_id
 from spark_rapids_tpu.io.arrow_convert import arrow_to_host_batch
 from spark_rapids_tpu.memory.semaphore import TpuSemaphore
-from spark_rapids_tpu.obs.trace import span as obs_span
+from spark_rapids_tpu.obs.trace import span as obs_span, wall_ns
 from spark_rapids_tpu.ops.base import AttributeReference
 from spark_rapids_tpu.utils import metrics as M
 
@@ -387,11 +391,19 @@ class _FileScanBase(PhysicalExec):
     def node_name(self):
         return f"{type(self).__name__}({self.fmt}, {len(self.splits)} splits)"
 
-    def _read_host_iter(self, split: FileSplit, conf):
+    def _read_host_iter(self, split: FileSplit, conf, stage: bool = False):
         """Generator form of the host decode: the Arrow read runs on first
         pull, so a prefetch wrapper (io/prefetch.py) moves the WHOLE decode
         onto its worker thread — batch k+1 of the query decodes while
-        batch k computes downstream."""
+        batch k computes downstream.
+
+        With `stage` (the device scan, `TpuFileScanExec._read_host`) it
+        yields each batch packed for its upload, a `StagedUpload`, in
+        place of the `HostColumnarBatch`: the packing is host work on
+        host data and belongs with the decode, ahead of the task's
+        admission permit, wherever the decode runs. The Arrow table and
+        the decoded batch are dropped once packed: what waits for the
+        permit is the packed buffers alone."""
         from spark_rapids_tpu import conf as C
 
         pv = dict(split.partition_values)
@@ -401,25 +413,40 @@ class _FileScanBase(PhysicalExec):
         with obs_span("scan.host_decode", columns=len(data_attrs)) as sp:
             table = read_split(split, data_attrs)
             batch = arrow_to_host_batch(table, data_attrs)
+            del table
             if sp is not None:
                 sp.attrs["rows"] = batch.num_rows
-        if pv:
-            # append partition-value constant columns (reference:
-            # ColumnarPartitionReaderWithPartitionValues)
-            batch = _with_partition_columns(batch, self.attrs, pv)
-        max_rows = conf.get(C.MAX_READ_BATCH_SIZE_ROWS)
-        if batch.num_rows <= max_rows:
-            yield batch
-            return
-        for i in range(0, batch.num_rows, max_rows):
-            yield batch.slice(i, max_rows)
+            if pv:
+                # append partition-value constant columns (reference:
+                # ColumnarPartitionReaderWithPartitionValues)
+                batch = _with_partition_columns(batch, self.attrs, pv)
+            max_rows = conf.get(C.MAX_READ_BATCH_SIZE_ROWS)
+            if batch.num_rows <= max_rows:
+                batches = [batch]
+            else:
+                batches = [batch.slice(i, max_rows)
+                           for i in range(0, batch.num_rows, max_rows)]
+            del batch
+            if stage:
+                t0 = wall_ns() if sp is not None else 0
+                batches = [hb.stage_upload() for hb in batches]
+                if sp is not None:
+                    sp.attrs["pack_ms"] = (wall_ns() - t0) / 1e6
+                    sp.attrs["packed_bytes"] = sum(
+                        b.nbytes for st in batches for b in st.bufs)
+        # handed over one at a time: a batch that has gone downstream is
+        # not kept alive from here
+        batches.reverse()
+        while batches:
+            yield batches.pop()
 
-    def _host_batches_prefetched(self, split: FileSplit, conf):
+    def _host_batches_prefetched(self, split: FileSplit, conf,
+                                 stage: bool = False):
         """Host decode iterator with the configured double-buffering depth
         (rapids.tpu.io.prefetchBatches; per-read option overrides)."""
         from spark_rapids_tpu.io.prefetch import maybe_prefetch, prefetch_depth
 
-        return maybe_prefetch(self._read_host_iter(split, conf),
+        return maybe_prefetch(self._read_host_iter(split, conf, stage),
                               prefetch_depth(conf, split))
 
 
@@ -452,8 +479,12 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
 
     Neither path holds a permit through host work on host data. The
     host decoder's split is one Arrow read on the scan prefetcher's
-    reader thread (io/prefetch.py, `rapids.tpu.io.prefetchBatches`;
-    `_read_host_iter` / `to_device`). A split with a string column is
+    reader thread (io/prefetch.py, `rapids.tpu.io.prefetchBatches`; on
+    the task's own thread at depth 0), packed there for its upload
+    (`_read_host_iter` with `stage`: `HostColumnarBatch.stage_upload`),
+    so the task asks for its permit with the packed buffers in hand and
+    holds it for `StagedUpload.upload()` and the program issue alone
+    (`_read_host` / `_upload`). A split with a string column is
     staged on the task thread — the footer, each string chunk's read,
     decompression and page walk, Arrow's decode of the other columns —
     before the task asks for the permit (`_stage_split`), and only
@@ -461,8 +492,9 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
     thread a task was tried there and lost to this order on the chip's
     host, where the interpreter's lock, not the cores, bounds the host
     half (PERF.md section 6, PR 29). Staging is host memory only: a
-    split's staged row groups, as the host path holds a split's Arrow
-    table."""
+    split's staged row groups, as the host path holds a split's packed
+    buffers (fresh ones every split, written by nobody once packed: the
+    runtime may still be reading them after `jnp.asarray` returns)."""
 
     placement = "tpu"
 
@@ -515,23 +547,29 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
         return PartitionedBatches(len(self.splits), factory)
 
     def _read_host(self, split: FileSplit, conf):
-        """Host path: decode double-buffers on the prefetch worker; the
-        upload ISSUES here (asynchronously — jax returns an unblocked
-        device future) under this task's admission permit, so batch k+1's
-        decode and upload overlap batch k's downstream compute."""
-        for hb in self._host_batches_prefetched(split, conf):
+        """Host path: decode AND packing double-buffer on the prefetch
+        worker (inline, on this thread, at depth 0: either way before the
+        task asks for its permit), so what runs under the permit is the
+        transfer and the program issue (asynchronously — jax returns an
+        unblocked device future) and nothing else: batch k+1's decode
+        and packing overlap batch k's upload and downstream compute, and
+        another task's."""
+        for staged in self._host_batches_prefetched(split, conf, stage=True):
             TpuSemaphore.get().acquire_if_necessary(current_task_id())
-            yield self._upload(hb)
+            yield self._upload(staged)
 
     @staticmethod
-    def _upload(hb: HostColumnarBatch):
-        """One host batch onto the device, under the caller's permit (a
-        step of its own so that the generator's frame keeps no reference
-        to a batch that has gone downstream)."""
+    def _upload(staged: StagedUpload):
+        """One packed host batch onto the device, under the caller's
+        permit (a step of its own so that the generator's frame keeps no
+        reference to a batch that has gone downstream). `upload()` is
+        pure over the staged buffers, which nothing writes: a retry
+        issues it again from the same bytes."""
         from spark_rapids_tpu.engine.retry import with_retry
 
-        with obs_span("scan.upload", columns=len(hb.columns)) as sp:
-            batch = with_retry(lambda: hb.to_device(), site="scan")
+        with obs_span("scan.upload", columns=len(staged.specs),
+                      staged=1) as sp:
+            batch = with_retry(staged.upload, site="scan")
             if sp is not None:
                 sp.attrs["bytes"] = batch.device_memory_size()
         return batch

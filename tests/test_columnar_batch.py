@@ -15,6 +15,9 @@ from spark_rapids_tpu.columnar.batch import (
     gather_batch,
     slice_batch_host,
 )
+import spark_rapids_tpu.columnar.batch as B
+from spark_rapids_tpu.columnar import encoded as ENC
+from spark_rapids_tpu.columnar.dtypes import DecimalType
 import jax.numpy as jnp
 
 
@@ -197,3 +200,291 @@ def test_lazy_filter_compact_matches_eager():
         assert df.filter(F.col("v") > 10**9).collect() == []
     finally:
         session.conf.set("rapids.tpu.engine.filterCompactSync", "auto")
+
+
+# ---------------------------------------------------------------------------
+# stage_upload: one pass, byte for byte what five passes gave
+# ---------------------------------------------------------------------------
+def _reference_stage_upload(hb):
+    """The plain reference packer: `HostColumnarBatch.stage_upload` as it
+    was until PR 32, five passes over a fixed-width column (zeros, the
+    nulls zeroed at the SOURCE width, the assignment with its cast, the
+    validity's zeros + copy, one concatenate a dtype group). The packer
+    in the package is held to it byte for byte: what goes up, where each
+    segment lies and what each column is must not move."""
+    n = hb.num_rows
+    cap = bucket_capacity(n)
+    parts = []  # (group, seg, want_bool)
+    specs = []
+    for hc in hb.columns:
+        validity = np.zeros(cap, dtype=bool)
+        validity[:n] = hc.validity[:n]
+        if isinstance(hc, ENC.HostDictionaryColumn):
+            codes = np.zeros(cap, dtype=np.int32)
+            codes[:n] = np.where(hc.validity[:n], hc.data[:n], 0)
+            parts.append(("int32", codes, False))
+            parts.append(("uint8", validity.view(np.uint8), True))
+            specs.append(("dict", hc.dtype, hc.dictionary))
+        elif hc.dtype is DataType.STRING:
+            encoded = [
+                s.encode("utf-8") if isinstance(s, str) else bytes(s)
+                for s in hc.data[:n]
+            ]
+            lengths = np.fromiter(
+                (len(b) if validity[i] else 0
+                 for i, b in enumerate(encoded)),
+                dtype=np.int32, count=n,
+            )
+            offsets = np.zeros(cap + 1, dtype=np.int32)
+            np.cumsum(lengths, out=offsets[1:n + 1])
+            offsets[n + 1:] = offsets[n]
+            nbytes = int(offsets[n])
+            byte_cap = bucket_capacity(max(nbytes, 1))
+            buf = np.zeros(byte_cap, dtype=np.uint8)
+            if nbytes:
+                joined = b"".join(
+                    b if validity[i] else b""
+                    for i, b in enumerate(encoded))
+                buf[:nbytes] = np.frombuffer(joined, dtype=np.uint8)
+            parts.append(("int32", offsets, False))
+            parts.append(("uint8", buf, False))
+            parts.append(("uint8", validity.view(np.uint8), True))
+            specs.append(("string",
+                          B.len_bucket(int(lengths.max()) if n else 1)))
+        else:
+            npdt = B.physical_np_dtype(hc.dtype)
+            data = np.zeros(cap, dtype=npdt)
+            data[:n] = np.where(hc.validity[:n], hc.data[:n], 0)
+            if npdt == np.dtype(np.bool_):
+                parts.append(("uint8", data.view(np.uint8), True))
+            else:
+                parts.append((npdt.name, data, False))
+            parts.append(("uint8", validity.view(np.uint8), True))
+            specs.append(("fixed", hc.dtype,
+                          B.host_value_range(hc.dtype, data[:n])))
+    order = {}
+    for gname, seg, _want in parts:
+        order.setdefault(gname, []).append(seg)
+    keys = tuple(sorted(order))
+    bufs = tuple(np.concatenate(order[k]) for k in keys)
+    layout = []
+    offs = {k: 0 for k in keys}
+    for gname, seg, want in parts:
+        layout.append((keys.index(gname), offs[gname], seg.shape[0], want))
+        offs[gname] += seg.shape[0]
+    return n, specs, bufs, tuple(layout)
+
+
+def _assert_packs_as_the_reference(hb):
+    want_n, want_specs, want_bufs, want_layout = _reference_stage_upload(hb)
+    before = [(c.data.copy(), np.array(c.validity)) for c in hb.columns]
+    staged = hb.stage_upload()
+    assert staged.num_rows == want_n
+    assert staged.layout == want_layout
+    assert all(type(x) is int for seg in staged.layout for x in seg[:3])
+    assert len(staged.specs) == len(want_specs)
+    for got, want in zip(staged.specs, want_specs):
+        assert got[:2] == want[:2]
+        if got[0] == "dict":
+            assert got[2] is want[2]
+        else:
+            assert got == want and type(got[-1]) is type(want[-1])
+    assert len(staged.bufs) == len(want_bufs)
+    for got, want in zip(staged.bufs, want_bufs):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert got.tobytes() == want.tobytes()
+        # its own memory, which the source's does not reach
+        assert got.flags.writeable and got.flags.c_contiguous
+        for c in hb.columns:
+            assert not np.shares_memory(got, c.validity)
+            if c.data.dtype != object:
+                assert not np.shares_memory(got, c.data)
+    # the source is read, never written
+    for c, (data, validity) in zip(hb.columns, before):
+        assert np.array_equal(c.validity, validity)
+        assert np.array_equal(c.data, data) if data.dtype == object \
+            else c.data.tobytes() == data.tobytes()
+    return staged
+
+
+_CAP = 32  # a capacity bucket: rows of 31, 32 and 33 sit around its edge
+_ROWS = {"0": 0, "1": 1, "cap-1": _CAP - 1, "cap": _CAP, "cap+1": _CAP + 1}
+_STRINGS = ["", "a", "héllo", "日本語", "x" * 40, "tab\there", "NUL\x00in"]
+
+
+def _source(kind, n, rng):
+    """(dtype, data) of `n` values whose null lanes would show if they
+    went up: no zero among them where the type allows it."""
+    if kind == "BOOL":
+        return DataType.BOOL, np.ones(n, dtype=bool) ^ (rng.random(n) < 0.3)
+    if kind == "INT32":
+        return DataType.INT32, rng.integers(-2**31, 2**31, n).astype(np.int32)
+    if kind == "INT64_in_int32":
+        return DataType.INT64, rng.integers(-1000, 70000, n).astype(np.int64)
+    if kind == "INT64_wide":
+        return DataType.INT64, rng.integers(-2**62, 2**62, n, dtype=np.int64)
+    if kind == "FLOAT32":
+        data = (rng.standard_normal(n) * 1e3).astype(np.float32)
+        data[::5] = np.resize([-0.0, np.inf, np.nan, 1e-42, -np.inf], len(data[::5]))
+        return DataType.FLOAT32, data
+    if kind in ("FLOAT64", "FLOAT64_as_f32"):
+        data = rng.standard_normal(n) * 1e3
+        data[::5] = np.resize([-0.0, 1e300, np.nan, 1e-310, -np.inf], len(data[::5]))
+        return DataType.FLOAT64, data
+    if kind == "DATE":
+        return DataType.DATE, rng.integers(8000, 10500, n).astype(np.int32)
+    if kind == "TIMESTAMP":
+        return DataType.TIMESTAMP, \
+            rng.integers(10**15, 2 * 10**15, n, dtype=np.int64)
+    if kind == "DECIMAL":
+        return DecimalType(12, 2), \
+            rng.integers(-10**11, 10**11, n, dtype=np.int64)
+    assert kind == "STRING"
+    data = np.empty(n, dtype=object)
+    data[:] = [_STRINGS[i] for i in rng.integers(0, len(_STRINGS), n)]
+    return DataType.STRING, data
+
+
+_KINDS = ["BOOL", "INT32", "INT64_in_int32", "INT64_wide", "FLOAT32",
+          "FLOAT64", "FLOAT64_as_f32", "DATE", "TIMESTAMP", "DECIMAL",
+          "STRING", "DICTIONARY"]
+_DICTIONARY = ENC.DeviceDictionary.from_values(["x", "yy", "zzz", ""])
+
+
+def _column(kind, nulls, n, read_only, rng):
+    validity = {"none": np.ones(n, dtype=bool),
+                "some": rng.random(n) < 0.6,
+                "all": np.zeros(n, dtype=bool)}[nulls]
+    if kind == "DICTIONARY":
+        col = ENC.HostDictionaryColumn(
+            DataType.STRING, rng.integers(1, 4, n).astype(np.int32),
+            validity, _DICTIONARY)
+    else:
+        dt, data = _source(kind, n, rng)
+        col = HostColumnVector(dt, data, validity)
+    if read_only:
+        # what Arrow's zero-copy `to_numpy` hands over
+        col.data.setflags(write=False)
+        col.validity.setflags(write=False)
+    return col
+
+
+# (a DOUBLE of 1e300 is inf at f32 width, in both packers)
+_overflows = pytest.mark.filterwarnings("ignore:overflow encountered in cast")
+
+
+@_overflows
+@pytest.mark.parametrize("read_only", [False, True],
+                         ids=["writable", "read_only"])
+@pytest.mark.parametrize("rows", list(_ROWS))
+@pytest.mark.parametrize("nulls", ["none", "some", "all"])
+@pytest.mark.parametrize("kind", _KINDS)
+def test_stage_upload_equals_the_reference_packer(
+        monkeypatch, kind, nulls, rows, read_only):
+    if kind == "FLOAT64_as_f32":
+        # a TPU's width for a DOUBLE: the cast rides the copy
+        monkeypatch.setattr(B, "device_float64_supported", lambda: False)
+    n = _ROWS[rows]
+    rng = np.random.default_rng([_KINDS.index(kind), n, read_only])
+    col = _column(kind, nulls, n, read_only, rng)
+    staged = _assert_packs_as_the_reference(HostColumnarBatch([col], n))
+    if kind == "FLOAT64_as_f32":
+        assert [b.dtype.name for b in staged.bufs] == ["float32", "uint8"]
+    if kind.startswith("INT64") and n:
+        (spec,) = staged.specs
+        narrow = kind == "INT64_in_int32" or not col.validity.any()
+        assert B.fits_int32(spec[2]) == narrow
+
+
+@_overflows
+@pytest.mark.parametrize("f64", [True, False], ids=["f64", "f32"])
+@pytest.mark.parametrize("rows", list(_ROWS))
+@pytest.mark.parametrize("nulls", ["none", "some", "all"])
+def test_stage_upload_of_every_kind_in_one_batch(
+        monkeypatch, nulls, rows, f64):
+    """Every dtype group at once, two columns a kind: the groups' order,
+    each segment's place in its group, BOOL and validity and string bytes
+    side by side in the uint8 one."""
+    monkeypatch.setattr(B, "device_float64_supported", lambda: f64)
+    n = _ROWS[rows]
+    rng = np.random.default_rng([n, f64])
+    kinds = [k for k in _KINDS if k != "FLOAT64_as_f32"]
+    cols = [_column(k, nulls, n, i % 2 == 0, rng)
+            for i, k in enumerate(kinds + kinds[::-1])]
+    staged = _assert_packs_as_the_reference(HostColumnarBatch(cols, n))
+    want = ["float32", "int32", "int64", "uint8"]
+    assert [b.dtype.name for b in staged.bufs] == \
+        (sorted(want + ["float64"]) if f64 else want)
+
+
+@pytest.mark.parametrize("case", [
+    "bool_held_as_uint8", "int32_held_as_int64", "date_held_as_int64",
+    "float32_held_as_float64", "validity_held_as_uint8",
+    "fewer_rows_than_the_arrays", "strided_source", "string_bytes_values",
+    "no_columns", "no_columns_no_rows"])
+def test_stage_upload_casts_as_the_assignment_did(case):
+    """Sources the engine's own readers do not make but a hand-built batch
+    may: the copy casts as the assignment it replaced did (numpy's
+    `unsafe`), where `same_kind` would refuse the first of these."""
+    n = 21
+    rng = np.random.default_rng(7)
+    some = rng.random(n) < 0.7
+    if case == "bool_held_as_uint8":
+        col = HostColumnVector(DataType.BOOL,
+                               rng.integers(0, 4, n).astype(np.uint8), some)
+    elif case == "int32_held_as_int64":
+        col = HostColumnVector(
+            DataType.INT32, rng.integers(-2**40, 2**40, n, dtype=np.int64),
+            some)
+    elif case == "date_held_as_int64":
+        col = HostColumnVector(
+            DataType.DATE, rng.integers(8000, 10500, n).astype(np.int64),
+            some)
+    elif case == "float32_held_as_float64":
+        col = HostColumnVector(DataType.FLOAT32, rng.standard_normal(n), some)
+    elif case == "validity_held_as_uint8":
+        col = HostColumnVector(
+            DataType.INT64, rng.integers(1, 99, n).astype(np.int64),
+            rng.integers(0, 3, n).astype(np.uint8))
+    elif case == "strided_source":
+        col = HostColumnVector(
+            DataType.FLOAT64, rng.standard_normal(2 * n)[::2],
+            np.repeat(some, 2)[::2])
+    elif case == "string_bytes_values":
+        data = np.empty(n, dtype=object)
+        data[:] = [b"raw\xff" if i % 3 else "str" for i in range(n)]
+        col = HostColumnVector(DataType.STRING, data, some)
+    elif case == "fewer_rows_than_the_arrays":
+        col = _column("INT64_wide", "some", n, False, rng)
+        _assert_packs_as_the_reference(HostColumnarBatch([col], 8))
+        _assert_packs_as_the_reference(
+            HostColumnarBatch([col], n).slice(16, 16))
+        return
+    else:
+        rows = n if case == "no_columns" else 0
+        staged = _assert_packs_as_the_reference(HostColumnarBatch([], rows))
+        assert staged.bufs == () and staged.layout == ()
+        assert staged.upload().num_rows == rows
+        return
+    _assert_packs_as_the_reference(HostColumnarBatch([col], n))
+
+
+def test_staged_uploads_own_their_buffers():
+    """Two packings of one batch share no memory with each other, and an
+    upload leaves the staged bytes as they were: nothing is pooled, and
+    nothing is written once a transfer may be reading it."""
+    rng = np.random.default_rng(11)
+    cols = [_column(k, "some", 100, True, rng) for k in _KINDS
+            if k != "FLOAT64_as_f32"]
+    hb = HostColumnarBatch(cols, 100)
+    first, second = hb.stage_upload(), hb.stage_upload()
+    for a in first.bufs:
+        for b in second.bufs:
+            assert not np.shares_memory(a, b)
+    before = [b.tobytes() for b in first.bufs]
+    up = first.upload()
+    again = first.upload()  # a retry: pure over the staged buffers
+    assert [b.tobytes() for b in first.bufs] == before
+    want = repr(hb.to_pylist_rows())  # (a NaN equals no NaN)
+    assert repr(up.to_host().to_pylist_rows()) == want
+    assert repr(again.to_host().to_pylist_rows()) == want

@@ -228,11 +228,13 @@ def test_traced_write_span_tree_and_accounting(session, tmp_path, encoder):
 
 def test_traced_fixed_width_parquet_scan_spans(tmp_path):
     """A scan without a string column is Arrow's (PR 30): a split leaves
-    one scan.host_decode (`columns`, `rows`), opened on the prefetcher's
-    thread under the task's span, and one scan.upload (`bytes`) a batch,
-    on the task's thread and after its permit; no span of the device
-    decoder. A task waiting for the admission permit is no deeper in the
-    tree than a permit holder's upload."""
+    one scan.host_decode (`columns`, `rows`, and since PR 32 the packing
+    for the upload: `pack_ms`, `packed_bytes`), opened on the prefetcher's
+    thread under the task's span and closed before the task asks for its
+    permit, and one scan.upload (`bytes`, `staged`) a batch, on the
+    task's thread and after its permit; no span of the device decoder. A
+    task waiting for the admission permit is no deeper in the tree than a
+    permit holder's upload."""
     # one permit: with two splits the second task waits in the semaphore
     session = srt.new_session({C.CONCURRENT_TPU_TASKS.key: 1,
                                "rapids.tpu.sql.spmd.meshDevices": 1,
@@ -253,16 +255,22 @@ def test_traced_fixed_width_parquet_scan_spans(tmp_path):
     assert len(tasks) == 2
     for task in tasks:
         (decode,) = [c for c in task.children if c.name == "scan.host_decode"]
-        assert decode.attrs == {"columns": 3, "rows": 2 * 4096}
+        assert set(decode.attrs) == {"columns", "rows", "pack_ms",
+                                     "packed_bytes"}
+        assert decode.attrs["columns"] == 3
+        assert decode.attrs["rows"] == 2 * 4096
+        assert 0 < decode.attrs["pack_ms"] <= decode.duration_ns / 1e6
         assert decode.tid != task.tid  # the reader thread's
         (asked,) = [c for c in task.children
                     if c.name == "Acquire TPU Semaphore"]
         (upload,) = [c for c in task.children if c.name == "scan.upload"]
         assert upload.tid == task.tid and upload.attrs["columns"] == 3
+        assert upload.attrs["staged"] == 1
         # q and p as int32 (q narrows on its value range), x as f64 here,
-        # a validity byte each
+        # a validity byte each: what was packed is what goes up
         assert upload.attrs["bytes"] >= 2 * 4096 * (4 + 4 + 8)
-        assert decode.end_ns <= upload.start_ns
+        assert upload.attrs["bytes"] == decode.attrs["packed_bytes"]
+        assert decode.end_ns <= asked.start_ns
         assert asked.end_ns <= upload.start_ns
 
 

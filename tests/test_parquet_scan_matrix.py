@@ -10,7 +10,14 @@ layouts (files this module assembles itself, page by page), streams with
 an RLE run, a padded group in front of later values and a bit-width-0
 page, row counts under one 32-value group, and twelve pyarrow-written
 columns (dictionary and PLAIN, required and nullable, real nulls, pages
-that pad inside a chunk) as v1 and as v2 pages."""
+that pad inside a chunk) as v1 and as v2 pages.
+
+And end to end, in the shape of the benchmark's two cells (PR 32: a split
+is packed for its upload ahead of the task's permit, at either prefetch
+depth): a file of two row groups whose row count is no power of two, one
+column with nulls, read, two columns computed, written back as parquet and
+read by Arrow, exactly; Q6's filter and sum over the same file against
+numpy."""
 
 import numpy as np
 import pyarrow as pa
@@ -18,8 +25,13 @@ import pyarrow.parquet as pq
 import pytest
 
 import spark_rapids_tpu as srt
+from spark_rapids_tpu import conf as C
+from spark_rapids_tpu.columnar.dtypes import DataType
 from spark_rapids_tpu.io import parquet_device as PD
 from spark_rapids_tpu.io import scan as SCAN
+from spark_rapids_tpu.ops.literals import Literal
+from spark_rapids_tpu.plan import functions as F
+from spark_rapids_tpu.plan.column import Column
 from spark_rapids_tpu.utils import metrics as M
 
 
@@ -321,3 +333,123 @@ def test_arrow_written_column(scan_session, arrow_only, tmp_path, case,
         assert col.total_uncompressed_size > 3 * 600
     want = _assert_scan_equals_arrow(scan_session, path)
     assert want.count(None) == arr.null_count
+
+
+# ---------------------------------------------------------------------------
+# the two cells' shape, end to end on this backend
+# ---------------------------------------------------------------------------
+LINEITEM_ROWS = 3001  # two row groups of 2048 and 953: no power of two
+
+
+@pytest.fixture(scope="module")
+def lineitem(tmp_path_factory):
+    """Q6's four columns of a lineitem, `l_quantity` with nulls, and the
+    row's number (a write keeps no order a reader may count on)."""
+    rng = np.random.default_rng(32)
+    n = LINEITEM_ROWS
+    arrays = {
+        "id": np.arange(n, dtype=np.int64),
+        "l_shipdate": rng.integers(8036, 10592, n).astype(np.int32),
+        "l_quantity": rng.integers(1, 51, n).astype(np.float64),
+        "l_extendedprice": rng.integers(90000, 10500000, n) / 100.0,
+        "l_discount": rng.integers(0, 11, n) / 100.0}
+    nulls = rng.random(n) < 0.1
+    path = str(tmp_path_factory.mktemp("lineitem") / "lineitem.parquet")
+    pq.write_table(pa.table({
+        "id": arrays["id"],
+        "l_shipdate": pa.array(arrays["l_shipdate"], pa.int32())
+        .cast(pa.date32()),
+        "l_quantity": pa.array(arrays["l_quantity"], mask=nulls),
+        "l_extendedprice": arrays["l_extendedprice"],
+        "l_discount": arrays["l_discount"]}), path, row_group_size=2048)
+    assert pq.ParquetFile(path).metadata.num_row_groups == 2
+    return path, arrays, nulls
+
+
+@pytest.mark.parametrize("device_encode", [True, False],
+                         ids=["device_encoder", "arrow_encoder"])
+@pytest.mark.parametrize("depth", [0, 1])
+def test_read_compute_write_reads_back_exactly(lineitem, arrow_only,
+                                               tmp_path, depth,
+                                               device_encode):
+    """The write cell's action: every row, Q6's columns and two computed
+    from them, through `write.parquet` and back through Arrow. A DOUBLE is
+    f64 on this backend, so the products are numpy's to the bit. (On the
+    chip Arrow encodes a DOUBLE: `arrow_encoder` walks that sink here.)"""
+    path, arrays, nulls = lineitem
+    session = srt.new_session({
+        "rapids.tpu.sql.spmd.meshDevices": 1,
+        C.IO_PREFETCH_BATCHES.key: depth,
+        C.PARQUET_DEVICE_ENCODE.key: device_encode})
+    out = str(tmp_path / "out")
+    try:
+        price, disc = F.col("l_extendedprice"), F.col("l_discount")
+        session.read.parquet(path).select(
+            F.col("id"), F.col("l_shipdate"), F.col("l_quantity"), price,
+            disc, (price * disc).alias("revenue"),
+            (price * (F.lit(1.0) - disc)).alias("disc_price")) \
+            .write.parquet(out)
+        assert session.last_query_metrics[M.CPU_FALLBACK_EVENTS] == 0
+    finally:
+        session.stop()
+    got = pq.read_table(out).sort_by("id")
+    assert got.num_rows == LINEITEM_ROWS
+    assert got.column_names == ["id", "l_shipdate", "l_quantity",
+                                "l_extendedprice", "l_discount", "revenue",
+                                "disc_price"]
+    assert got.column("l_shipdate").type == pa.date32()
+    want = dict(
+        arrays,
+        revenue=arrays["l_extendedprice"] * arrays["l_discount"],
+        disc_price=arrays["l_extendedprice"] * (1.0 - arrays["l_discount"]))
+    for name in got.column_names:
+        col = got.column(name)
+        if name == "l_shipdate":
+            col = col.cast(pa.int32())
+        assert col.null_count == (int(nulls.sum()) if name == "l_quantity"
+                                  else 0), name
+        if name == "l_quantity":
+            assert np.array_equal(
+                np.asarray(col.is_null()), nulls)
+            col = col.fill_null(0.0)
+            want[name] = np.where(nulls, 0.0, want[name])
+        have = col.to_numpy()
+        assert have.dtype == want[name].dtype, name
+        assert have.tobytes() == want[name].tobytes(), name
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_q6_shape_over_the_same_file(lineitem, arrow_only, depth):
+    """The query cell's action: three range predicates (a NULL quantity
+    keeps no row), one sum of a product."""
+    path, arrays, nulls = lineitem
+    lo, hi = 8766, 9131  # 1994-01-01, 1995-01-01 as day numbers
+
+    def date_lit(day):
+        return Column(Literal(day, DataType.DATE))
+
+    session = srt.new_session({"rapids.tpu.sql.spmd.meshDevices": 1,
+                               C.IO_PREFETCH_BATCHES.key: depth})
+    try:
+        li = session.read.parquet(path)
+        rows = (li.filter((li["l_shipdate"] >= date_lit(lo))
+                          & (li["l_shipdate"] < date_lit(hi))
+                          & (li["l_discount"] >= F.lit(0.05))
+                          & (li["l_discount"] <= F.lit(0.07))
+                          & (li["l_quantity"] < F.lit(24.0)))
+                .withColumn("revenue",
+                            F.col("l_extendedprice") * F.col("l_discount"))
+                .agg(F.sum("revenue").alias("revenue"),
+                     F.count("*").alias("n"))).collect()
+        assert session.last_query_metrics[M.CPU_FALLBACK_EVENTS] == 0
+    finally:
+        session.stop()
+    keep = ((arrays["l_shipdate"] >= lo) & (arrays["l_shipdate"] < hi)
+            & (arrays["l_discount"] >= 0.05) & (arrays["l_discount"] <= 0.07)
+            & (arrays["l_quantity"] < 24.0) & ~nulls)
+    assert 0 < keep.sum() < LINEITEM_ROWS
+    ((revenue, n),) = rows
+    assert n == int(keep.sum())
+    want = float((arrays["l_extendedprice"][keep]
+                  * arrays["l_discount"][keep]).sum())
+    assert revenue == pytest.approx(want, rel=1e-12)

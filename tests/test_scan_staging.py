@@ -4,7 +4,10 @@ permit (`TpuFileScanExec._stage_split`: footer, the string chunks' reads,
 decompression and page walk, Arrow's decode of the columns the device
 decoder does not take: every fixed-width one, since PR 30) and a DEVICE
 half that alone runs under it (`_decode_staged`). A scan without a STRING
-column has no halves: it is the host decoder's.
+column is the host decoder's (`_read_host`), and since PR 32 it too packs
+a split for its upload (`stage_upload`) ahead of the task's permit, on
+the prefetcher's reader thread or, at depth 0, on the task's own: what a
+permit covers there is `StagedUpload.upload()` and the program issue.
 
 Pinned here: the host half touches neither jax nor the semaphore and runs
 while another task holds the permit; the rows equal the host decoder's at
@@ -12,10 +15,15 @@ every prefetch depth (the host decoder's own knob); a page shape the
 decoder refuses in the middle of a split sends what is LEFT of the split
 to the host decoder, each row once; a device error is retried from the
 staged item without re-running the host half; an abandoned or cancelled
-scan leaves no reader thread behind.
+scan leaves no reader thread behind. For the host decoder's path, last in
+this file: no packing under a held permit at depth 0 and 1, a second
+task's packing goes on while the first holds every permit, an upload that
+fails is issued again from the same staged buffers, and those buffers are
+each split's own and are not written once packed.
 """
 
 import logging
+import threading
 
 import numpy as np
 import pyarrow as pa
@@ -24,9 +32,10 @@ import pytest
 
 import spark_rapids_tpu as srt
 from spark_rapids_tpu import conf as C
-from spark_rapids_tpu.columnar.batch import StagedUpload
+from spark_rapids_tpu.columnar.batch import HostColumnarBatch, StagedUpload
 from spark_rapids_tpu.engine import cancel as CX
 from spark_rapids_tpu.engine import retry as R
+from spark_rapids_tpu.exec.transitions import current_task_id
 from spark_rapids_tpu.io import parquet_device as PD
 from spark_rapids_tpu.io import scan as SCAN
 from spark_rapids_tpu.io.arrow_convert import schema_attrs
@@ -509,10 +518,213 @@ def test_cancelled_scan_leaves_no_reader(tmp_path, monkeypatch):
     session = _new_session(**{PREFETCH: 2})
     try:
         with pytest.raises(CX.TpuDeadlineExceeded):
-            session.read.parquet(str(tmp_path)).collect(timeout=0.5)
+            # (room for the host half on a loaded machine: the deadline has
+            # to find a task in its device half)
+            session.read.parquet(str(tmp_path)).collect(timeout=2.0)
         assert entered
         assert session.last_query_metrics["cancelledQueries"] == 1
         assert live_reader_count() == 0
         CX.assert_reclaimed()
     finally:
         session.stop()
+
+
+# ---------------------------------------------------------------------------
+# the host decoder's path (no STRING column): packed ahead of the permit
+# ---------------------------------------------------------------------------
+FIXED = ["id", "q", "d", "x"]  # `_write_files` without its string
+
+
+class _Watch:
+    """Every `stage_upload` and every `StagedUpload.upload` of a query,
+    with whether the task it ran for held its permit just then. A reader
+    thread has no task id of its own: `_read_host_iter` is made on the
+    task's thread, which is where the id is taken, and pulled wherever
+    the prefetch depth puts it."""
+
+    def __init__(self, monkeypatch, before_upload=None):
+        self.packs, self.uploads = [], []
+        self._task = threading.local()
+        watch = self
+        read_host_iter = SCAN.TpuFileScanExec._read_host_iter
+        stage_upload = HostColumnarBatch.stage_upload
+        upload = StagedUpload.upload
+
+        def owned_iter(scan, split, conf, stage=False):
+            task = current_task_id()
+
+            def pulled():
+                watch._task.id = task
+                yield from read_host_iter(scan, split, conf, stage)
+
+            return pulled()
+
+        def watched_pack(hb):
+            task = watch._task.id
+            held = TpuSemaphore.get().held_by(task)
+            staged = stage_upload(hb)
+            held = held or TpuSemaphore.get().held_by(task)
+            watch.packs.append((task, held, threading.get_ident(), staged))
+            return staged
+
+        def watched_upload(staged):
+            if before_upload is not None:
+                before_upload(watch, staged)
+            bytes_before = [b.tobytes() for b in staged.bufs]
+            batch = upload(staged)
+            watch.uploads.append(
+                (current_task_id(),
+                 TpuSemaphore.get().held_by(current_task_id()),
+                 threading.get_ident(), staged, bytes_before))
+            return batch
+
+        monkeypatch.setattr(SCAN.TpuFileScanExec, "_read_host_iter",
+                            owned_iter)
+        monkeypatch.setattr(HostColumnarBatch, "stage_upload", watched_pack)
+        monkeypatch.setattr(StagedUpload, "upload", watched_upload)
+
+
+def _fixed_rows(session, root):
+    return sorted(session.read.parquet(str(root)).select(*FIXED).collect(),
+                  key=lambda r: r[0])
+
+
+def _assert_every_row_once(rows, paths):
+    table = pa.concat_tables([pq.read_table(p, columns=FIXED)
+                              for p in paths])
+    assert [r[0] for r in rows] == list(range(table.num_rows))
+    for i, name in enumerate(FIXED):
+        assert [r[i] for r in rows] == table.column(name).to_pylist(), name
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_arrow_path_packs_no_split_under_a_held_permit(
+        tmp_path, monkeypatch, depth):
+    paths = _write_files(tmp_path, files=3, row_groups=2)
+    watch = _Watch(monkeypatch)
+    session = _new_session(**{PREFETCH: depth, C.OBS_TRACING.key: True})
+    try:
+        rows = _fixed_rows(session, tmp_path)
+        trace = session.last_query_trace
+    finally:
+        session.stop()
+    _assert_every_row_once(rows, paths)
+    # a split a task, packed once, and never while its task held a permit
+    assert len(watch.packs) == 3 and len(watch.uploads) == 3
+    assert not any(held for _task, held, _tid, _staged in watch.packs)
+    assert len({task for task, *_ in watch.packs}) == 3
+    # the transfer is what the permit covers: on the task's own thread
+    assert all(held for _task, held, *_ in watch.uploads)
+    assert {task for task, *_ in watch.uploads} == \
+        {task for task, *_ in watch.packs}
+    task_threads = {tid for _task, _held, tid, *_ in watch.uploads}
+    pack_threads = {tid for _task, _held, tid, _staged in watch.packs}
+    if depth:
+        assert not pack_threads & task_threads  # the reader's
+    else:
+        assert pack_threads == task_threads
+    # the span tree says the same: the packing inside `scan.host_decode`,
+    # which ends before its task asks for the permit; `scan.upload` after
+    tasks = [sp for sp in trace.spans() if sp.kind == "task" and
+             any(c.name == "scan.host_decode" for c in sp.children)]
+    assert len(tasks) == 3
+    for task in tasks:
+        (decode,) = [c for c in task.children
+                     if c.name == "scan.host_decode"]
+        (asked,) = [c for c in task.children
+                    if c.name == "Acquire TPU Semaphore"]
+        (up,) = [c for c in task.children if c.name == "scan.upload"]
+        assert decode.end_ns <= asked.start_ns <= asked.end_ns <= up.start_ns
+        assert (decode.tid == task.tid) == (depth == 0)
+        assert up.tid == task.tid and up.attrs["staged"] == 1
+        assert decode.attrs["rows"] == 2 * ROWS
+        assert 0 < decode.attrs["pack_ms"] <= decode.duration_ns / 1e6
+        # four columns and their validity, padded to the capacity bucket
+        assert decode.attrs["packed_bytes"] == \
+            4096 * (8 + 8 + 4 + 8) + 4 * 4096
+        assert up.attrs["bytes"] == decode.attrs["packed_bytes"]
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_arrow_path_packs_while_another_task_holds_every_permit(
+        tmp_path, monkeypatch, depth):
+    """One permit; whichever task gets it stays inside its upload until
+    BOTH splits are packed. Packing that waited for a permit would never
+    get there."""
+    paths = _write_files(tmp_path, files=2, row_groups=2)
+
+    def hold_until_both_are_packed(watch, staged):
+        for _ in range(600):
+            if len(watch.packs) == 2:
+                return
+            CX.cancel_aware_sleep(0.05, site="test.scan")
+        raise AssertionError(
+            "the second split was not packed while the first task held "
+            "the only permit")
+
+    watch = _Watch(monkeypatch, hold_until_both_are_packed)
+    session = _new_session(**{PREFETCH: depth,
+                              C.CONCURRENT_TPU_TASKS.key: 1})
+    try:
+        rows = _fixed_rows(session, tmp_path)
+    finally:
+        session.stop()
+    _assert_every_row_once(rows, paths)
+    assert len(watch.packs) == 2 and len(watch.uploads) == 2
+    assert not any(held for _task, held, *_ in watch.packs)
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_arrow_path_upload_error_is_retried_from_the_same_staged_split(
+        tmp_path, monkeypatch, depth):
+    paths = _write_files(tmp_path, files=2, row_groups=2)
+    tried = []
+
+    def fail_the_second(watch, staged):
+        tried.append(staged)
+        if len(tried) == 2:
+            raise R.TpuRetryOOM("RESOURCE_EXHAUSTED: injected at upload 2")
+
+    watch = _Watch(monkeypatch, fail_the_second)
+    session = _new_session(**{PREFETCH: depth})
+    try:
+        rows = _fixed_rows(session, tmp_path)
+        metrics = dict(session.last_query_metrics)
+    finally:
+        session.stop()
+    _assert_every_row_once(rows, paths)
+    assert metrics[M.RETRIES] == 1
+    assert metrics[M.CPU_FALLBACK_EVENTS] == 0
+    # the failed upload ran again on the very buffers that were staged:
+    # nothing was read or packed a second time
+    assert len(tried) == 3 and tried[1] is tried[2]
+    assert len(watch.packs) == 2 and len(watch.uploads) == 2
+    assert {id(st) for *_, st in watch.packs} == {id(st) for st in tried}
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_arrow_path_staged_splits_own_their_bytes(
+        tmp_path, monkeypatch, depth):
+    """No pool, no reuse, no fix-up after `upload()`: `jnp.asarray` may
+    return before a transfer has finished (and on this backend the device
+    array may alias the host one), so a staged buffer is written by
+    nobody once it is packed."""
+    _write_files(tmp_path, files=3, row_groups=2)
+    watch = _Watch(monkeypatch)
+    session = _new_session(**{PREFETCH: depth})
+    try:
+        session.read.parquet(str(tmp_path)).select(*FIXED) \
+            .agg(F.sum("x"), F.sum("q"), F.count("d")).collect()
+    finally:
+        session.stop()
+    assert len(watch.uploads) == 3
+    staged = [st for *_, st, _before in watch.uploads]
+    for i, a in enumerate(staged):
+        assert all(isinstance(b, np.ndarray) and b.flags.owndata
+                   for b in a.bufs)
+        for b in staged[i + 1:]:
+            assert not any(np.shares_memory(x, y)
+                           for x in a.bufs for y in b.bufs)
+    # downstream has consumed the batches; the staged bytes are as packed
+    for *_, st, before in watch.uploads:
+        assert [b.tobytes() for b in st.bufs] == before

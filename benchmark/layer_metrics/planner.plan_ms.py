@@ -1,13 +1,11 @@
 """Median over the window's actions of the span tree's `plan` span: the
-planner's host time for one action. An action that leaves no span tree (a
-write runs outside a query context) gives nothing."""
+planner's host time for one action. Nothing where no action of the
+window left a tree with a `plan` span (a program whose write runs outside
+a query context, as before PR 25); once one has, a tree without the span
+counts as 0, as lib/spans.median_an_action counts it."""
 
-from lib import loop
+from lib import spans
 
 
 def read(run):
-    per_action = [sum(sp.duration_ns for sp in s.record.spans.find("plan")
-                      if sp.name == "plan") / 1e6
-                  for s in run.samples
-                  if not s.error and s.record.spans is not None]
-    return loop.median(per_action) if per_action else None
+    return spans.median_an_action(run, ("plan",), spans.total_ms)

@@ -401,13 +401,16 @@ def measure(bench: dict, entry: dict, config: dict, cell: dict, seed: int,
     done = [s.record for s in samples if not s.error]
     run.written_bytes = action.written_bytes(done[-1].result) \
         if done and hasattr(action, "written_bytes") else 0
-    failed = count_failed(action, cell["action"], expected, samples)
+    failed, compared = count_failed(action, cell["action"], expected, samples)
     t = phase("compare", t)
 
     emit({"phases_s": phases, "setup_s": setup_s,
           "first_query_s": first_query_s, "setup_build_s": setup_build_s,
           "window_build_s": window_build_s,
           "actions": len(samples), "action_s": loop.durations(samples),
+          "rate": loop.rate(run.rows_per_action, samples),
+          "rate_less_longest": loop.rate_less_longest(run.rows_per_action,
+                                                      samples),
           "counters_last_action": done[-1].counters if done else None,
           "scanned_bytes": run.scanned_bytes,
           "written_bytes": run.written_bytes,
@@ -430,16 +433,23 @@ def measure(bench: dict, entry: dict, config: dict, cell: dict, seed: int,
         out_device["busy_s"] = run.trace["busy_s"]
         out_device["window_s"] = run.trace["window_s"]
         result["breakdown"] = breakdown(run)
+    # last in the line: each number compared, at its worst over the
+    # window's actions, beside its limit (run.py repeats it on stderr)
+    result["compared"] = {n["name"]: [n["value"], n["limit"]]
+                          for n in compared}
+    if failed:
+        result["compared"]["actions_failed"] = [failed, 0]
     session.stop()
     shutil.rmtree(data_dir, ignore_errors=True)
     return result
 
 
-def count_failed(action, action_name: str, expected, samples: list) -> int:
+def count_failed(action, action_name: str, expected, samples: list):
     """How many of the window's actions raised, differ from the reference
-    or show a counter that must read 0. Prints every number compared
-    beside its limit: for the first and the last action and every failed
-    one, and the worst of each number over all of them."""
+    or show a counter that must read 0, and each number compared at its
+    worst over the actions (the result line's `compared`). Prints every
+    number compared beside its limit for the first and the last action
+    and every failed one."""
     done = [s for s in samples if not s.error]
     per_action = iter(action.compare(expected,
                                      [s.record.result for s in done]))
@@ -458,15 +468,14 @@ def count_failed(action, action_name: str, expected, samples: list) -> int:
         for n in numbers:
             if n["name"] not in worst or n["value"] > worst[n["name"]]["value"]:
                 worst[n["name"]] = n
-    emit({"compared_worst_over_actions": list(worst.values())})
-    return failed
+    return failed, list(worst.values())
 
 
 def breakdown(run) -> dict:
     """The operations that took most device time, and the longest idle
-    gaps on the first chip, each labelled with the innermost span of the
-    program's span tree that was open at the gap's middle (or the action,
-    where the action leaves no span tree)."""
+    gaps on the first chip, each labelled with the span of the program's
+    span tree that `owner` picks at the gap's middle (or the action, where
+    the action leaves no span tree)."""
     tr = run.trace
     marked = [s.record for s in run.samples if not s.error][:len(tr["action_s"])]
     # both clocks are read at a marked action's start: the profiler's in
@@ -480,19 +489,38 @@ def breakdown(run) -> dict:
     return {"device_ops": tr["device_ops"], "idle_gaps": labelled}
 
 
+# spans in which a thread waits for another: the task queued for the
+# chip's admission permit, and the prefetcher's own span, which is open
+# from the reader thread's start to its end beside the reader's steps
+WAITS = ("Acquire TPU Semaphore", "prefetch:")
+
+
 def owner(records: list, at_ns: float, action_name: str) -> str:
+    """The label of a moment: of the spans of the action's tree that are
+    open at it and have no open child (what each thread of the action is
+    in), one that works before one that waits (WAITS), then the deepest,
+    then the one begun last. So the head of a Q6 action reads the reader's
+    `scan.host_decode`, not the prefetcher's span beside it under the same
+    task, and a queued task's wait for the permit does not hide the step
+    of the task that holds it; where every thread waits, the wait is the
+    label."""
+    def is_open(sp) -> bool:
+        return sp.end_ns is not None and sp.start_ns <= at_ns <= sp.end_ns
+
     for rec in records:
         if not rec.start_ns <= at_ns <= rec.end_ns:
             continue
-        best, depth = action_name, -1
-        if rec.spans is not None:
-            stack = [(rec.spans.root, 0)]
-            while stack:
-                sp, d = stack.pop()
-                if sp.end_ns is None or not sp.start_ns <= at_ns <= sp.end_ns:
-                    continue
-                if d > depth:
-                    best, depth = f"{sp.kind}:{sp.name}", d
+        best, rank = action_name, None
+        stack = [(rec.spans.root, 0)] if rec.spans is not None else []
+        while stack:
+            sp, d = stack.pop()
+            if not is_open(sp):
+                continue
+            if any(is_open(c) for c in sp.children):
                 stack.extend((c, d + 1) for c in sp.children)
+                continue
+            mine = (not sp.name.startswith(WAITS), d, sp.start_ns)
+            if rank is None or mine > rank:
+                best, rank = f"{sp.kind}:{sp.name}", mine
         return best
     return "between actions"

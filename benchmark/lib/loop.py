@@ -66,3 +66,19 @@ def rate(units_per_action: float, samples: List[Sample]) -> float:
     window's start to the end of the last one: the mean, over all the
     work and all the time."""
     return units_per_action * len(samples) / samples[-1].end_s
+
+
+def rate_less_longest(units_per_action: float, samples: List[Sample]) -> float:
+    """The same rate with the window's single longest action set aside:
+    the units of every other action over the window's seconds less that
+    action's; with one sample, the plain rate. One action that stands
+    still for seconds (PERF.md, section 7: the machine's, 2 to 9 runs in
+    74) moves `rate` by 7-20% and this by nothing; in a traced run the
+    action set aside is the one that pays the profiler's stop. It stands
+    beside `rate`, as a per-layer reading: the end-to-end rate stays the
+    one over all the work and all the time."""
+    if len(samples) < 2:
+        return rate(units_per_action, samples)
+    longest = max(durations(samples))
+    return (units_per_action * (len(samples) - 1)
+            / (samples[-1].end_s - longest))

@@ -7,8 +7,10 @@ Needs a TPU with as many chips as the cell asks for: with any other
 platform or device count it says why on stderr and exits non-zero, with no
 result line (there is no CPU continuation; benchmark/tests rehearse the
 phases on the CPU). The last line of stdout is the one JSON object of the
-contract: correct, attempted, failed, metrics, device, and breakdown in a
-traced run. Progress goes to stderr, everything else worth keeping to
+contract: correct, attempted, failed, metrics, device, breakdown in a
+traced run, and last `compared`, each number compared at its worst over
+the window beside its limit (the last lines of stderr say the same).
+Progress goes to stderr, everything else worth keeping to
 earlier lines of stdout. See benchmark/README.md.
 """
 
@@ -56,6 +58,11 @@ def main(argv=None) -> int:
         print(f"benchmark: {e}", file=sys.stderr)
         return 1
     faulthandler.cancel_dump_traceback_later()
+    # the last lines of stderr: each number compared beside its limit
+    for name, (value, limit) in result["compared"].items():
+        print(f"compared {name}: {value!r} (limit {limit!r})",
+              file=sys.stderr)
+    print(f"correct: {result['correct']}", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

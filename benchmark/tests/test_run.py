@@ -27,6 +27,9 @@ def check_line(result, bench, cell, group):
         assert m["unit"] == units[name] and isinstance(m["value"], (int, float))
     assert set(result["device"]) >= {"platform", "kind", "count",
                                      "memory_peak_bytes"}
+    # each number compared beside its limit, under the line's last key
+    assert list(result)[-1] == "compared" and result["compared"]
+    assert all(value <= limit for value, limit in result["compared"].values())
 
 
 def test_q6_scan(rehearse, bench):
@@ -58,6 +61,7 @@ def test_traced_run_reports_the_layer_metrics(rehearse, bench, monkeypatch):
     assert m["operators.dispatches"] > 0 and m["sink.fences"] == 1
     assert m["planner.plan_ms"] > 0
     assert m["window.build_s"] == 0
+    assert m["window.steady_rows_per_s"] > 0
     assert 0 < m["device.idle_share"] < 100
     assert result["device"]["busy_s"] > 0
     assert result["device"]["window_s"] > result["device"]["busy_s"]
@@ -85,6 +89,9 @@ def test_an_altered_answer_is_not_correct(rehearse, monkeypatch):
     result = rehearse("q6_scan", seconds=0.5)
     assert result["correct"] is False
     assert 0 < result["failed"] < result["attempted"]
+    value, limit = result["compared"]["q6.max_rel_err"]
+    assert value > 5 * limit
+    assert result["compared"]["actions_failed"] == [result["failed"], 0]
 
 
 def test_a_write_that_drops_rows_is_not_correct(rehearse, monkeypatch):
@@ -124,6 +131,21 @@ def run_py(args, cwd=ROOT, env=None):
 
 CELL_ARGS = ["--workload", "q6_scan", "--seed", "1", "--seconds", "1",
              "--trace", "0"]
+
+
+def test_run_py_ends_stderr_with_the_numbers_compared(monkeypatch, capfd):
+    import run
+
+    line = {"correct": False, "attempted": 2, "failed": 1, "metrics": {},
+            "device": {}, "compared": {"q6.max_rel_err": [1e-4, 1e-5],
+                                       "actions_failed": [1, 0]}}
+    monkeypatch.setattr(harness, "run_cell", lambda *a, **kw: line)
+    assert run.main(CELL_ARGS) == 0
+    out, err = capfd.readouterr()
+    assert err.splitlines()[-3:] == [
+        "compared q6.max_rel_err: 0.0001 (limit 1e-05)",
+        "compared actions_failed: 1 (limit 0)", "correct: False"]
+    assert out.splitlines()[-1].startswith('{"correct": false')
 
 
 def test_run_py_exits_non_zero_without_a_tpu():

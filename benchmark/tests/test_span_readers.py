@@ -102,11 +102,25 @@ def test_reader_finds_nothing_to_read(metric):
         span("task:p0", 0, 90, [span("TpuFusedStage", 1, 2, kind="op")],
              kind="task")], kind="stage")]
     cases = [[action(None)],                       # no tree (the parent)
-             [action(two_threads(), error="x")]]  # only failed actions
-    if metric != "planner.plan_ms":  # PR 24's reader: 0 for a tree, kept
-        cases.append([action(other), action(other)])  # no such span
+             [action(two_threads(), error="x")],  # only failed actions
+             [action(other), action(other)]]      # no such span
     for samples in cases:
         assert read(run_of(samples)) is None
+
+
+def test_plan_ms_without_a_plan_span():
+    """A tree without a `plan` span gives nothing, not 0 (PR 24's reader
+    gave 0); beside trees that have one it counts as 0, as every span
+    reader counts an action that lacks its span."""
+    read = harness.load_reader("layer_metrics", "planner.plan_ms")
+    no_plan = [span("stage:result", 0, 90, kind="stage")]
+    assert read(run_of([action(no_plan)] * 3)) is None
+    assert read(run_of([action(two_threads()), action(two_threads()),
+                        action(no_plan)])) == pytest.approx(5)
+    # a `plan` span that never closed is not read
+    open_plan = span("plan", 0, 5, kind="stage")
+    open_plan.end_ns = None
+    assert read(run_of([action([open_plan])])) is None
 
 
 def test_attr_readers_need_the_attr():

@@ -363,11 +363,15 @@ class HostColumnarBatch:
         return [tuple(vals) for vals in zip(*col_lists)] if col_lists else []
 
     def slice(self, start: int, length: int) -> "HostColumnarBatch":
-        cols = [
-            HostColumnVector(c.dtype, c.data[start:start + length],
-                             c.validity[start:start + length])
-            for c in self.columns
-        ]
+        def cut(c):
+            data = c.data[start:start + length]
+            validity = c.validity[start:start + length]
+            if getattr(c, "dictionary", None) is not None:
+                # a dictionary column's codes mean nothing without it
+                return type(c)(c.dtype, data, validity, c.dictionary)
+            return HostColumnVector(c.dtype, data, validity)
+
+        cols = [cut(c) for c in self.columns]
         return HostColumnarBatch(cols, min(length, max(0, self.num_rows - start)))
 
     def estimated_size_bytes(self) -> int:

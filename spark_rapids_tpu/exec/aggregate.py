@@ -23,6 +23,7 @@ gather's byte-total read in _assemble.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
@@ -43,6 +44,7 @@ from spark_rapids_tpu.columnar.batch import (
 )
 from spark_rapids_tpu.columnar.dtypes import DataType
 from spark_rapids_tpu.engine.retry import with_retry
+from spark_rapids_tpu.exec import dense_agg as DA
 from spark_rapids_tpu.exec import rowkeys as RK
 from spark_rapids_tpu.exec.base import (
     CpuExec,
@@ -66,6 +68,7 @@ from spark_rapids_tpu.ops.eval import (
     _col_to_colv,
     cpu_project,
 )
+from spark_rapids_tpu.obs import trace as OBS
 from spark_rapids_tpu.utils import metrics as M
 
 PARTIAL = "partial"
@@ -308,29 +311,11 @@ class TpuHashAggregateExec(_HashAggregateBase, TpuExec):
                tuple(f.fingerprint() for f in bound_filters))
         buffer_npdts = tuple(physical_np_dtype(a.data_type)
                              for a in self.buffer_attrs)
-        from spark_rapids_tpu.ops.values import EvalContext, ScalarV
-        from spark_rapids_tpu.ops.eval import _scalar_to_colv
 
         def build(donate_argnums=()):
-            def kernel(cols, num_rows):
-                capacity = cols[0].validity.shape[0] if cols else 8
-                ctx = EvalContext(jnp, True, cols, num_rows, capacity)
-
-                def as_col(e):
-                    r = e.eval(ctx)
-                    if isinstance(r, ScalarV):
-                        r = _scalar_to_colv(ctx, r, e.data_type)
-                    return r
-
-                live = ctx.row_mask()
-                for f in bound_filters:
-                    r = f.eval(ctx)
-                    if isinstance(r, ScalarV):
-                        live = live & ((not r.is_null) and bool(r.value))
-                    else:
-                        live = live & r.data.astype(bool) & r.validity
-                key_cols = [as_col(e) for e in bound_keys]
-                in_cols = [as_col(e) for e in bound_inputs]
+            def agg_update(cols, num_rows):
+                key_cols, in_cols, live, capacity = _update_inputs(
+                    cols, num_rows, bound_keys, bound_inputs, bound_filters)
                 gi = _group_info_masked(key_cols, live, capacity)
                 buf_outs = []
                 for op, cv in zip(op_names, in_cols):
@@ -354,10 +339,101 @@ class TpuHashAggregateExec(_HashAggregateBase, TpuExec):
             # donate_argnums=(0,) donates the input batch's columns into
             # the update program (lazy form only: in-kernel assembly reads
             # nothing from the inputs afterwards; docs/async-execution.md)
-            return jax.jit(kernel, donate_argnums=donate_argnums)
+            return jax.jit(agg_update, donate_argnums=donate_argnums)
 
         return get_or_build(key, build,
                             donate_argnums=(0,) if donate else ())
+
+    def _build_dense_update_kernel(self, input_attrs, key_exprs,
+                                   input_exprs, op_names, filters,
+                                   radices: tuple, buffer_npdts: tuple):
+        """The update over a table of dictionary codes (exec/dense_agg.py):
+        the filters, the keys' codes and the inputs evaluate as in the
+        sort-based kernel; the grouping and the reductions are the
+        table's. Returns what the lazy kernels return: the intermediate
+        batch's columns, compact, and the group count as a device
+        scalar."""
+        from spark_rapids_tpu.engine.jit_cache import get_or_build
+
+        bound_keys = bind_all(key_exprs, input_attrs)
+        bound_inputs = bind_all(input_exprs, input_attrs)
+        bound_filters = bind_all(filters, input_attrs)
+        key = ("agg_dense_update", radices, buffer_npdts,
+               tuple(e.fingerprint() for e in bound_keys),
+               tuple(zip(op_names,
+                         (e.fingerprint() for e in bound_inputs))),
+               tuple(f.fingerprint() for f in bound_filters))
+
+        def build():
+            def agg_dense_update(cols, num_rows):
+                key_cols, in_cols, live, _ = _update_inputs(
+                    cols, num_rows, bound_keys, bound_inputs, bound_filters)
+                return DA.group_reduce(key_cols, radices, live, op_names,
+                                       in_cols, buffer_npdts)
+
+            return jax.jit(agg_dense_update)
+
+        return get_or_build(key, build)
+
+    def _build_dense_merge_kernel(self, n_keys: int, radices: tuple,
+                                  buffer_npdts: tuple):
+        from spark_rapids_tpu.engine.jit_cache import get_or_build
+
+        ops = tuple(op for op, _ in self._merge_ops())
+        key = ("agg_dense_merge", n_keys, radices, ops, buffer_npdts)
+
+        def build():
+            def agg_dense_merge(cols, num_rows):
+                capacity = cols[0].validity.shape[0]
+                return DA.group_reduce(
+                    cols[:n_keys], radices, jnp.arange(capacity) < num_rows,
+                    ops, cols[n_keys:], buffer_npdts)
+
+            return jax.jit(agg_dense_merge)
+
+        return get_or_build(key, build)
+
+    def _dense_batch(self, outs, num_groups, key_dicts,
+                     buf_dicts) -> ColumnarBatch:
+        """The intermediate batch of a dense kernel's output: a key is a
+        DictionaryColumn over its dictionary again, as is a min/max
+        buffer that was reduced over ranks; the row count stays on the
+        device."""
+        from spark_rapids_tpu.columnar.encoded import DictionaryColumn
+
+        n_keys = len(self.grouping)
+        cols = []
+        for i, ((data, validity), attr) in enumerate(
+                zip(outs, self._inter_attrs)):
+            d = key_dicts[i] if i < n_keys else \
+                (buf_dicts or {}).get(i - n_keys)
+            cols.append(DictionaryColumn(d.value_dtype, data, validity, d)
+                        if d is not None
+                        else ColumnVector(attr.data_type, data, validity))
+        return ColumnarBatch(cols, num_groups)
+
+    def _dense_radices(self, ops, key_dicts, buf_dicts, in_dtypes=None):
+        """The table's radices where this aggregate over these
+        dictionaries takes exec/dense_agg.py (every grouping key a
+        dictionary column, `key_dicts`: position -> dictionary), else
+        None: the sort-based path. `in_dtypes`: the update's input
+        types (default: the buffers', the merge's inputs)."""
+        n_keys = len(self.grouping)
+        if not n_keys or len(key_dicts) != n_keys:
+            return None
+        dts = in_dtypes if in_dtypes is not None else \
+            [a.data_type for a in self.buffer_attrs]
+        dts = [DataType.INT32 if bi in (buf_dicts or {}) else dt
+               for bi, dt in enumerate(dts)]
+        return DA.applies(ops, dts,
+                          [key_dicts[k].size for k in range(n_keys)])
+
+    def _dense_npdts(self, buf_dicts) -> tuple:
+        """Storage dtypes of the buffers in a dense kernel: a min/max
+        buffer reduced over a dictionary's ranks holds int32 codes."""
+        return tuple(np.dtype(np.int32) if bi in (buf_dicts or {})
+                     else physical_np_dtype(a.data_type)
+                     for bi, a in enumerate(self.buffer_attrs))
 
     def _lazy_ok(self) -> bool:
         """In-kernel assembly (device-scalar row counts, zero per-batch
@@ -391,7 +467,7 @@ class TpuHashAggregateExec(_HashAggregateBase, TpuExec):
                              for a in self.buffer_attrs)
 
         def build():
-            def kernel(cols, num_rows):
+            def agg_merge(cols, num_rows):
                 from spark_rapids_tpu.ops.values import narrow_colv
 
                 capacity = cols[0].validity.shape[0] if cols else 8
@@ -416,7 +492,7 @@ class TpuHashAggregateExec(_HashAggregateBase, TpuExec):
                             gi.num_groups)
                 return key_cols, buf_outs, gi
 
-            return jax.jit(kernel)
+            return jax.jit(agg_merge)
 
         return get_or_build(key, build)
 
@@ -486,6 +562,7 @@ class TpuHashAggregateExec(_HashAggregateBase, TpuExec):
 
             with M.trace_range("TpuHashAggregate.finalize",
                                self.metrics[M.TOTAL_TIME]):
+                OBS.annotate(groups=n_groups)
                 outs = with_retry(_attempt, site="agg.finalize")
             for (si, _o, dt), (d, v) in zip(fixed, outs):
                 if si in enc_slots:
@@ -595,6 +672,30 @@ class TpuHashAggregateExec(_HashAggregateBase, TpuExec):
 
         merge_op_names = [op for op, _ in self._merge_ops()]
 
+        def rows_attr(b: ColumnarBatch) -> dict:
+            return {"rows": b.num_rows} if b.rows_on_host else {}
+
+        def dense_merge(batch, rs, key_dicts, buf_dicts, code_ords):
+            """Partials with every key a dictionary column, brought to
+            one dictionary a column by the concat: the table's reduction
+            with the merge ops (exec/dense_agg.py)."""
+            from spark_rapids_tpu.columnar import encoded as ENC
+
+            npdts = self._dense_npdts(buf_dicts)
+            kern = self._build_dense_merge_kernel(n_keys, rs, npdts)
+            cols = ENC.eval_cols(batch, code_ords)
+
+            def _attempt():
+                M.record_dispatch()
+                return kern(cols, count_arg(batch))
+
+            with M.trace_range("TpuHashAggregate.merge",
+                               self.metrics[M.TOTAL_TIME]):
+                OBS.annotate(path="dense", groups=math.prod(rs),
+                             **rows_attr(batch))
+                outs, num_groups = with_retry(_attempt, site="agg.merge")
+            return self._dense_batch(outs, num_groups, key_dicts, buf_dicts)
+
         def merge(batch: ColumnarBatch) -> ColumnarBatch:
             from spark_rapids_tpu.columnar import encoded as ENC
 
@@ -630,6 +731,12 @@ class TpuHashAggregateExec(_HashAggregateBase, TpuExec):
                          for i in enc_buf_pos}
             enc_sig = tuple(sorted(enc_keys)) + ("buf",) + \
                 tuple(sorted(buf_dicts))
+            dense_rs = self._dense_radices(merge_op_names, enc_keys,
+                                           buf_dicts)
+            if dense_rs is not None:
+                return dense_merge(batch, dense_rs, enc_keys, buf_dicts,
+                                   frozenset(enc_keys)
+                                   | frozenset(enc_buf_pos))
             m_lazy = lazy and not enc_keys and not buf_dicts
             nc = str_chunks(batch, str_merge_ords)
             # capture the kernel in a local: the memo slot is shared by
@@ -654,6 +761,7 @@ class TpuHashAggregateExec(_HashAggregateBase, TpuExec):
 
             with M.trace_range("TpuHashAggregate.merge",
                                self.metrics[M.TOTAL_TIME]):
+                OBS.annotate(path="sort", **rows_attr(batch))
                 out = with_retry(_attempt, site="agg.merge")
             if m_lazy:
                 outs, num_groups = out
@@ -752,6 +860,38 @@ class TpuHashAggregateExec(_HashAggregateBase, TpuExec):
                         eff_attrs, eff_keys, eff_filters = \
                             eff_child_attrs, key_exprs, filters
                         enc_sig = ()
+                    dense_rs = self._dense_radices(
+                        eff_ops, enc_plan.key_dicts, enc_plan.buf_dicts,
+                        [e.data_type for e in eff_inputs]) \
+                        if enc_plan is not None else None
+                    if n_keys:
+                        M.record_agg_batch(dense_rs is not None)
+                    if dense_rs is not None:
+                        # every key a dictionary column and a table that
+                        # fits: no sort, no group-count fence
+                        npdts = self._dense_npdts(enc_plan.buf_dicts)
+                        kern = self._build_dense_update_kernel(
+                            eff_attrs, eff_keys, eff_inputs,
+                            tuple(eff_ops), eff_filters, dense_rs, npdts)
+                        cols = ENC.eval_cols(batch, enc_plan.code_ords)
+
+                        def _attempt():
+                            M.record_dispatch()
+                            return kern(cols, count_arg(batch))
+
+                        with M.trace_range("TpuHashAggregate.update",
+                                           self.metrics[M.TOTAL_TIME]):
+                            OBS.annotate(path="dense",
+                                         groups=math.prod(dense_rs),
+                                         **rows_attr(batch))
+                            outs, num_groups = with_retry(
+                                _attempt, site="agg.update")
+                        local = self._dense_batch(
+                            outs, num_groups, enc_plan.key_dicts,
+                            enc_plan.buf_dicts)
+                        running = local if running is None else \
+                            merge(concat_batches([running, local]))
+                        continue
                     nc = str_chunks(batch, str_update_ords)
                     b_lazy = update_lazy and \
                         (enc_plan is None or not enc_plan.code_ords) and \
@@ -792,6 +932,7 @@ class TpuHashAggregateExec(_HashAggregateBase, TpuExec):
 
                     with M.trace_range("TpuHashAggregate.update",
                                        self.metrics[M.TOTAL_TIME]):
+                        OBS.annotate(path="sort", **rows_attr(batch))
                         out = with_retry(_attempt, site="agg.update",
                                          donated=b_donate)
                     # keyed by the batch's (quantized) column vranges so the
@@ -874,7 +1015,7 @@ def _finalize_kernel(out_cap: int, npdts: tuple):
 
     def build():
         @jax.jit
-        def fn(outs, n_groups):
+        def agg_finalize(outs, n_groups):
             slot = jnp.arange(out_cap) < n_groups
             res = []
             for (data, validity), npdt in zip(outs, npdts):
@@ -885,7 +1026,7 @@ def _finalize_kernel(out_cap: int, npdts: tuple):
                 d = jnp.where(v, d, jnp.zeros((), d.dtype))
                 res.append((d, v))
             return res
-        return fn
+        return agg_finalize
 
     return get_or_build(("agg_finalize", out_cap, npdts), build)
 
@@ -912,6 +1053,34 @@ def _assemble_traced(key_cols, buf_outs, gi, capacity: int, buffer_npdts):
         d = jnp.where(v, d, jnp.zeros((), d.dtype))
         outs.append((d, v))
     return outs
+
+
+def _update_inputs(cols, num_rows, bound_keys, bound_inputs, bound_filters):
+    """Traced: what an update kernel starts from, the sort-based and the
+    dense one alike: (key columns, input columns, the rows that count,
+    the batch's capacity). A row counts when it lies under `num_rows`
+    and passes every filter the fused stage folded into the aggregate."""
+    from spark_rapids_tpu.ops.eval import _scalar_to_colv
+    from spark_rapids_tpu.ops.values import EvalContext, ScalarV
+
+    capacity = cols[0].validity.shape[0] if cols else 8
+    ctx = EvalContext(jnp, True, cols, num_rows, capacity)
+
+    def as_col(e):
+        r = e.eval(ctx)
+        if isinstance(r, ScalarV):
+            r = _scalar_to_colv(ctx, r, e.data_type)
+        return r
+
+    live = ctx.row_mask()
+    for f in bound_filters:
+        r = f.eval(ctx)
+        if isinstance(r, ScalarV):
+            live = live & ((not r.is_null) and bool(r.value))
+        else:
+            live = live & r.data.astype(bool) & r.validity
+    return ([as_col(e) for e in bound_keys],
+            [as_col(e) for e in bound_inputs], live, capacity)
 
 
 def _group_info(key_cols, num_rows, capacity: int) -> RK.GroupInfo:

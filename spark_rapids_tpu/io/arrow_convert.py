@@ -73,13 +73,85 @@ def _chunked_to_np(col: pa.ChunkedArray) -> pa.Array:
     return col.combine_chunks() if col.num_chunks != 1 else col.chunk(0)
 
 
-def arrow_to_host_batch(table: pa.Table,
-                        attrs: List[AttributeReference]) -> HostColumnarBatch:
+def _dictionary_column(col: pa.ChunkedArray, max_fraction: float):
+    """A STRING column Arrow read as dictionary arrays (one a row group,
+    each with its own dictionary, in the order its values first appear)
+    as codes + ONE dictionary, and no value decoded. The dictionary is
+    the row groups' values in ascending byte order: splits that hold the
+    same values then share one interned `DeviceDictionary`, so their
+    partial aggregates merge and sort on the codes as they are, with no
+    remap between them (columnar/encoded.py `align_encoded`,
+    `to_rank_space`). Each row group's indices are taken through a table
+    of its dictionary's positions in that order, in Arrow, as Arrow's own
+    `unify_dictionaries` would transpose them. None where the dictionary
+    fails the encoded-scan heuristic (`scan_encoded_ok`): the caller
+    decodes the column."""
+    import pyarrow.compute as pc
+
+    from spark_rapids_tpu.columnar.encoded import (
+        DeviceDictionary,
+        HostDictionaryColumn,
+        scan_encoded_ok,
+    )
+
+    n = len(col)
+    chunks = [c for c in col.chunks if len(c)]
+    dicts = [c.dictionary.cast(pa.string()) for c in chunks]
+    if any(d.null_count for d in dicts):
+        return None
+    values = pa.concat_arrays(dicts).unique() if dicts \
+        else pa.array([], pa.string())
+    if not scan_encoded_ok(len(values), n, max_fraction) and n:
+        return None
+    # binary, so that the order is the bytes' (the engine's string order)
+    values = values.take(pc.sort_indices(values.cast(pa.binary())))
+    codes = np.zeros(n, dtype=np.int32)
+    validity = np.ones(n, dtype=bool)
+    at = 0
+    for c, d in zip(chunks, dicts):
+        idx = c.indices
+        if not d.equals(values):
+            idx = pc.index_in(d, value_set=values).take(idx)
+        if idx.null_count:
+            validity[at:at + len(c)] = np.asarray(idx.is_valid())
+            idx = idx.fill_null(0)
+        codes[at:at + len(c)] = idx.to_numpy(zero_copy_only=False)
+        at += len(c)
+    if len(values):
+        _, offsets_buf, data_buf = values.buffers()
+        offsets = np.frombuffer(offsets_buf, dtype=np.int32)[
+            values.offset:values.offset + len(values) + 1]
+        byts = np.frombuffer(data_buf, dtype=np.uint8)[
+            offsets[0]:offsets[-1]] if data_buf is not None \
+            else np.zeros(0, np.uint8)
+        offsets = offsets - offsets[0]
+    else:
+        offsets, byts = np.zeros(1, np.int32), np.zeros(0, np.uint8)
+    return HostDictionaryColumn(
+        DataType.STRING, codes, validity,
+        DeviceDictionary.from_byte_table(byts, offsets))
+
+
+def arrow_to_host_batch(table: pa.Table, attrs: List[AttributeReference],
+                        dict_fraction: Optional[float] = None
+                        ) -> HostColumnarBatch:
+    """`dict_fraction` (the device scan: `rapids.tpu.sql.encoded.
+    maxDictFraction`) keeps a STRING column that Arrow hands over as
+    dictionary arrays as codes + dictionary (`HostDictionaryColumn`);
+    without it every dictionary array is decoded, as the host operators
+    need."""
     cols = []
     for attr in attrs:
         # look up by NAME: pyarrow ORC returns selected columns in file
         # order, not requested order
-        arr = _chunked_to_np(table.column(attr.name))
+        col = table.column(attr.name)
+        if dict_fraction is not None and attr.data_type is DataType.STRING \
+                and pa.types.is_dictionary(col.type):
+            coded = _dictionary_column(col, dict_fraction)
+            if coded is not None:
+                cols.append(coded)
+                continue
+        arr = _chunked_to_np(col)
         if pa.types.is_dictionary(arr.type):
             arr = arr.dictionary_decode()
         dt = attr.data_type

@@ -278,15 +278,67 @@ def plan_splits(fmt: str, paths: List[str], options: Dict[str, Any],
     return splits
 
 
+def dict_chunk_ndvs(split: FileSplit, attrs: List[AttributeReference],
+                    conf) -> Dict[str, List[int]]:
+    """The STRING columns of a parquet split that Arrow can hand over as
+    dictionary codes at no cost of its own, each with its chunks'
+    dictionary sizes: in every row group of the split the chunk is
+    dictionary-encoded throughout (a dictionary page, no PLAIN data page
+    behind it: the page headers say, the footer cannot) and its
+    dictionary passes the encoded-scan heuristic
+    (`rapids.tpu.sql.encoded.*`). Any other STRING column (a PLAIN
+    `l_comment`) is not named: asked for as a dictionary, Arrow would
+    build one for it value by value. Headers only: a few hundred bytes a
+    chunk."""
+    from spark_rapids_tpu import conf as C
+    from spark_rapids_tpu.columnar import encoded as ENC
+    from spark_rapids_tpu.io import parquet_device as PD
+
+    strings = [a.name for a in attrs if a.data_type is DataType.STRING]
+    if split.fmt != "parquet" or not strings or \
+            not conf.get(C.ENCODED_ENABLED):
+        return {}
+    import pyarrow.parquet as pq
+
+    frac = conf.get(C.ENCODED_MAX_DICT_FRACTION)
+    md = pq.read_metadata(split.path)
+    groups = list(split.row_groups) if split.row_groups is not None \
+        else list(range(md.num_row_groups))
+    if not groups:
+        return {}
+    index = {md.row_group(groups[0]).column(ci).path_in_schema: ci
+             for ci in range(md.num_columns)}
+    out: Dict[str, List[int]] = {}
+    # a row group without rows holds no value to decode either way
+    groups = [rg for rg in groups if md.row_group(rg).num_rows]
+    for name in strings:
+        ci = index.get(name)
+        ndvs = []
+        for rg in groups if ci is not None else ():
+            col = md.row_group(rg).column(ci)
+            ndv = PD.chunk_dict_ndv(split.path, col)
+            if ndv is None or not ENC.scan_encoded_ok(
+                    ndv, md.row_group(rg).num_rows, frac) or \
+                    PD.chunk_dict_only(split.path, col) is not True:
+                break
+            ndvs.append(ndv)
+        if ci is not None and len(ndvs) == len(groups):
+            out[name] = ndvs
+    return out
+
+
 def read_split(split: FileSplit, attrs: List[AttributeReference],
-               pf=None) -> pa.Table:
+               pf=None, dict_columns: Tuple[str, ...] = ()) -> pa.Table:
     """The split's rows of `attrs` as one Arrow table (`pf`: the split's
-    parquet file where the caller has it open already)."""
+    parquet file where the caller has it open already). `dict_columns`
+    (`dict_chunk_ndvs` names them) come back as dictionary arrays, one a
+    row group, undecoded."""
     names = [a.name for a in attrs]
     if split.fmt == "parquet":
         import pyarrow.parquet as pq
 
-        pf = pf or pq.ParquetFile(split.path)
+        pf = pf or pq.ParquetFile(split.path,
+                                  read_dictionary=list(dict_columns) or None)
         groups = list(split.row_groups) if split.row_groups is not None \
             else list(range(pf.metadata.num_row_groups))
         return pf.read_row_groups(groups, columns=names)
@@ -391,7 +443,8 @@ class _FileScanBase(PhysicalExec):
     def node_name(self):
         return f"{type(self).__name__}({self.fmt}, {len(self.splits)} splits)"
 
-    def _read_host_iter(self, split: FileSplit, conf, stage: bool = False):
+    def _read_host_iter(self, split: FileSplit, conf, stage: bool = False,
+                        dict_columns: Tuple[str, ...] = ()):
         """Generator form of the host decode: the Arrow read runs on first
         pull, so a prefetch wrapper (io/prefetch.py) moves the WHOLE decode
         onto its worker thread — batch k+1 of the query decodes while
@@ -411,11 +464,21 @@ class _FileScanBase(PhysicalExec):
         # on the prefetcher's thread, which carries the task's context
         # and span (io/prefetch.py)
         with obs_span("scan.host_decode", columns=len(data_attrs)) as sp:
-            table = read_split(split, data_attrs)
-            batch = arrow_to_host_batch(table, data_attrs)
+            table = read_split(split, data_attrs, dict_columns=dict_columns)
+            batch = arrow_to_host_batch(
+                table, data_attrs,
+                conf.get(C.ENCODED_MAX_DICT_FRACTION) if dict_columns
+                else None)
             del table
             if sp is not None:
                 sp.attrs["rows"] = batch.num_rows
+                if dict_columns:
+                    coded = [c for c in batch.columns
+                             if getattr(c, "dictionary", None) is not None]
+                    sp.attrs["dict_columns"] = len(coded)
+                    sp.attrs["dict_bytes"] = sum(
+                        c.data.nbytes + int(c.dictionary.host_offsets[-1])
+                        for c in coded)
             if pv:
                 # append partition-value constant columns (reference:
                 # ColumnarPartitionReaderWithPartitionValues)
@@ -441,13 +504,15 @@ class _FileScanBase(PhysicalExec):
             yield batches.pop()
 
     def _host_batches_prefetched(self, split: FileSplit, conf,
-                                 stage: bool = False):
+                                 stage: bool = False,
+                                 dict_columns: Tuple[str, ...] = ()):
         """Host decode iterator with the configured double-buffering depth
         (rapids.tpu.io.prefetchBatches; per-read option overrides)."""
         from spark_rapids_tpu.io.prefetch import maybe_prefetch, prefetch_depth
 
-        return maybe_prefetch(self._read_host_iter(split, conf, stage),
-                              prefetch_depth(conf, split))
+        return maybe_prefetch(
+            self._read_host_iter(split, conf, stage, dict_columns),
+            prefetch_depth(conf, split))
 
 
 class CpuFileScanExec(_FileScanBase, CpuExec):
@@ -501,12 +566,13 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
     def execute(self, ctx: ExecContext) -> PartitionedBatches:
         from spark_rapids_tpu import conf as C
 
-        # the attributes alone say whether a split can hold a column the
-        # device decodes (parquet_device.column_eligible: strings): a
-        # scan without one opens no footer to find that out
-        device_decode = self.fmt == "parquet" and \
-            ctx.conf.get(C.PARQUET_DEVICE_DECODE) and \
-            any(a.data_type is DataType.STRING for a in self.attrs)
+        # the attributes alone say whether a split can hold a STRING
+        # column: a scan without one opens no footer to find out how it
+        # is encoded
+        n_strings = sum(a.data_type is DataType.STRING
+                        for a in self.attrs) if self.fmt == "parquet" else 0
+        device_decode = n_strings > 0 and \
+            ctx.conf.get(C.PARQUET_DEVICE_DECODE)
         device_csv = self.fmt == "csv" and ctx.conf.get(C.CSV_DEVICE_PARSE)
         device_orc = self.fmt == "orc" and ctx.conf.get(C.ORC_DEVICE_DECODE)
 
@@ -514,7 +580,15 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
             from spark_rapids_tpu.engine.retry import with_retry
 
             def gen():
-                if device_decode:
+                # a dictionary-encoded STRING column is Arrow's too: it
+                # hands the codes and the dictionary over undecoded
+                # (PERF.md section 6, PR 37). The device decoder keeps a
+                # split that holds a STRING column Arrow would have to
+                # decode value by value (PLAIN, a dictionary that fell
+                # back): there it decodes every STRING column of the split
+                dict_columns = self._arrow_dict_columns(pidx, ctx.conf) \
+                    if n_strings else ()
+                if device_decode and len(dict_columns) < n_strings:
                     # a generator over the split's row groups; False where
                     # no column qualified, and nothing was yielded
                     if (yield from self._read_device(self.splits[pidx],
@@ -540,13 +614,15 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
                     if batches is not None:
                         yield from batches
                         return
-                yield from self._read_host(self.splits[pidx], ctx.conf)
+                yield from self._read_host(self.splits[pidx], ctx.conf,
+                                           dict_columns)
 
             return count_output(self.metrics, gen())
 
         return PartitionedBatches(len(self.splits), factory)
 
-    def _read_host(self, split: FileSplit, conf):
+    def _read_host(self, split: FileSplit, conf,
+                   dict_columns: Tuple[str, ...] = ()):
         """Host path: decode AND packing double-buffer on the prefetch
         worker (inline, on this thread, at depth 0: either way before the
         task asks for its permit), so what runs under the permit is the
@@ -554,7 +630,8 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
         unblocked device future) and nothing else: batch k+1's decode
         and packing overlap batch k's upload and downstream compute, and
         another task's."""
-        for staged in self._host_batches_prefetched(split, conf, stage=True):
+        for staged in self._host_batches_prefetched(
+                split, conf, stage=True, dict_columns=dict_columns):
             TpuSemaphore.get().acquire_if_necessary(current_task_id())
             yield self._upload(staged)
 
@@ -572,6 +649,13 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
             batch = with_retry(staged.upload, site="scan")
             if sp is not None:
                 sp.attrs["bytes"] = batch.device_memory_size()
+        coded = [cv for cv in batch.columns
+                 if getattr(cv, "dictionary", None) is not None]
+        if coded:
+            from spark_rapids_tpu.columnar.encoded import record_scan_emission
+
+            for cv in coded:
+                record_scan_emission(cv, batch.num_rows)
         return batch
 
     def _read_device_csv(self, split: FileSplit, conf):
@@ -913,6 +997,47 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
         return [slice_batch_host(batch, i, max_rows)
                 for i in range(0, rows, max_rows)]
 
+    def _dict_ndvs_by_split(self, conf) -> List[Dict[str, List[int]]]:
+        """`dict_chunk_ndvs` of every split, read once for the exec and
+        these conf values: the planner asks first, and the tasks of every
+        action after it find the answer here (the headers are parsed in
+        Python, which eight tasks starting together would do one after
+        another). The plan cache keys a physical plan by its files' size
+        and mtime, so an exec never outlives the footers it read."""
+        from spark_rapids_tpu import conf as C3
+
+        key = (conf.get(C3.ENCODED_ENABLED),
+               conf.get(C3.ENCODED_MAX_DICT_FRACTION))
+        cached = getattr(self, "_dict_ndvs_cache", None)
+        if cached is None or cached[0] != key:
+            cached = (key, [dict_chunk_ndvs(sp, self.attrs, conf)
+                            for sp in self.splits])
+            self._dict_ndvs_cache = cached
+        return cached[1]
+
+    def _arrow_dict_columns(self, pidx: int, conf) -> Tuple[str, ...]:
+        """The STRING columns of split `pidx` that the scan asks Arrow to
+        hand over undecoded."""
+        return tuple(self._dict_ndvs_by_split(conf)[pidx])
+
+    def dict_columns_plan(self, conf) -> Dict[str, Tuple[int, int]]:
+        """Plan-time: the STRING columns that every split of the scan
+        hands over as dictionary codes (`dict_chunk_ndvs`, the runtime's
+        own rule and its own reading), each with (the largest chunk
+        dictionary, the sum of all chunks' dictionaries): the first is
+        what a task's unified dictionary is expected to hold, the second
+        a sound bound on the distinct values of the whole column."""
+        try:
+            per_split = self._dict_ndvs_by_split(conf)
+        except Exception:  # unreadable footer: the runtime says so
+            return {}
+        out: Dict[str, Tuple[int, int]] = {}
+        for name in per_split[0] if per_split else ():
+            if all(name in d for d in per_split):
+                ndvs = [n for d in per_split for n in d[name]]
+                out[name] = (max(ndvs, default=0), sum(ndvs))
+        return out
+
     def encoded_plan(self, conf) -> Dict[str, str]:
         """Plan-time mirror of the runtime encoded-scan decision
         (columnar/encoded.py): column name -> 'certain' (every row group
@@ -1040,6 +1165,11 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
                         else "possible"
             except Exception:
                 out = {}
+        if self.fmt == "parquet":
+            # what Arrow hands over as codes is encoded whether or not
+            # the device decoder is on (`execute`'s routing)
+            out.update(dict.fromkeys(self.dict_columns_plan(conf),
+                                     "certain"))
         self._encoded_plan_cache = ((enabled, frac), out)
         return out
 

@@ -1305,9 +1305,17 @@ class _Analyzer:
         batch_rows = Interval(0, cap_rows if rows_hi == INF
                               else min(cap_rows, rows_hi))
         self._inexact()
+        # a column every chunk of which is dictionary-encoded has at most
+        # the sum of its chunks' dictionaries of distinct values, and a
+        # null: what sizes a group-by over it from its groups, not from
+        # its rows (ROADMAP M3)
+        dict_plan = node.dict_columns_plan(self.conf) \
+            if hasattr(node, "dict_columns_plan") else {}
+        ndv = {a.expr_id: dict_plan[a.name][1] + 1
+               for a in node.output if a.name in dict_plan}
         st = self._mk(node, Interval(0, rows_hi), parts,
                       Interval(0, parts), Interval(0, INF), batch_rows,
-                      set())
+                      set(), ndv=ndv or None)
         # decode staging: raw split bytes + the in-flight decoded batches.
         # Prefetch double-buffering multiplies the latter: with depth k
         # the consumer's batch, the worker's in-hand batch, and k queued
@@ -1839,14 +1847,9 @@ class _Analyzer:
         hint = node.bucket_rows_hints[-1]
 
         try:
-            import jax
+            from spark_rapids_tpu.plan.spmd import mesh_size
 
-            from spark_rapids_tpu import conf as _C
-
-            m = len(jax.devices())
-            want = int(self.conf.get(_C.SPMD_MESH_DEVICES) or 0)
-            if want:
-                m = min(m, want)
+            m = mesh_size(self.conf)
         except Exception:  # pragma: no cover - no backend at plan time
             m = 1
         m_out = 1 if node.info.sort is not None else m

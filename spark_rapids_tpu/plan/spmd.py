@@ -67,7 +67,7 @@ from spark_rapids_tpu.exec.base import (
     TpuExec,
     count_output,
 )
-from spark_rapids_tpu.ops.base import AttributeReference, Expression
+from spark_rapids_tpu.ops.base import Alias, AttributeReference, Expression
 
 log = logging.getLogger(__name__)
 
@@ -707,6 +707,59 @@ class TpuSpmdStageExec(TpuExec):
             bucket_costs=pb.bucket_costs)
 
 
+def mesh_size(conf: C.TpuConf) -> int:
+    """Devices a stage program of this session would span
+    (shuffle/ici.stage_mesh's count, without building the mesh)."""
+    import jax
+
+    m = len(jax.devices())
+    want = int(conf.get(C.SPMD_MESH_DEVICES) or 0)
+    return min(m, want) if want else m
+
+
+def _streams_dense(infos: List[SpmdStageInfo], conf: C.TpuConf) -> bool:
+    """Is this pipeline better left to the streaming operators? On a mesh
+    of ONE device, where every grouping key is a dictionary-coded STRING
+    column of a parquet scan and the table over the dictionaries the
+    footers report fits `exec/dense_agg.py`: the partial aggregate then
+    reduces each batch as its task uploads it into a table of a few
+    slots, with no sort and no fence, and what the exchange, the merge
+    and the sort see is a handful of rows. The single program has nothing
+    to exchange across chips there, and would first re-pack the whole
+    stage input into one [1, capacity] table (a row-count fence and a
+    copy of every column) to aggregate it into the same few slots; sized
+    from its input rows it did not fit `spmd.maxSortLanes` at all (TPC-H
+    Q1 at SF1: ROADMAP S6, M3). On a larger mesh the program is what
+    spreads the work, and the analyzer sizes its buckets from the same
+    dictionaries (plan/resources._file_scan)."""
+    from spark_rapids_tpu.exec import dense_agg as DA
+    from spark_rapids_tpu.io.scan import TpuFileScanExec
+
+    if len(infos) != 1 or infos[0].joins:
+        return False
+    info = infos[0]
+    if not info.key_exprs:
+        return False
+    scans = info.input_node.collect_nodes(
+        lambda n: isinstance(n, TpuFileScanExec))
+    if len(scans) != 1:
+        return False
+    if mesh_size(conf) != 1:
+        return False
+    plan = scans[0].dict_columns_plan(conf)
+    names = {a.expr_id: a.name for a in scans[0].output}
+    sizes = []
+    for e in info.key_exprs:
+        inner = e.child if isinstance(e, Alias) else e
+        if not isinstance(inner, AttributeReference) or \
+                names.get(inner.expr_id) not in plan:
+            return False
+        sizes.append(plan[names[inner.expr_id]][0])
+    return DA.applies(info.op_names,
+                      [e.data_type for e in info.input_exprs],
+                      sizes) is not None
+
+
 def lower_spmd_stages(plan: PhysicalExec, conf: C.TpuConf) -> PhysicalExec:
     """Wrap every maximal SPMD-eligible pipeline (chains included) in a
     TpuSpmdStageExec. Runs LAST in the plan pipeline (after fusion), so
@@ -723,6 +776,8 @@ def lower_spmd_stages(plan: PhysicalExec, conf: C.TpuConf) -> PhysicalExec:
     def walk(node: PhysicalExec) -> PhysicalExec:
         infos = match_spmd_chain(node, join_lowering=join_lowering,
                                  chaining=chaining)
+        if infos is not None and _streams_dense(infos, conf):
+            infos = None
         if infos is not None:
             # recurse only at/below the CHAIN's innermost stage input (a
             # deeper ineligible producer may still contain eligible

@@ -390,6 +390,30 @@ def dispatch_count() -> int:
     return _DISPATCHES.value
 
 
+# grouped-aggregate update batches by the path they took: the table over
+# dictionary codes (exec/dense_agg.py) or the sort (exec/rowkeys.py). An
+# ungrouped aggregate counts in neither
+DENSE_AGG_BATCHES = "denseAggBatches"
+SORT_AGG_BATCHES = "sortAggBatches"
+_DENSE_AGG_BATCHES = Metric(DENSE_AGG_BATCHES)
+_SORT_AGG_BATCHES = Metric(SORT_AGG_BATCHES)
+
+
+def record_agg_batch(dense: bool) -> None:
+    name, metric = (DENSE_AGG_BATCHES, _DENSE_AGG_BATCHES) if dense \
+        else (SORT_AGG_BATCHES, _SORT_AGG_BATCHES)
+    metric.add(1)
+    _note(name, 1)
+
+
+def dense_agg_batch_count() -> int:
+    return _DENSE_AGG_BATCHES.value
+
+
+def sort_agg_batch_count() -> int:
+    return _SORT_AGG_BATCHES.value
+
+
 # ---------------------------------------------------------------------------
 # Fault-tolerance accounting (engine/retry.py increments; queries snapshot
 # before/after, same pattern as the dispatch counter above)

@@ -47,6 +47,22 @@ def _transfer_guard_sanitizer(request):
         yield
 
 
+@pytest.fixture
+def device_string_decoder(monkeypatch):
+    """Every parquet STRING column to the device decoder
+    (io/parquet_device.py), as a scan routed them until PR 37 gave the
+    dictionary-encoded ones to Arrow (io/scan.py `dict_chunk_ndvs`; here
+    no split of any scan names a column Arrow could hand over as codes,
+    to the planner and to the tasks alike). The decoder keeps the splits
+    that hold a PLAIN string column, and its tests keep reading the small
+    dictionary files they always read: they steer here, in the test, not
+    through an option of the program."""
+    from spark_rapids_tpu.io.scan import TpuFileScanExec
+
+    monkeypatch.setattr(TpuFileScanExec, "_dict_ndvs_by_split",
+                        lambda self, conf: [{} for _ in self.splits])
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _clear_jit_caches_per_module():
     """Release compiled executables between test modules. A full-suite run

@@ -11,9 +11,10 @@ text holds no f64: the chip's compiler would not refuse one, it would
 emulate it, at minutes of compile per program (the first chip run of PR 22
 spent 33 s on q6 for that reason).
 
-Sort-bearing programs compile for minutes at every size (q1's aggregate
-update kernel: 255 s cold on the v5e, PR 22), so q1's is only lowered here
-and its compile is left to chip_smoke.py.
+Sort-bearing programs compile for minutes at every size (q1's sort-based
+aggregate update kernel: 255 s cold on the v5e, PR 22). Since PR 37 q1's
+update is the dense table of exec/dense_agg.py, which holds no sort, and
+is compiled here at an SF1 partition's capacity.
 """
 
 import os
@@ -205,18 +206,53 @@ def test_q6_fused_aggregate_in_f32(smoke_programs, one_chip,
     lowered.compile()
 
 
-def test_q1_aggregate_update_kernel_lowers_in_f32(smoke_programs, one_chip):
-    """The sort-based group-by update of q1 (two encoded string keys, eight
-    aggregates). Lowered only: its compile is minutes (module docstring)."""
-    calls = [c for c in smoke_programs.rec.programs(
+def test_q1_aggregate_update_kernel_is_dense_in_f32(
+        smoke_programs, one_chip, no_persistent_cache):
+    """q1's update since PR 37: two dictionary-coded string keys from
+    Arrow, eight aggregates, the table of exec/dense_agg.py: no sort and
+    no scatter in the program, DOUBLE in f32, and a compile of seconds at
+    an SF1 partition's capacity (the sort-based kernel it replaces took
+    255 s on the v5e and was only lowered here)."""
+    calls = smoke_programs.rec.programs("_build_dense_update_kernel",
+                                        "exec/aggregate.py")
+    assert calls, "q1 built no dense update kernel"
+    assert not [c for c in smoke_programs.rec.programs(
         "_build_update_kernel", "exec/aggregate.py")
-        if "stablehlo.sort" in c[1].lower(*c[2], **c[3]).as_text()]
-    assert calls, "q1 built no sort-based update kernel"
+        if "stablehlo.sort" in c[1].lower(*c[2], **c[3]).as_text()], \
+        "q1 built a sort-based update kernel beside it"
     fun, jitted, args, kwargs = calls[0]
     tiny = _capacity_of(args)
-    _, text = _lower(jitted, _at_capacity(
-        args, tiny, ROW_GROUP_CAP, one_chip), kwargs)
-    assert f"tensor<{ROW_GROUP_CAP}xf32>" in text
+    lowered, text = _lower(jitted, _at_capacity(
+        args, tiny, PARTITION_CAP, one_chip), kwargs)
+    assert f"tensor<{PARTITION_CAP}xf32>" in text
+    assert "stablehlo.sort" not in text and "stablehlo.scatter" not in text
+    ma = lowered.compile().memory_analysis()
+    assert ma.temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("builder, filename, sorts", [
+    ("_build_dense_merge_kernel", "exec/aggregate.py", False),
+    ("TpuSortExec._build_kernel", "exec/sort.py", True),
+])
+def test_q1_behind_the_update_sees_a_table_not_a_partition(
+        smoke_programs, one_chip, no_persistent_cache, builder, filename,
+        sorts):
+    """What runs behind q1's update sees the groups, never the rows: the
+    merge of the partials is the table's reduction again, and the ORDER
+    BY sorts a handful of lanes. Their shapes do not grow with the scale
+    factor, so they compile here at the size the chip runs them (a sort
+    over a partition's lanes was minutes)."""
+    calls = smoke_programs.rec.programs(builder, filename)
+    assert calls, f"q1 built no {builder} program"
+    for fun, jitted, args, kwargs in calls:
+        assert _capacity_of(args) <= 4096
+        shapes = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+            if isinstance(x, (jax.Array, np.ndarray)) else x, args)
+        lowered, text = _lower(jitted, shapes, kwargs)
+        assert ("stablehlo.sort" in text) == sorts
+        lowered.compile()
 
 
 def test_parquet_decode_programs(device_flavour, one_chip,

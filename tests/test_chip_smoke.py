@@ -65,7 +65,11 @@ def test_rehearsal_query_matches_reference(smoke, name):
     assert line["watchdogKills"] == 0 and line["speculativeTasks"] == 0
     assert line["cold"]["watchdogKills"] == 0
     if name == "q1":
-        assert line["spmd_planned"] and not line["spmd_degraded"]
+        # one device in the mesh and two dictionary-coded string keys:
+        # the planner leaves q1 to the streaming operators and the dense
+        # aggregate (plan/spmd.py `_streams_dense`, PR 37); before, the
+        # stage was planned here and degraded at SF1 on the chip
+        assert not line["spmd_planned"] and not line["spmd_degraded"]
     if name == "q3":
         # the join stage overflows the SPMD lane budget and the executor
         # reroutes it to the host loop without failing: reported, not fatal
@@ -140,6 +144,7 @@ def _failing_decoder(monkeypatch, exc):
     monkeypatch.setattr(PD, "decode_chunk_device", decode)
 
 
+@pytest.mark.usefixtures("device_string_decoder")
 def test_device_decode_error_reaches_the_script(smoke, monkeypatch):
     """A device decoder that fails (as a compiler or runtime error on the
     chip would make it) is not turned into a host decode of the split:
@@ -152,6 +157,7 @@ def test_device_decode_error_reaches_the_script(smoke, monkeypatch):
                              smoke.ref_tables)
 
 
+@pytest.mark.usefixtures("device_string_decoder")
 def test_refused_page_shape_is_counted_and_fails_the_smoke(
         smoke, monkeypatch):
     """A page shape the device decoder refuses still decodes on the host

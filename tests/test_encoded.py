@@ -615,6 +615,7 @@ def test_mixed_bare_and_computed_partition_keys(session, tmp_path):
 # ---------------------------------------------------------------------------
 # Fault injection at the materialize site
 # ---------------------------------------------------------------------------
+@pytest.mark.usefixtures("device_string_decoder")
 def test_fault_injection_at_materialize_site(session, tmp_path):
     """Injected OOM at encoded.materialize: spill+retry owns it, the
     query completes oracle-equal."""
@@ -963,6 +964,7 @@ def test_run_tables_attach_and_survive_concat(session, tmp_path):
     assert cv.runs.num_runs < md.row_group(0).num_rows // 4
 
 
+@pytest.mark.usefixtures("device_string_decoder")
 @pytest.mark.parametrize("seed", SEEDS[:2])
 def test_run_collapsed_aggregate_oracle_equal(session, tmp_path, seed):
     """Sorted/low-cardinality scan -> the update batch collapses to one

@@ -207,6 +207,7 @@ def device_chunks(monkeypatch):
     assert all(dt is DataType.STRING for dt, _ in seen), seen
 
 
+@pytest.mark.usefixtures("device_string_decoder")
 class TestDeviceParquetDecode:
     """The parquet scan vs the Arrow oracle: fixed-width columns through
     Arrow's read, the string column through the device decoder

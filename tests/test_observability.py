@@ -274,6 +274,53 @@ def test_traced_fixed_width_parquet_scan_spans(tmp_path):
         assert asked.end_ns <= upload.start_ns
 
 
+@pytest.mark.parametrize("path", ["dense", "sort"])
+def test_traced_grouped_aggregate_spans(tmp_path, monkeypatch, path):
+    """A dictionary-encoded STRING column is Arrow's too (PR 37): the
+    split's scan.host_decode says how many columns it kept as codes
+    (`dict_columns`, `dict_bytes`), and a group-by over them runs the
+    table of exec/dense_agg.py: its update and merge spans say `path`,
+    `groups` (the table's slots) and `rows`, and the task counts
+    `denseAggBatches`. With the table taken away (in the test) the same
+    query says `sort` and counts `sortAggBatches`."""
+    from spark_rapids_tpu.exec import dense_agg as DA
+
+    if path == "sort":
+        monkeypatch.setattr(DA, "MAX_GROUPS", 0)
+    # the streaming operators either way: without the table the planner
+    # would give a one-device group-by to the SPMD stage program
+    session = srt.new_session({"rapids.tpu.sql.spmd.enabled": False,
+                               C.OBS_TRACING.key: True})
+    try:
+        df, _ = _parquet_source(session, tmp_path, string=True)
+        rows = df.groupBy("s").agg(F.sum("q").alias("sq")).collect()
+        trace = session.last_query_trace
+        assert session.last_query_metrics[M.CPU_FALLBACK_EVENTS] == 0
+    finally:
+        session.stop()
+    assert sorted(r[0] for r in rows) == ["flag0", "flag1", "flag2"]
+    assert not trace.find("scan.decode")      # no device decode program
+    decodes = trace.find("scan.host_decode")
+    assert len(decodes) == 2
+    for sp in decodes:
+        assert sp.attrs["dict_columns"] == 1 and sp.attrs["columns"] == 2
+        # 8192 int32 codes and the bytes of three five-letter values
+        assert sp.attrs["dict_bytes"] == 2 * 4096 * 4 + 15
+    updates = trace.find("TpuHashAggregate.update")
+    assert len(updates) == 2
+    for sp in updates:
+        assert sp.attrs["path"] == path and sp.attrs["rows"] == 2 * 4096
+        # a radix of 4 holds three values and the null key
+        assert sp.attrs.get("groups") == (4 if path == "dense" else None)
+    merges = trace.find("TpuHashAggregate.merge")
+    assert merges and all(sp.attrs["path"] == path for sp in merges)
+    counted = {name: sum(sp.counts.get(name, 0) for sp in trace.spans())
+               for name in (M.DENSE_AGG_BATCHES, M.SORT_AGG_BATCHES)}
+    assert counted == {M.DENSE_AGG_BATCHES: 2 * (path == "dense"),
+                       M.SORT_AGG_BATCHES: 2 * (path == "sort")}
+
+
+@pytest.mark.usefixtures("device_string_decoder")
 def test_traced_parquet_scan_spans(tmp_path):
     """A scan with a string column: one scan.read (the host half's, PR 29:
     before the task asks for its permit) and one scan.decode (the device

@@ -44,6 +44,9 @@ from spark_rapids_tpu.memory.semaphore import TpuSemaphore
 from spark_rapids_tpu.plan import functions as F
 from spark_rapids_tpu.utils import metrics as M
 
+# the device decoder's two halves, over files of dictionary strings
+pytestmark = pytest.mark.usefixtures("device_string_decoder")
+
 PREFETCH = C.IO_PREFETCH_BATCHES.key
 DEVICE_DECODE = C.PARQUET_DEVICE_DECODE.key
 ROWS = 2048  # a row group
@@ -550,12 +553,12 @@ class _Watch:
         stage_upload = HostColumnarBatch.stage_upload
         upload = StagedUpload.upload
 
-        def owned_iter(scan, split, conf, stage=False):
+        def owned_iter(scan, split, conf, *args):
             task = current_task_id()
 
             def pulled():
                 watch._task.id = task
-                yield from read_host_iter(scan, split, conf, stage)
+                yield from read_host_iter(scan, split, conf, *args)
 
             return pulled()
 

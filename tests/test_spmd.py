@@ -359,6 +359,7 @@ def test_explain_surfaces_join_lowering(session):
 # ---------------------------------------------------------------------------
 # Encoded stage inputs: codes flow into the program
 # ---------------------------------------------------------------------------
+@pytest.mark.usefixtures("device_string_decoder")
 def test_encoded_stage_inputs_stay_codes(session, tmp_path):
     """Dictionary-encoded parquet strings enter the stage program as
     int32 CODES (filter rewritten to code space, group key grouped on

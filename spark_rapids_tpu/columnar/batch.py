@@ -1199,26 +1199,66 @@ def device_const(arr: np.ndarray):
         return got
 
 
+# the most operands one pack program takes: tracing a jit over hundreds of
+# operands costs seconds
+_PACK_OPERANDS = 64
+
+
+class PackTally:
+    """What the pack programs of one concat did, for the span that asked
+    (`exec/transitions._coalesce_iter`): `operands` is the largest operand
+    count one `_pack3d` call saw, `programs` the jitted pack programs
+    dispatched (the runs and joins of `_pack3d`, the pack kernels)."""
+
+    __slots__ = ("operands", "programs")
+
+    def __init__(self):
+        self.operands = 0
+        self.programs = 0
+
+
+_TALLY = threading.local()
+
+
+@contextlib.contextmanager
+def pack_tally():
+    """Tally the calling thread's pack programs inside the block."""
+    outer = getattr(_TALLY, "open", None)
+    tally = _TALLY.open = PackTally()
+    try:
+        yield tally
+    finally:
+        _TALLY.open = outer
+
+
+def _note_pack_program(operands: int = 0) -> None:
+    """One pack program dispatched, to the calling thread's open tally."""
+    tally = getattr(_TALLY, "open", None)
+    if tally is not None:
+        tally.programs += 1
+        tally.operands = max(tally.operands, operands)
+
+
 def _pack3d(piece_lists: Sequence[Sequence], m_pad: int, bkt: int):
     """Pack C columns x M same-bucket pieces into one (C, m_pad, bkt)
     matrix with ONE jitted concatenate + reshape (+ pad) program. jnp.stack
     costs an expand_dims dispatch per operand, and even the fused eager
     concatenate pays a per-op dispatch that a jitted launch pipelines
-    away."""
+    away. Past `_PACK_OPERANDS` operands the pieces are first concatenated
+    in runs of that many (`_concat_runs`), so no program takes more and
+    none is issued eagerly; the operand order is the same."""
     from spark_rapids_tpu.engine.jit_cache import get_or_build
 
     c = len(piece_lists)
     m = len(piece_lists[0])
     flat = [p for pieces in piece_lists for p in pieces]
-    if len(flat) > 64:
-        # tracing a jit over hundreds of operands costs seconds; at that
-        # piece count the two eager dispatches are already amortized
-        mat = jnp.concatenate(flat).reshape(c, m, bkt)
-        if m_pad > m:
-            mat = jnp.pad(mat, [(0, 0), (0, m_pad - m), (0, 0)])
-        return mat
-    key = ("pack3d", c, m, m_pad, bkt,
-           tuple(p.dtype.name for p in flat))
+    _note_pack_program(operands=len(flat))
+    if len(flat) > _PACK_OPERANDS:
+        flat = _concat_runs(flat)
+        key = ("pack3d_join", c, m, m_pad, bkt, flat[0].dtype.name)
+    else:
+        key = ("pack3d", c, m, m_pad, bkt,
+               tuple(p.dtype.name for p in flat))
 
     def build():
         def fn(flat_arrs):
@@ -1230,6 +1270,37 @@ def _pack3d(piece_lists: Sequence[Sequence], m_pad: int, bkt: int):
         return jax.jit(fn)
 
     return get_or_build(key, build)(flat)
+
+
+def _concat_runs(flat: List) -> List:
+    """Bring more than `_PACK_OPERANDS` same-dtype flat arrays down to at
+    most that many, in order: each run of `_PACK_OPERANDS` goes through
+    one cached jitted concatenate, level by level. At every level all
+    arrays but the last are one length, so a program's key is the run's
+    operand count and those two lengths: a full run, a remainder run, and
+    nothing that names the piece count."""
+    from spark_rapids_tpu.engine.jit_cache import get_or_build
+
+    def build():
+        def pack3d_run(run):
+            return jnp.concatenate(run)
+
+        return jax.jit(pack3d_run)
+
+    dtype = flat[0].dtype.name
+    while len(flat) > _PACK_OPERANDS:
+        level = []
+        for i in range(0, len(flat), _PACK_OPERANDS):
+            run = flat[i:i + _PACK_OPERANDS]
+            if len(run) == 1:
+                level.append(run[0])
+                continue
+            key = ("pack3d_run", dtype, len(run), run[0].shape[0],
+                   run[-1].shape[0])
+            _note_pack_program()
+            level.append(get_or_build(key, build)(run))
+        flat = level
+    return flat
 
 
 def _dtype_subgroups(cols_of_first_piece) -> List[Tuple[str, Tuple[int, ...]]]:
@@ -1410,6 +1481,7 @@ def _pack_kernel(name: str, traced, statics: tuple, *args):
 
     key = (name,) + tuple(args[i] for i in statics)
     fn = get_or_build(key, lambda: jax.jit(traced, static_argnums=statics))
+    _note_pack_program()
     return fn(*args)
 
 

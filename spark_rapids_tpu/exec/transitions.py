@@ -25,6 +25,7 @@ from spark_rapids_tpu.columnar.batch import (
     HostColumnarBatch,
     HostColumnVector,
     concat_batches,
+    pack_tally,
 )
 from spark_rapids_tpu.columnar.dtypes import DataType
 from spark_rapids_tpu.exec.base import (
@@ -254,17 +255,27 @@ def _coalesce_iter(it: Iterator, goal: CoalesceGoal, concat, size_of,
     pending: List = []
     pending_bytes = 0
     concat_time = metrics["concatTime"]
+
+    def concat_pending():
+        # the span's attrs say what the concat was given and what packing
+        # it cost (columnar/batch.PackTally; a host concat packs nothing)
+        with pack_tally() as tally:
+            out = concat(pending)
+        OBS.annotate(pieces=len(pending), operands=tally.operands,
+                     programs=tally.programs)
+        return out
+
     for b in it:
         if target is not None and pending and \
                 pending_bytes + size_of(b) > target:
             with M.trace_range("coalesce-concat", concat_time):
-                yield concat(pending)
+                yield concat_pending()
             pending, pending_bytes = [], 0
         pending.append(b)
         pending_bytes += size_of(b)
     if pending:
         with M.trace_range("coalesce-concat", concat_time):
-            yield concat(pending)
+            yield concat_pending()
 
 
 def _concat_host(batches: List[HostColumnarBatch]) -> HostColumnarBatch:

@@ -1,6 +1,8 @@
 """Columnar substrate tests (reference test model: GpuColumnVector round-trip
 coverage inside tests/ suites; GpuCoalesceBatchesSuite for concat)."""
 
+import itertools
+
 import numpy as np
 import pytest
 
@@ -488,3 +490,175 @@ def test_staged_uploads_own_their_buffers():
     want = repr(hb.to_pylist_rows())  # (a NaN equals no NaN)
     assert repr(up.to_host().to_pylist_rows()) == want
     assert repr(again.to_host().to_pylist_rows()) == want
+
+
+# ---------------------------------------------------------------------------
+# concat_batches on both sides of _pack3d's operand limit: the same bytes
+# ---------------------------------------------------------------------------
+_WIDE = ([DataType.FLOAT32] * 6 + [DataType.INT64] * 3
+         + [DataType.INT32] * 2 + [DataType.BOOL] * 2)      # 13 columns
+
+
+def _wide_piece(rng, rows):
+    cols = []
+    for dt in _WIDE:
+        npdt = dt.to_np()
+        data = (rng.integers(0, 2, rows).astype(bool) if dt is DataType.BOOL
+                else rng.integers(-10**6, 10**6, rows).astype(npdt))
+        cols.append(HostColumnVector(dt, data, rng.random(rows) > 0.2))
+    return HostColumnarBatch(cols, rows)
+
+
+def _wide_pieces(seed, pieces, buckets):
+    """`pieces` host batches of 13 mixed columns with nulls; `buckets`
+    is the capacities they cycle through (one: a single pack group)."""
+    rng = np.random.default_rng(seed)
+    return [_wide_piece(rng, int(rng.integers(b // 2 + 1, b + 1)))
+            for _, b in zip(range(pieces), itertools.cycle(buckets))]
+
+
+def _assert_rows_equal(got, want_cols):
+    """`got`, a host batch, against per column (data, validity) arrays:
+    validity lane for lane, data wherever the lane is valid."""
+    assert got.num_rows == len(want_cols[0][1])
+    for col, (data, valid) in zip(got.columns, want_cols):
+        n = got.num_rows
+        np.testing.assert_array_equal(col.validity[:n], valid)
+        np.testing.assert_array_equal(col.data[:n][valid], data[valid])
+
+
+@pytest.mark.parametrize("buckets", [(16,), (8, 16, 32)],
+                         ids=["one_group", "three_groups"])
+@pytest.mark.parametrize("pieces", [3, 8, 64, 70])
+@pytest.mark.parametrize("branch", ["plain", "live"])
+def test_concat_wide_pieces_equals_numpy(branch, pieces, buckets):
+    hosts = _wide_pieces(pieces * 31 + len(buckets), pieces, buckets)
+    devs = [hb.to_device() for hb in hosts]
+    keeps = [np.ones(hb.num_rows, bool) for hb in hosts]
+    if branch == "live":
+        # a shuffle's lazy slice: shared columns, a mask of the lanes
+        # that are rows of this piece, the count on the device
+        rng = np.random.default_rng(pieces)
+        keeps = [rng.random(hb.num_rows) > 0.4 for hb in hosts]
+        for i, (db, keep) in enumerate(zip(devs, keeps)):
+            mask = np.zeros(db.capacity, bool)
+            mask[:len(keep)] = keep
+            devs[i] = ColumnarBatch(db.columns, jnp.asarray(
+                int(keep.sum()), jnp.int32), live=jnp.asarray(mask))
+    with B.pack_tally() as tally:
+        out = concat_batches(devs)
+    want = [(np.concatenate([hb.columns[ci].data[k]
+                             for hb, k in zip(hosts, keeps)]),
+             np.concatenate([hb.columns[ci].validity[k]
+                             for hb, k in zip(hosts, keeps)]))
+            for ci in range(len(_WIDE))]
+    _assert_rows_equal(out.to_host(), want)
+    per_group = -(-pieces // len(buckets))
+    # the validity call's: under the limit at 3 pieces (and at 8 in
+    # three groups), past it in every other case
+    assert tally.operands == len(_WIDE) * per_group
+
+
+def _pack_keys():
+    from spark_rapids_tpu.engine import jit_cache
+
+    with jit_cache._LOCK:
+        return {k[0] for k in jit_cache._CACHE
+                if isinstance(k[0], tuple) and str(k[0][0]).startswith("pack")}
+
+
+def test_concat_strings_past_the_operand_limit():
+    """_concat_string_cols packs offsets, bytes and validity through
+    _pack3d: 70 pieces of one bucket are 70 operands a call."""
+    hosts = []
+    for i in range(70):
+        words = [None if j == i % 8 else "%02d_%02d" % (i, j)
+                 for j in range(8)]
+        hosts.append(HostColumnarBatch([
+            HostColumnVector.from_pylist(words, DataType.STRING),
+            HostColumnVector.from_pylist(list(range(8)), DataType.INT32)]))
+    before = _pack_keys()
+    with B.pack_tally() as tally:
+        out = concat_batches([hb.to_device() for hb in hosts])
+    assert tally.operands == 70
+    # full runs of offsets, bytes and both validities; the INT32 data's
+    assert {k[1] for k in _pack_keys() - before if k[0] == "pack3d_run"} \
+        == {"int32", "uint8", "bool"}
+    assert out.to_host().to_pylist_rows() == [
+        r for hb in hosts for r in hb.to_pylist_rows()]
+
+
+def test_the_run_path_is_bounded_and_never_eager(monkeypatch):
+    """Past the limit _pack3d issues no eager concatenate (every pack
+    program is a jitted one, and the tally counts them), no program takes
+    more than the limit's operands, and a larger piece count brings a
+    fixed handful of program keys, not one a piece."""
+    from spark_rapids_tpu.engine import jit_cache
+
+    eager = []
+    real = jnp.concatenate
+
+    def concatenate(arrays, *a, **kw):
+        import jax.core
+
+        arrays = list(arrays)
+        if not any(isinstance(x, jax.core.Tracer) for x in arrays):
+            eager.append(len(arrays))
+        assert len(arrays) <= B._PACK_OPERANDS
+        return real(arrays, *a, **kw)
+
+    monkeypatch.setattr(B.jnp, "concatenate", concatenate)
+    jit_cache.clear()
+    new_keys, programs = {}, {}
+    for pieces in (65, 200):
+        before = _pack_keys()
+        devs = [hb.to_device() for hb in _wide_pieces(pieces, pieces, (16,))]
+        with B.pack_tally() as tally:
+            concat_batches(devs)
+        new_keys[pieces] = _pack_keys() - before
+        programs[pieces] = tally.programs
+    assert eager == []
+    # 13 x 65 = 845 validity operands: 13 full runs, a remainder and a
+    # join; the data of 6, 3, 2 and 2 columns likewise; the pack kernel
+    assert programs[65] == (14 + 1) + (7 + 1) + (4 + 1) + 2 * (3 + 1) + 1
+    assert programs[200] == (41 + 1) + (19 + 1) + (10 + 1) + 2 * (7 + 1) + 1
+    # what 200 pieces add to 65's: their remainder runs, a join a call
+    # and the kernel: the piece count is in no run's key
+    assert len(new_keys[200]) <= len(new_keys[65])
+    assert {k[0] for k in new_keys[200]} <= {
+        "pack3d_run", "pack3d_join", "pack_fixed"}
+    # at the limit and under it: the key and the one program of before
+    # (two INT32 columns x 32 pieces are 64 operands, three INT64 are 96)
+    devs = [hb.to_device() for hb in _wide_pieces(1, 32, (16,))]
+    before = _pack_keys()
+    with B.pack_tally() as tally:
+        concat_batches(devs)
+    added = _pack_keys() - before
+    assert ("pack3d", 2, 32, 32, 16, ("int32",) * 64) in added
+    assert ("pack3d", 2, 32, 32, 16, ("bool",) * 64) in added
+    assert ("pack3d_join", 3, 32, 32, 16, "int64") in added
+    assert tally.operands == 13 * 32
+    assert tally.programs == 1 + 1 + (2 + 1) + (3 + 1) + (7 + 1) + 1
+
+
+@pytest.mark.parametrize("operands", [64, 65, 128, 129, 64 * 64 + 3])
+def test_pack3d_on_both_sides_of_the_limit(operands):
+    """One column of `operands` pieces, padded to the next power of two:
+    the matrix numpy stacks, through one program up to the limit, through
+    runs past it, through two levels of runs past the limit squared."""
+    rng = np.random.default_rng(operands)
+    pieces = [rng.integers(0, 100, 8).astype(np.int32)
+              for _ in range(operands)]
+    m_pad = 1 << (operands - 1).bit_length()
+    with B.pack_tally() as tally:
+        got = B._pack3d([[jnp.asarray(p) for p in pieces]], m_pad, 8)
+    want = np.zeros((1, m_pad, 8), np.int32)
+    want[0, :operands] = np.stack(pieces)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    runs = 0
+    left = operands
+    while left > B._PACK_OPERANDS:
+        full, rest = divmod(left, B._PACK_OPERANDS)
+        runs += full + (rest > 1)
+        left = full + (rest > 0)
+    assert tally.programs == runs + 1 and tally.operands == operands

@@ -268,10 +268,11 @@ def q1_bench():
         sys.path.remove(BENCH)
 
 
-def _q1_on(session, q1_bench, seed, root):
+def _q1_on(session, q1_bench, seed, root, **layout):
     action, gen, config = q1_bench
     arrays = gen.gen_tables(0.004, seed, ["lineitem"])
-    paths = gen.write_parquet(arrays, str(root), config["layout"])
+    paths = gen.write_parquet(arrays, str(root),
+                              dict(config["layout"], **layout))
     for key, value in config["conf"].items():
         session.conf.set(key, value)
     df = action.build({"lineitem": session.read.parquet(paths["lineitem"])})
@@ -325,3 +326,27 @@ def test_the_planner_streams_a_dense_group_by_on_one_device(session,
     assert "TpuSpmdStage" not in text and "TpuHashAggregateExec" in text
     session.conf.set("rapids.tpu.sql.spmd.meshDevices", 0)
     assert "TpuSpmdStage" in df.explain()
+
+
+def test_q1s_final_aggregate_input_is_packed_in_bounded_programs(
+        session, q1_bench, tmp_path):
+    """The cell's shape on the CPU: eight splits, so the one coalesced
+    reduce task concatenates 8 map outputs x 8 hash partitions = 64 lazy
+    slices of 13 columns. Its `coalesce-concat` span says what that cost:
+    832 validity operands in one `_pack3d` call, packed in runs (13 + 1,
+    7 + 1, 4 + 1, 2 + 1 programs, the live masks' one, the pack kernel)
+    where 114 eager concatenates were."""
+    action, arrays, df = _q1_on(session, q1_bench, 13, tmp_path,
+                                files_per_table=8)
+    session.conf.set("rapids.tpu.obs.tracing.enabled", True)
+    got = action.run(df, None)
+    assert all(n["value"] <= n["limit"] for n in
+               action.compare(action.reference(arrays), [got])[0])
+    concats = [sp.attrs for sp in
+               session.last_query_trace.find("coalesce-concat")]
+    assert all(set(a) >= {"pieces", "operands", "programs"}
+               for a in concats)
+    final = [a for a in concats if a["pieces"] > 1]
+    assert len(final) == 1, concats
+    assert final[0]["pieces"] == 64 and final[0]["operands"] == 832
+    assert final[0]["programs"] == 32 < 40

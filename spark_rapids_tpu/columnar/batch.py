@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu.columnar.dtypes import DataType, from_np
+from spark_rapids_tpu.obs import trace as OBS
 from spark_rapids_tpu.utils import metrics as M
 
 MIN_CAPACITY = 8
@@ -257,6 +258,12 @@ class HostColumnVector:
     def __len__(self):
         return len(self.data)
 
+    def rows(self, key) -> "HostColumnVector":
+        """The rows a numpy index picks (a slice, a boolean mask), as a
+        column of the same kind."""
+        return HostColumnVector(self.dtype, self.data[key],
+                                self.validity[key])
+
     @staticmethod
     def from_pylist(values: Sequence[Any], dtype: DataType) -> "HostColumnVector":
         n = len(values)
@@ -363,15 +370,7 @@ class HostColumnarBatch:
         return [tuple(vals) for vals in zip(*col_lists)] if col_lists else []
 
     def slice(self, start: int, length: int) -> "HostColumnarBatch":
-        def cut(c):
-            data = c.data[start:start + length]
-            validity = c.validity[start:start + length]
-            if getattr(c, "dictionary", None) is not None:
-                # a dictionary column's codes mean nothing without it
-                return type(c)(c.dtype, data, validity, c.dictionary)
-            return HostColumnVector(c.dtype, data, validity)
-
-        cols = [cut(c) for c in self.columns]
+        cols = [c.rows(slice(start, start + length)) for c in self.columns]
         return HostColumnarBatch(cols, min(length, max(0, self.num_rows - start)))
 
     def estimated_size_bytes(self) -> int:
@@ -631,9 +630,10 @@ class ColumnarBatch:
         """Reconstruct host columns from the grouped download buffers,
         consuming segments at the shared per-dtype cursors `offs`.
         Encoded columns arrive as codes: keep_encoded=True (the serialized
-        shuffle) keeps them as HostDictionaryColumn; otherwise they expand
-        here through the host dictionary — the result-sink form of late
-        materialization (the values never crossed the fence)."""
+        shuffle, the spill store, a file writer's sink) keeps them as
+        HostDictionaryColumn; otherwise they expand here through the host
+        dictionary — the result-sink form of late materialization (the
+        values never crossed the fence)."""
         from spark_rapids_tpu.columnar.encoded import (
             HostDictionaryColumn,
             is_encoded,
@@ -1575,6 +1575,15 @@ def _gather_fixed_cols(cap: int, datas, valids, indices, indices_valid,
                               out_rows)
 
 
+@functools.partial(jax.jit, static_argnums=(0,))
+def _compact_gather_fixed_cols(cap: int, datas, valids, indices, out_rows):
+    """`_gather_fixed_cols` as a compaction runs it (a filter's survivors
+    brought to the front by `_compact_plan`'s order), under a name of its
+    own: a device trace tells the compaction's programs from a sort's
+    permutation or a slice's gather by the `compact` in their names."""
+    return _gather_fixed_body(cap, datas, valids, indices, None, out_rows)
+
+
 def _gather_fixed_body(cap: int, datas, valids, indices, indices_valid,
                        out_rows):
     idx = indices[:cap]
@@ -1645,7 +1654,8 @@ def _sync_free_strings() -> bool:
 def gather_batch(batch: ColumnarBatch, indices, out_rows: int,
                  indices_valid=None,
                  unique_indices: bool = False,
-                 donate: bool = False) -> ColumnarBatch:
+                 donate: bool = False,
+                 compaction: bool = False) -> ColumnarBatch:
     """Gather rows by index into a new batch of `out_rows` logical rows.
     `indices` is a device int32 array of length >= bucket_capacity(out_rows);
     entries >= capacity are treated as 'emit null row' (used by outer joins).
@@ -1661,6 +1671,10 @@ def gather_batch(batch: ColumnarBatch, indices, out_rows: int,
     with_retry(donated=True) — the sources are consumed, so re-dispatch
     is impossible. String columns never donate (their source bytes are
     re-read after the plan phase below).
+
+    compaction=True says the indices are `_compact_plan`'s order (no
+    `indices_valid`, no donation): the fixed-width gather then runs as
+    `_compact_gather_fixed_cols`.
     """
     from spark_rapids_tpu.columnar.encoded import is_encoded
 
@@ -1674,11 +1688,17 @@ def gather_batch(batch: ColumnarBatch, indices, out_rows: int,
     if fixed:
         datas = tuple(cv.data for _, cv in fixed)
         valids = tuple(cv.validity for _, cv in fixed)
-        outs = _gather_fixed_cols_donated(
-            cap, datas, valids, indices, indices_valid,
-            np.int32(out_rows)) if donate else \
-            _gather_fixed_cols(cap, datas, valids, indices,
-                               indices_valid, np.int32(out_rows))
+        if compaction:
+            assert indices_valid is None and not donate
+            outs = _compact_gather_fixed_cols(cap, datas, valids, indices,
+                                              np.int32(out_rows))
+        elif donate:
+            outs = _gather_fixed_cols_donated(
+                cap, datas, valids, indices, indices_valid,
+                np.int32(out_rows))
+        else:
+            outs = _gather_fixed_cols(cap, datas, valids, indices,
+                                      indices_valid, np.int32(out_rows))
         for (i, cv), (data, validity) in zip(fixed, outs):
             if is_encoded(cv):
                 cols[i] = cv.with_codes(data, validity)
@@ -1771,6 +1791,21 @@ def _compact_plan(keep_mask, num_rows):
     return order, jnp.sum(keep)
 
 
+@contextlib.contextmanager
+def compact_span(rows_in, capacity: int, columns: int, lazy: bool):
+    """The `filter.compact` span around one batch's compaction, its plan
+    and its gather (exec/fused.py's stage exit, `compact_batch`), and the
+    process-wide `compactedBatches`. `rows_in` where the host holds the
+    count; the caller sets `rows_out` where it learns it (an eager
+    compaction syncs the count, a lazy one never does). Yields the span,
+    None with tracing off."""
+    M.record_compacted_batch()
+    attrs = {"rows_in": rows_in} if isinstance(rows_in, int) else {}
+    with OBS.span("filter.compact", capacity=capacity, columns=columns,
+                  lazy=lazy, **attrs) as sp:
+        yield sp
+
+
 def compact_batch(batch: ColumnarBatch, keep_mask,
                   lazy: bool = False) -> ColumnarBatch:
     """Compact rows where keep_mask is True to the front (the filter kernel;
@@ -1785,11 +1820,16 @@ def compact_batch(batch: ColumnarBatch, keep_mask,
     utils/devprobe measures, this folds the filter's fence into whatever downstream sync
     happens anyway; the cost is padded-lane compute at the unshrunk
     capacity."""
-    M.record_dispatch()
-    order, n = _compact_plan(keep_mask, jnp.int32(batch.num_rows))
-    if lazy:
-        return _gather_batch_traced(batch, order, n)
-    return gather_batch(batch, order, int(jax.device_get(n)))
+    with compact_span(batch.num_rows, int(keep_mask.shape[0]),
+                      batch.num_columns, lazy) as sp:
+        M.record_dispatch()
+        order, n = _compact_plan(keep_mask, jnp.int32(batch.num_rows))
+        if lazy:
+            return _gather_batch_traced(batch, order, n)
+        n = int(jax.device_get(n))
+        if sp is not None:
+            sp.attrs["rows_out"] = n
+        return gather_batch(batch, order, n, compaction=True)
 
 
 def _gather_batch_traced(batch: ColumnarBatch, indices,
@@ -1797,7 +1837,8 @@ def _gather_batch_traced(batch: ColumnarBatch, indices,
     """gather_batch with a TRACED output row count: output capacity = the
     input's (static), string byte capacity = the input byte buffer's
     (output bytes of a row-subset gather can never exceed it). No host
-    sync anywhere."""
+    sync anywhere. The lazy compaction's gather (its only callers), so
+    the fixed-width program is `_compact_gather_fixed_cols`."""
     from spark_rapids_tpu.columnar.encoded import is_encoded
 
     cap = batch.capacity
@@ -1809,7 +1850,7 @@ def _gather_batch_traced(batch: ColumnarBatch, indices,
     if fixed:
         datas = tuple(cv.data for _, cv in fixed)
         valids = tuple(cv.validity for _, cv in fixed)
-        outs = _gather_fixed_cols(cap, datas, valids, indices, None, n32)
+        outs = _compact_gather_fixed_cols(cap, datas, valids, indices, n32)
         for (i, cv), (data, validity) in zip(fixed, outs):
             cols[i] = cv.with_codes(data, validity) if is_encoded(cv) \
                 else ColumnVector(cv.dtype, data, validity,

@@ -666,8 +666,9 @@ class HostDictionaryColumn(HostColumnVector):
     """Host mirror of DictionaryColumn: `data` holds int32 codes, the
     shared dictionary holds the values. Exists transiently on the
     serialized-shuffle / spill path (to_host_many(keep_encoded=True) ->
-    serde -> to_device); any value access decodes through the host
-    dictionary."""
+    serde -> to_device) and between a write's sink and Arrow
+    (io/arrow_convert.py hands it over as a dictionary array); any
+    value access decodes through the host dictionary."""
 
     __slots__ = ("dictionary",)
 
@@ -676,6 +677,11 @@ class HostDictionaryColumn(HostColumnVector):
         super().__init__(dtype, np.asarray(codes, dtype=np.int32),
                          np.asarray(validity, dtype=bool))
         self.dictionary = dictionary
+
+    def rows(self, key) -> "HostDictionaryColumn":
+        # a dictionary column's codes mean nothing without it
+        return HostDictionaryColumn(self.dtype, self.data[key],
+                                    self.validity[key], self.dictionary)
 
     def decoded(self) -> HostColumnVector:
         values = materialize_host_values(self.data, self.validity,

@@ -401,6 +401,7 @@ class TpuFusedStageExec(TpuExec):
                 _compact_plan,
                 _gather_batch_traced,
                 bucket_capacity,
+                compact_span,
                 gather_batch,
             )
             from spark_rapids_tpu.engine.retry import (
@@ -475,6 +476,27 @@ class TpuFusedStageExec(TpuExec):
 
                 return with_retry(_attempt, site="fused")
 
+            def compact(out: ColumnarBatch, live, n, plan=None):
+                """The stage exit's compaction of one output batch: the
+                survivors' order (computed here unless `plan` hands over
+                a sibling variant's) and the gather, in one
+                `filter.compact` span. -> (dense batch, plan)."""
+                with compact_span(out.num_rows, int(live.shape[0]),
+                                  out.num_columns, lazy) as sp:
+                    if plan is None:
+                        order, nk = compact_plan(live, n)
+                        # tpulint: host-sync -- policy-gated stage-exit
+                        plan = (order, nk if lazy
+                                else int(jax.device_get(nk)))
+                    order, n_keep = plan
+                    if lazy:
+                        return _gather_batch_traced(out, order,
+                                                    n_keep), plan
+                    if sp is not None:
+                        sp.attrs["rows_out"] = n_keep
+                    return gather_batch(out, order, n_keep,
+                                        compaction=True), plan
+
             def run_simple(b: ColumnarBatch, off: int) -> ColumnarBatch:
                 """One-variant no-limit batch: the split-and-retry /
                 CPU-fallback unit."""
@@ -503,12 +525,7 @@ class TpuFusedStageExec(TpuExec):
                     # input, which only an owned input may hand on)
                     out = wrap_out(outs, b2.num_rows, b2.owned, out_enc)
                     if self._row_changing:
-                        order, nk = compact_plan(live, n)
-                        # tpulint: host-sync -- policy-gated stage-exit
-                        n_keep = nk if lazy else int(jax.device_get(nk))
-                        out2 = _gather_batch_traced(out, order, n_keep) \
-                            if lazy else gather_batch(out, order, n_keep)
-                        return out2
+                        return compact(out, live, n)[0]
                     return out
 
                 if not donated:
@@ -563,7 +580,7 @@ class TpuFusedStageExec(TpuExec):
                 # would corrupt the cross-batch LIMIT budget
                 batch, cols, ops2, enc_sig, out_enc = prep(batch)
                 n = jnp.asarray(batch.num_rows, dtype=jnp.int32)
-                order = n_keep = None
+                plan = None
                 for variant in range(self._n_variants):
                     if remaining is not None and remaining <= 0:
                         break
@@ -573,13 +590,9 @@ class TpuFusedStageExec(TpuExec):
                             ops=ops2, enc_sig=enc_sig)
                     out = wrap_out(outs, batch.num_rows, False, out_enc)
                     if self._row_changing:
-                        if order is None or not self._live_shared:
-                            order, nk = compact_plan(live, n)
-                            # tpulint: host-sync -- policy-gated stage-exit
-                            n_keep = nk if lazy else \
-                                int(jax.device_get(nk))
-                        out = _gather_batch_traced(out, order, n_keep) \
-                            if lazy else gather_batch(out, order, n_keep)
+                        out, plan = compact(
+                            out, live, n,
+                            plan if self._live_shared else None)
                     if remaining is not None and \
                             not self._limit_below_expand:
                         # tpulint: host-sync -- cross-batch LIMIT budget

@@ -105,14 +105,17 @@ class RequireSingleBatch(CoalesceGoal):
         return isinstance(other, RequireSingleBatch)
 
 
-def sink_download_many(run):
+def sink_download_many(run, keep_encoded: bool = False):
     """Grouped sink download with async error attribution: the ONE place
     a query is allowed to block on device values. A device-rooted error
     surfacing here under issue-ahead execution belongs to some upstream
     dispatch, not to the transfer — it re-raises as TpuAsyncSinkError so
     the session's checked replay re-attributes it to the originating op
     (docs/async-execution.md). Shared by the query-level lifted sink and
-    the per-partition DeviceToHostExec path."""
+    the per-partition DeviceToHostExec path. `keep_encoded`: a dictionary
+    column comes back as codes + dictionary (`HostDictionaryColumn`), for
+    a consumer that takes it so (a file writer); otherwise it is expanded
+    to values here, as a result's rows need."""
     from spark_rapids_tpu.columnar.batch import to_host_many
     from spark_rapids_tpu.engine.async_exec import async_enabled
     from spark_rapids_tpu.engine.retry import (
@@ -127,10 +130,12 @@ def sink_download_many(run):
         # behind; a partial bucket is trimmed before the transfer)
         OBS.annotate(batches=len(run),
                      bytes=sum(c.device_memory_size()
-                               for b in run for c in b.columns))
+                               for b in run for c in b.columns),
+                     keep_encoded=keep_encoded)
     try:
-        return with_retry(lambda: to_host_many(run),
-                          site="transfer.download")
+        return with_retry(
+            lambda: to_host_many(run, keep_encoded=keep_encoded),
+            site="transfer.download")
     except Exception as e:  # noqa: BLE001 — attribution boundary
         typed = as_typed_error(e)
         if typed is None or isinstance(typed, TpuAsyncSinkError) or \
@@ -198,19 +203,24 @@ class DeviceToHostExec(PhysicalExec):
 
     placement = "cpu"  # output is host data
 
-    def __init__(self, child: PhysicalExec):
+    def __init__(self, child: PhysicalExec, keep_encoded: bool = False):
         super().__init__(child)
+        # the consumer takes a dictionary column as codes + dictionary
+        # (io/writer.py sets it for the sink under a WriteFile); any
+        # other consumer reads values
+        self.keep_encoded = keep_encoded
 
     @property
     def output(self):
         return self.children[0].output
 
     def with_children(self, new_children):
-        return DeviceToHostExec(new_children[0])
+        return DeviceToHostExec(new_children[0], self.keep_encoded)
 
     def execute(self, ctx: ExecContext) -> PartitionedBatches:
         child_pb = self.children[0].execute(ctx)
         total_time = self.metrics[M.TOTAL_TIME]
+        keep_encoded = self.keep_encoded
 
         def factory(pidx: int) -> Iterator[HostColumnarBatch]:
             sem = TpuSemaphore.get()
@@ -229,13 +239,13 @@ class DeviceToHostExec(PhysicalExec):
                     run_bytes += db.device_memory_size()
                     if len(run) >= run_cap or run_bytes > (128 << 20):
                         with M.trace_range("DeviceToHost", total_time):
-                            hbs = sink_download_many(run)
+                            hbs = sink_download_many(run, keep_encoded)
                         yield from hbs
                         run, run_bytes = [], 0
                         run_cap = min(run_cap * 2, 32)
                 if run:
                     with M.trace_range("DeviceToHost", total_time):
-                        hbs = sink_download_many(run)
+                        hbs = sink_download_many(run, keep_encoded)
                     yield from hbs
             finally:
                 sem.release_if_necessary(current_task_id())

@@ -241,17 +241,43 @@ def _unscaled_to_decimal128(col, dt: DecimalType) -> pa.Array:
         [vbuf, pa.py_buffer(limbs.tobytes())], null_count=null_count)
 
 
+def _dictionary_array(col) -> pa.DictionaryArray:
+    """A STRING `HostDictionaryColumn` as Arrow's dictionary array: the
+    codes as they are, nulls from the validity, the dictionary's byte
+    table handed over by its buffers. No value is decoded and nothing
+    runs a row at a time."""
+    d = col.dictionary
+    values = pa.Array.from_buffers(
+        pa.string(), d.size,
+        [None,
+         pa.py_buffer(np.ascontiguousarray(d.host_offsets, dtype=np.int32)),
+         pa.py_buffer(np.ascontiguousarray(d.host_bytes, dtype=np.uint8))])
+    mask = None if col.validity.all() else ~col.validity
+    return pa.DictionaryArray.from_arrays(
+        pa.array(col.data, type=pa.int32(), mask=mask), values)
+
+
 def host_batch_to_arrow(batch: HostColumnarBatch,
                         attrs: List[AttributeReference]) -> pa.Table:
+    """A dictionary-coded STRING column (`HostDictionaryColumn`, what a
+    sink with `keep_encoded` downloads) becomes a `pa.DictionaryArray`;
+    the caller decides what its format makes of one (io/writer.py)."""
+    from spark_rapids_tpu.columnar.encoded import HostDictionaryColumn
+
     arrays = []
     names = []
     for attr, col in zip(attrs, batch.columns):
         dt = attr.data_type
+        names.append(attr.name)
+        if isinstance(col, HostDictionaryColumn):
+            if dt is DataType.STRING and not col.dictionary.is_fixed:
+                arrays.append(_dictionary_array(col))
+                continue
+            col = col.decoded()     # a fixed-width dictionary: one take
         mask = ~col.validity  # arrow mask semantics: True = null
         if dt is DataType.STRING:
-            vals = [v if ok else None
-                    for v, ok in zip(col.data, col.validity)]
-            arrays.append(pa.array(vals, type=pa.string()))
+            # one Arrow call over the object array and the mask
+            arrays.append(pa.array(col.data, type=pa.string(), mask=mask))
         elif dt is DataType.TIMESTAMP:
             arrays.append(pa.array(col.data.astype(np.int64), mask=mask)
                           .cast(pa.timestamp("us", tz="UTC")))
@@ -263,7 +289,6 @@ def host_batch_to_arrow(batch: HostColumnarBatch,
         else:
             arrays.append(pa.array(col.data, mask=mask,
                                    type=dt_to_arrow_type(dt)))
-        names.append(attr.name)
     # positional construction: duplicate column names must round-trip to the
     # writer (which then raises), not silently drop columns
     return pa.table(arrays, names=names)

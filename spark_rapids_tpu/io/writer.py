@@ -23,6 +23,7 @@ import uuid
 from typing import Any, Dict, List
 
 import numpy as np
+import pyarrow as pa
 
 from spark_rapids_tpu.columnar.batch import HostColumnarBatch
 from spark_rapids_tpu.io.arrow_convert import host_batch_to_arrow
@@ -95,8 +96,17 @@ def execute_write(session, plan: L.WriteFile):
         and isinstance(physical, DeviceToHostExec)
         and OE.schema_encodable(attrs))
     # the device encoders take DEVICE batches: they run the sink's child
-    source = physical.children[0] \
-        if device_encode or device_encode_orc else physical
+    if device_encode or device_encode_orc:
+        source = physical.children[0]
+    elif isinstance(physical, DeviceToHostExec):
+        # the consumer is a file writer: a dictionary-coded column comes
+        # through the fence as codes + dictionary and reaches Arrow so
+        # (`host_batch_to_arrow`). A node of its own, since the planned
+        # one may be the plan cache's, which a collect() shares
+        source = physical = DeviceToHostExec(physical.children[0],
+                                             keep_encoded=True)
+    else:
+        source = physical
 
     ctx = session._exec_context()
     pb = source.execute(ctx)
@@ -147,10 +157,25 @@ def _ext(fmt: str) -> str:
 
 
 def _concat_arrow(batches: List[HostColumnarBatch], attrs):
-    import pyarrow as pa
-
+    """The batches of one file as one Arrow table. Batches whose
+    dictionaries differ (a split that lacks a value) are brought to one,
+    in Arrow: a column chunk is then written under one dictionary page. A
+    column that is codes in some batches and values in others is brought
+    to values."""
     tables = [host_batch_to_arrow(b, attrs) for b in batches]
-    return tables[0] if len(tables) == 1 else pa.concat_tables(tables)
+    if len(tables) == 1:
+        return tables[0]
+    if any(not t.schema.equals(tables[0].schema) for t in tables[1:]):
+        # the scan hands a STRING column over as codes a split: one whose
+        # file fell back to PLAIN comes as values, and the column is then
+        # values in every batch of this file
+        plain = {i for t in tables for i, f in enumerate(t.schema)
+                 if not pa.types.is_dictionary(f.type)}
+        tables = [t.cast(_values_schema(t.schema, plain)) for t in tables]
+    table = pa.concat_tables(tables)
+    if any(pa.types.is_dictionary(f.type) for f in table.schema):
+        table = table.unify_dictionaries()
+    return table
 
 
 def _write_arrow_file(batches: List[HostColumnarBatch], attrs,
@@ -158,8 +183,20 @@ def _write_arrow_file(batches: List[HostColumnarBatch], attrs,
     """One file through Arrow's host writer; returns its rows. The two
     spans split the host's work: building the Arrow table, then Arrow's
     encode + compress + file I/O (one call, so one span)."""
-    with obs_span("write.arrow"):
+    with obs_span("write.arrow") as sp:
         table = _concat_arrow(batches, attrs)
+        if sp is not None:
+            # the STRING columns of this file handed to Arrow as codes +
+            # dictionary, and what they hold (the codes and, a batch, its
+            # dictionary's bytes): as `scan.host_decode` counts its own
+            coded = [i for i, f in enumerate(table.schema)
+                     if pa.types.is_dictionary(f.type)]
+            sp.attrs.update(
+                dict_columns=len(coded),
+                dict_bytes=sum(
+                    b.columns[i].data.nbytes
+                    + int(b.columns[i].dictionary.host_offsets[-1])
+                    for b in batches for i in coded))
     with obs_span("write.file", encoder="arrow", path=file_path) as sp:
         _write_table(table, file_path, plan)
         if sp is not None:
@@ -168,13 +205,49 @@ def _write_arrow_file(batches: List[HostColumnarBatch], attrs,
     return table.num_rows
 
 
+def _values_schema(schema, only=None):
+    """`schema` with every dictionary field (of the positions `only`,
+    where given) as its value type: what a reader of the file is to see."""
+    return pa.schema([f.with_type(f.type.value_type)
+                      if pa.types.is_dictionary(f.type)
+                      and (only is None or i in only) else f
+                      for i, f in enumerate(schema)],
+                     metadata=schema.metadata)
+
+
+def _write_parquet_coded(table, file_path: str, compression) -> None:
+    """`pq.write_table` for a table that holds dictionary arrays: Arrow
+    writes each as a dictionary-encoded chunk from its codes and its
+    dictionary as they are. The parquet schema is that of the values
+    (BYTE_ARRAY / String), and so is the Arrow schema the footer carries
+    (`ARROW:schema`, which the writer would otherwise store as the
+    dictionary type and hand every pyarrow reader dictionary arrays):
+    a reader sees the file a table of plain strings gives."""
+    import base64
+
+    import pyarrow.parquet as pq
+
+    stored = base64.b64encode(
+        _values_schema(table.schema).serialize().to_pybytes())
+    with pq.ParquetWriter(file_path, table.schema, compression=compression,
+                          store_schema=False) as writer:
+        writer.write_table(table)
+        writer.add_key_value_metadata({"ARROW:schema": stored})
+
+
 def _write_table(table, file_path: str, plan: L.WriteFile) -> None:
+    coded = any(pa.types.is_dictionary(f.type) for f in table.schema)
     if plan.fmt == "parquet":
         import pyarrow.parquet as pq
 
-        compression = plan.options.get("compression", "snappy")
-        pq.write_table(table, file_path, compression=compression)
-    elif plan.fmt == "orc":
+        write = _write_parquet_coded if coded else pq.write_table
+        write(table, file_path,
+              compression=plan.options.get("compression", "snappy"))
+        return
+    if coded:
+        # ORC and CSV take values: decoded in Arrow, a column at a time
+        table = table.cast(_values_schema(table.schema))
+    if plan.fmt == "orc":
         import pyarrow.orc as po
 
         po.write_table(table, file_path)
@@ -266,6 +339,9 @@ def _partition_key_groups(key_cols, n: int):
     """Canonical partition-key grouping shared by the device- and
     host-encoded dynamic writers: (per-column value arrays with None for
     NULL, per-row group index, each group's first row index)."""
+    # a dictionary-coded key names directories by its values
+    key_cols = [c.decoded() if getattr(c, "dictionary", None) is not None
+                else c for c in key_cols]
     key_vals = [np.where(c.validity, c.data.astype(object), None)
                 for c in key_cols]
     decorated = np.array(
@@ -285,8 +361,6 @@ def _write_partitioned(batches: List[HostColumnarBatch], attrs, plan,
                        path: str, pidx: int, write_id: str) -> int:
     """Hive-style key=value directory layout (reference: the dynamic
     partition data writer, GpuFileFormatDataWriter.scala)."""
-    from spark_rapids_tpu.columnar.batch import HostColumnVector
-
     part_names = plan.partition_by
     part_idx = [i for i, a in enumerate(attrs) if a.name in part_names]
     data_idx = [i for i, a in enumerate(attrs) if a.name not in part_names]
@@ -302,12 +376,7 @@ def _write_partitioned(batches: List[HostColumnarBatch], attrs, plan,
         for g in range(len(first_idx)):
             mask = inverse == g
             key = tuple(kv[first_idx[g]] for kv in key_vals)
-            cols = [
-                HostColumnVector(attrs[i].data_type,
-                                 b.columns[i].data[mask],
-                                 b.columns[i].validity[mask])
-                for i in data_idx
-            ]
+            cols = [b.columns[i].rows(mask) for i in data_idx]
             groups.setdefault(key, []).append(
                 HostColumnarBatch(cols, int(mask.sum())))
     for key, group_batches in groups.items():

@@ -864,7 +864,8 @@ class TpuSession:
                          M.HOST_PLACED_OPS, M.PLACEMENT_REPLACEMENTS,
                          M.SPECULATIVE_TASKS, M.SPECULATIVE_WINS,
                          M.WATCHDOG_KILLS, M.DEVICE_RESETS,
-                         M.DENSE_AGG_BATCHES, M.SORT_AGG_BATCHES):
+                         M.DENSE_AGG_BATCHES, M.SORT_AGG_BATCHES,
+                         M.COMPACTED_BATCHES):
                 self.last_query_metrics[name] = snap.get(name, 0)
             self.last_adaptive_report = list(qctx.aqe_notes)
             finished_trace = None

@@ -414,6 +414,22 @@ def sort_agg_batch_count() -> int:
     return _SORT_AGG_BATCHES.value
 
 
+# batches whose filter survivors were made dense on the device (the plan
+# and the gather of columnar/batch.py `compact_span`): a filter folded
+# into an aggregate's update program moves no row and counts nothing
+COMPACTED_BATCHES = "compactedBatches"
+_COMPACTED_BATCHES = Metric(COMPACTED_BATCHES)
+
+
+def record_compacted_batch() -> None:
+    _COMPACTED_BATCHES.add(1)
+    _note(COMPACTED_BATCHES, 1)
+
+
+def compacted_batch_count() -> int:
+    return _COMPACTED_BATCHES.value
+
+
 # ---------------------------------------------------------------------------
 # Fault-tolerance accounting (engine/retry.py increments; queries snapshot
 # before/after, same pattern as the dispatch counter above)

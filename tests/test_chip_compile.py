@@ -230,6 +230,34 @@ def test_q1_aggregate_update_kernel_is_dense_in_f32(
     assert ma.temp_size_in_bytes < 1 << 30
 
 
+def test_compaction_programs_at_an_sf1_split(one_chip, no_persistent_cache):
+    """A filter's survivors in front of a sink (PR 40, `lineitem_write7`):
+    the plan, an argsort of the keep mask, and the gather of seven
+    columns and their validities through it, at the 2^20 lanes of a split
+    of two row groups; DOUBLE in f32, codes and the date in int32. The
+    plan is the program that costs a cold run its half minute; neither
+    keeps temporaries worth naming."""
+    from spark_rapids_tpu.columnar import batch as B
+
+    cap = 2 * ROW_GROUP_CAP
+
+    def lanes(dtype):
+        return jax.ShapeDtypeStruct((cap,), dtype, sharding=one_chip)
+
+    count = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    lowered, text = _lower(B._compact_plan, (lanes(jnp.bool_), count), {})
+    assert "stablehlo.sort" in text
+    assert lowered.compile().memory_analysis().temp_size_in_bytes < 1 << 26
+    datas = tuple(lanes(d) for d in (jnp.float32,) * 4 + (jnp.int32,) * 3)
+    valids = tuple(lanes(jnp.bool_) for _ in datas)
+    lowered, text = _lower(B._compact_gather_fixed_cols,
+                           (cap, datas, valids, lanes(jnp.int32), count), {})
+    assert "stablehlo.sort" not in text and "stablehlo.scatter" not in text
+    compiled = lowered.compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 26
+    assert "jit__compact_gather_fixed_cols" in compiled.as_text()
+
+
 @pytest.mark.parametrize("builder, filename, sorts", [
     ("_build_dense_merge_kernel", "exec/aggregate.py", False),
     ("TpuSortExec._build_kernel", "exec/sort.py", True),

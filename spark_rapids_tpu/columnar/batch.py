@@ -1575,15 +1575,6 @@ def _gather_fixed_cols(cap: int, datas, valids, indices, indices_valid,
                               out_rows)
 
 
-@functools.partial(jax.jit, static_argnums=(0,))
-def _compact_gather_fixed_cols(cap: int, datas, valids, indices, out_rows):
-    """`_gather_fixed_cols` as a compaction runs it (a filter's survivors
-    brought to the front by `_compact_plan`'s order), under a name of its
-    own: a device trace tells the compaction's programs from a sort's
-    permutation or a slice's gather by the `compact` in their names."""
-    return _gather_fixed_body(cap, datas, valids, indices, None, out_rows)
-
-
 def _gather_fixed_body(cap: int, datas, valids, indices, indices_valid,
                        out_rows):
     idx = indices[:cap]
@@ -1651,11 +1642,34 @@ def _sync_free_strings() -> bool:
     return fence_cost_ms() >= _SYNC_FREE_FENCE_MS
 
 
+def _fixed_and_string_ordinals(batch: ColumnarBatch):
+    """-> ([(ordinal, column)] of the columns that move as fixed-width
+    lanes, [ordinal] of the plain STRING ones). An encoded (dictionary)
+    column moves its int32 CODES like any fixed-width column: the
+    dictionary rides along untouched."""
+    from spark_rapids_tpu.columnar.encoded import is_encoded
+
+    fixed = [(i, cv) for i, cv in enumerate(batch.columns)
+             if is_encoded(cv) or cv.dtype is not DataType.STRING]
+    sidx = [i for i, cv in enumerate(batch.columns)
+            if cv.dtype is DataType.STRING and not is_encoded(cv)]
+    return fixed, sidx
+
+
+def _fill_fixed_cols(cols, fixed, outs) -> None:
+    from spark_rapids_tpu.columnar.encoded import is_encoded
+
+    for (i, cv), (data, validity) in zip(fixed, outs):
+        # moved values are a subset of the source (null lanes hold 0),
+        # so the source range bound still holds
+        cols[i] = cv.with_codes(data, validity) if is_encoded(cv) \
+            else ColumnVector(cv.dtype, data, validity, vrange=cv.vrange)
+
+
 def gather_batch(batch: ColumnarBatch, indices, out_rows: int,
                  indices_valid=None,
                  unique_indices: bool = False,
-                 donate: bool = False,
-                 compaction: bool = False) -> ColumnarBatch:
+                 donate: bool = False) -> ColumnarBatch:
     """Gather rows by index into a new batch of `out_rows` logical rows.
     `indices` is a device int32 array of length >= bucket_capacity(out_rows);
     entries >= capacity are treated as 'emit null row' (used by outer joins).
@@ -1671,71 +1685,72 @@ def gather_batch(batch: ColumnarBatch, indices, out_rows: int,
     with_retry(donated=True) — the sources are consumed, so re-dispatch
     is impossible. String columns never donate (their source bytes are
     re-read after the plan phase below).
-
-    compaction=True says the indices are `_compact_plan`'s order (no
-    `indices_valid`, no donation): the fixed-width gather then runs as
-    `_compact_gather_fixed_cols`.
     """
-    from spark_rapids_tpu.columnar.encoded import is_encoded
-
     cap = bucket_capacity(max(out_rows, 1))
     M.record_dispatch()
-    # encoded (dictionary) columns gather their int32 CODES like any
-    # fixed-width column — the dictionary rides along untouched
-    fixed = [(i, cv) for i, cv in enumerate(batch.columns)
-             if is_encoded(cv) or cv.dtype is not DataType.STRING]
+    fixed, sidx = _fixed_and_string_ordinals(batch)
     cols: List[Optional[ColumnVector]] = [None] * batch.num_columns
     if fixed:
         datas = tuple(cv.data for _, cv in fixed)
         valids = tuple(cv.validity for _, cv in fixed)
-        if compaction:
-            assert indices_valid is None and not donate
-            outs = _compact_gather_fixed_cols(cap, datas, valids, indices,
-                                              np.int32(out_rows))
-        elif donate:
+        if donate:
             outs = _gather_fixed_cols_donated(
                 cap, datas, valids, indices, indices_valid,
                 np.int32(out_rows))
         else:
             outs = _gather_fixed_cols(cap, datas, valids, indices,
                                       indices_valid, np.int32(out_rows))
-        for (i, cv), (data, validity) in zip(fixed, outs):
-            if is_encoded(cv):
-                cols[i] = cv.with_codes(data, validity)
-                continue
-            # gathered values are a subset of the source (null lanes hold 0),
-            # so the source range bound still holds
-            cols[i] = ColumnVector(cv.dtype, data, validity,
-                                   vrange=cv.vrange)
-    sidx = [i for i, cv in enumerate(batch.columns)
-            if cv.dtype is DataType.STRING and
-            not is_encoded(batch.columns[i])]
+        _fill_fixed_cols(cols, fixed, outs)
     if sidx:
-        # plan every string column first so any byte totals still needed
-        # come back in a single host transfer (one sync per gather at most)
-        plans = [_gather_string_plan_cap(batch.columns[i].offsets,
-                                         batch.columns[i].validity,
-                                         indices, indices_valid, cap,
-                                         np.int32(out_rows))
-                 for i in sidx]
-        byte_caps: List[Optional[int]] = [None] * len(sidx)
-        if _sync_free_strings():
-            for j, i in enumerate(sidx):
-                byte_caps[j] = _string_byte_bound(batch.columns[i], cap,
-                                                  unique_indices)
-        need = [j for j, bc in enumerate(byte_caps) if bc is None]
-        if need:
-            totals = jax.device_get([plans[j][2][-1] for j in need])
-            for j, total in zip(need, totals):
-                byte_caps[j] = bucket_capacity(max(int(total), 1))
-        for j, i in enumerate(sidx):
-            starts, lengths, new_offsets, validity = plans[j]
-            out = _gather_string_bytes(batch.columns[i].data, starts,
-                                       new_offsets, lengths, byte_caps[j])
-            cols[i] = ColumnVector(DataType.STRING, out, validity,
-                                   new_offsets,
-                                   max_len=batch.columns[i].max_len)
+        _gather_string_cols(cols, batch, sidx, indices, indices_valid, cap,
+                            out_rows, unique_indices)
     return ColumnarBatch(cols, out_rows, owned=True)
+
+
+def _gather_string_cols(cols, batch: ColumnarBatch, sidx, indices,
+                        indices_valid, cap: int, out_rows: int,
+                        unique_indices: bool) -> None:
+    """The plain STRING columns `sidx` of `batch` gathered through
+    `indices` into `cols`, the host holding `out_rows`."""
+    # plan every string column first so any byte totals still needed
+    # come back in a single host transfer (one sync per gather at most)
+    plans = [_gather_string_plan_cap(batch.columns[i].offsets,
+                                     batch.columns[i].validity,
+                                     indices, indices_valid, cap,
+                                     np.int32(out_rows))
+             for i in sidx]
+    byte_caps: List[Optional[int]] = [None] * len(sidx)
+    if _sync_free_strings():
+        for j, i in enumerate(sidx):
+            byte_caps[j] = _string_byte_bound(batch.columns[i], cap,
+                                              unique_indices)
+    need = [j for j, bc in enumerate(byte_caps) if bc is None]
+    if need:
+        totals = jax.device_get([plans[j][2][-1] for j in need])
+        for j, total in zip(need, totals):
+            byte_caps[j] = bucket_capacity(max(int(total), 1))
+    for j, i in enumerate(sidx):
+        starts, lengths, new_offsets, validity = plans[j]
+        out = _gather_string_bytes(batch.columns[i].data, starts,
+                                   new_offsets, lengths, byte_caps[j])
+        cols[i] = ColumnVector(DataType.STRING, out, validity,
+                               new_offsets,
+                               max_len=batch.columns[i].max_len)
+
+
+def _gather_string_cols_traced(cols, batch: ColumnarBatch, sidx, indices,
+                               out_rows) -> None:
+    """`_gather_string_cols` with a TRACED row count: the byte capacity
+    is the input byte buffer's (the bytes of a subset of the rows can
+    never exceed it), so no host sync anywhere."""
+    for i in sidx:
+        cv = batch.columns[i]
+        starts, lengths, new_offsets, validity = _gather_string_plan_traced(
+            cv.offsets, cv.validity, indices, out_rows)
+        out = _gather_string_bytes(cv.data, starts, new_offsets, lengths,
+                                   int(cv.data.shape[0]))
+        cols[i] = ColumnVector(DataType.STRING, out, validity, new_offsets,
+                               max_len=cv.max_len)
 
 
 def _string_plan_body(offsets, validity, idx, in_bounds, sel_mask):
@@ -1783,22 +1798,109 @@ def _gather_string_bytes(src, starts, new_offsets, lengths, byte_cap: int):
     return jnp.where(valid, src[src_pos], 0).astype(jnp.uint8)
 
 
+# -- the stream compaction ----------------------------------------------------
+# A filter's survivors keep their order, so row i moves left by shift[i] =
+# the dropped rows in front of it, a number that never falls from one kept
+# row to the next. Such a move needs no gather (XLA's on a TPU v5e fetches
+# an element at a time: 21-27 ns a lane, ledger PR 40): bit b of the shift
+# says whether the row moves 2^b lanes in step b, lowest bit first, and a
+# step is one dense select between an array and itself shifted by a static
+# 2^b lanes. No two kept rows meet: after bits 0..b-1 row i stands at
+# i - (shift[i] mod 2^b), and for kept i < j, j - i >= 1 + shift[j] -
+# shift[i].
+
+# The network passes over the INPUT's lanes log2(capacity) times whatever
+# survives. Seven columns at 2^20 lanes, the reader's largest batch, cost
+# a v5e 0.72-0.79 ms of device time at any share kept (PR 41, `PERF.md`
+# section 6); fetching the survivors through their order instead wins
+# only from 2^22 lanes on and 1/64 kept or less, which no plan produces
+# yet, so there is no second path.
+
+
+def _shift_left(x, k: int):
+    """x[i + k] at lane i, zeros (False) in the last k lanes."""
+    return jnp.pad(x[k:], [(0, k)])
+
+
+def _dropped_through(drop):
+    """Inclusive count of True lanes, int32. Two levels (rows of 1024
+    lanes, then the rows' totals) where the capacity allows: a flat
+    `cumsum` over 2^20 lanes costs a v5e's compiler 32 s, this one 0.4."""
+    cap = drop.shape[0]
+    if cap <= 1024 or cap % 1024:
+        return jnp.cumsum(drop, dtype=jnp.int32)
+    in_row = jnp.cumsum(drop.reshape(cap // 1024, 1024), axis=1,
+                        dtype=jnp.int32)
+    total = in_row[:, -1]
+    return (in_row + (jnp.cumsum(total) - total)[:, None]).reshape(cap)
+
+
+def compact_steps(capacity: int) -> int:
+    """Steps of the shift network over `capacity` lanes."""
+    return (capacity - 1).bit_length()
+
+
 @jax.jit
 def _compact_plan(keep_mask, num_rows):
+    """The survivors' count: what the eager compaction syncs before it
+    moves a column, and sizes its output by."""
+    keep = keep_mask & row_mask(num_rows, keep_mask.shape[0])
+    return jnp.sum(keep, dtype=jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 5))
+def _compact_shift_fixed_cols(out_cap: int, datas, valids, keep_mask,
+                              num_rows, with_order: bool):
+    """The kept rows of every fixed-width column brought to the front in
+    their order by the log-step shift network: no gather, no scatter, no
+    sort. -> ([(data, validity)] at `out_cap` lanes, lanes past the count
+    zero and invalid: byte for byte what `_gather_fixed_body` gives
+    through `argsort(~keep, stable=True)`; the survivors' order (their
+    source lanes, int32; whatever past the count) or None; the count).
+
+    The validities travel as bits of uint32 words, 32 to a word, and a
+    dropped or vacated lane's shift is 0, so that it never moves and no
+    `live` mask needs carrying: what stands past the count is garbage
+    until the last select clears it."""
     cap = keep_mask.shape[0]
-    keep = keep_mask & (jnp.arange(cap) < num_rows)
-    order = jnp.argsort(~keep, stable=True).astype(jnp.int32)
-    return order, jnp.sum(keep)
+    keep = keep_mask & row_mask(num_rows, cap)
+    n = jnp.sum(keep, dtype=jnp.int32)
+    shift = jnp.where(keep, _dropped_through(~keep), 0)
+    words = []
+    for w in range(0, len(valids), 32):
+        word = jnp.zeros((cap,), jnp.uint32)
+        for j, v in enumerate(valids[w:w + 32]):
+            word = word | (v.astype(jnp.uint32) << j)
+        words.append(word)
+    carried = list(datas) + words
+    if with_order:
+        carried.append(jnp.arange(cap, dtype=jnp.int32))
+    for b in range(compact_steps(cap)):
+        k = 1 << b
+        arriving = _shift_left(shift, k)
+        incoming = ((arriving >> b) & 1) != 0
+        carried = [jnp.where(incoming, _shift_left(x, k), x)
+                   for x in carried]
+        shift = jnp.where(incoming, arriving,
+                          jnp.where(((shift >> b) & 1) != 0, 0, shift))
+    sel = row_mask(n, out_cap)
+    carried = [x[:out_cap] for x in carried]
+    outs = []
+    for j, d in enumerate(carried[:len(datas)]):
+        bit = (carried[len(datas) + j // 32] >> (j % 32)) & 1
+        outs.append((jnp.where(sel, d, jnp.zeros((), d.dtype)),
+                     sel & (bit != 0)))
+    return outs, carried[-1] if with_order else None, n
 
 
 @contextlib.contextmanager
 def compact_span(rows_in, capacity: int, columns: int, lazy: bool):
-    """The `filter.compact` span around one batch's compaction, its plan
-    and its gather (exec/fused.py's stage exit, `compact_batch`), and the
+    """The `filter.compact` span around one batch's compaction, its count
+    and its move (exec/fused.py's stage exit, `compact_batch`), and the
     process-wide `compactedBatches`. `rows_in` where the host holds the
-    count; the caller sets `rows_out` where it learns it (an eager
-    compaction syncs the count, a lazy one never does). Yields the span,
-    None with tracing off."""
+    count; `compact_rows` sets `rows_out` where it learns it (an eager
+    compaction syncs the count, a lazy one never does) and `steps`.
+    Yields the span, None with tracing off."""
     M.record_compacted_batch()
     attrs = {"rows_in": rows_in} if isinstance(rows_in, int) else {}
     with OBS.span("filter.compact", capacity=capacity, columns=columns,
@@ -1812,7 +1914,7 @@ def compact_batch(batch: ColumnarBatch, keep_mask,
     reference: cudf Table.filter used by GpuFilterExec,
     basicPhysicalOperators.scala:96-177).
 
-    lazy=True skips the row-count host sync: the gather runs at the
+    lazy=True skips the row-count host sync: the move runs at the
     INPUT's capacity and the result carries a traced num_rows (the batch
     invariant — rows 0..n-1 live, suffix padded — still holds, so every
     consumer works unchanged; anything needing a host int syncs lazily
@@ -1822,51 +1924,44 @@ def compact_batch(batch: ColumnarBatch, keep_mask,
     capacity."""
     with compact_span(batch.num_rows, int(keep_mask.shape[0]),
                       batch.num_columns, lazy) as sp:
-        M.record_dispatch()
-        order, n = _compact_plan(keep_mask, jnp.int32(batch.num_rows))
-        if lazy:
-            return _gather_batch_traced(batch, order, n)
-        n = int(jax.device_get(n))
-        if sp is not None:
-            sp.attrs["rows_out"] = n
-        return gather_batch(batch, order, n, compaction=True)
+        num_rows = jnp.int32(batch.num_rows)
+        n_keep = None
+        if not lazy:
+            M.record_dispatch()
+            n_keep = int(jax.device_get(_compact_plan(keep_mask, num_rows)))
+        return compact_rows(batch, keep_mask, num_rows, n_keep, sp)
 
 
-def _gather_batch_traced(batch: ColumnarBatch, indices,
-                         out_rows) -> ColumnarBatch:
-    """gather_batch with a TRACED output row count: output capacity = the
-    input's (static), string byte capacity = the input byte buffer's
-    (output bytes of a row-subset gather can never exceed it). No host
-    sync anywhere. The lazy compaction's gather (its only callers), so
-    the fixed-width program is `_compact_gather_fixed_cols`."""
-    from spark_rapids_tpu.columnar.encoded import is_encoded
-
-    cap = batch.capacity
-    n32 = jnp.asarray(out_rows, dtype=jnp.int32)
+def compact_rows(batch: ColumnarBatch, keep_mask, num_rows,
+                 n_keep: Optional[int], sp=None) -> ColumnarBatch:
+    """The move of one compaction, inside its `compact_span` `sp`: the rows
+    of `batch` whose `keep_mask` lane is True and lies under `num_rows`,
+    dense and in their order. `n_keep` is `_compact_plan`'s count on the
+    host (the eager compaction: the output takes that count's capacity
+    bucket) or None (the lazy one: the input's capacity, a traced count,
+    no sync). A plain STRING column is fetched through the survivors'
+    order, which the network carries only then."""
+    cap = int(keep_mask.shape[0])
+    lazy = n_keep is None
+    out_cap = cap if lazy else bucket_capacity(max(n_keep, 1))
+    if sp is not None:
+        sp.attrs["steps"] = compact_steps(cap)
+        if not lazy:
+            sp.attrs["rows_out"] = n_keep
     M.record_dispatch()
-    fixed = [(i, cv) for i, cv in enumerate(batch.columns)
-             if is_encoded(cv) or cv.dtype is not DataType.STRING]
+    fixed, sidx = _fixed_and_string_ordinals(batch)
+    outs, order, n = _compact_shift_fixed_cols(
+        out_cap, tuple(cv.data for _, cv in fixed),
+        tuple(cv.validity for _, cv in fixed), keep_mask, num_rows,
+        bool(sidx))
     cols: List[Optional[ColumnVector]] = [None] * batch.num_columns
-    if fixed:
-        datas = tuple(cv.data for _, cv in fixed)
-        valids = tuple(cv.validity for _, cv in fixed)
-        outs = _compact_gather_fixed_cols(cap, datas, valids, indices, n32)
-        for (i, cv), (data, validity) in zip(fixed, outs):
-            cols[i] = cv.with_codes(data, validity) if is_encoded(cv) \
-                else ColumnVector(cv.dtype, data, validity,
-                                  vrange=cv.vrange)
-    sidx = [i for i, cv in enumerate(batch.columns)
-            if cv.dtype is DataType.STRING and
-            not is_encoded(batch.columns[i])]
-    for i in sidx:
-        cv = batch.columns[i]
-        starts, lengths, new_offsets, validity = _gather_string_plan_traced(
-            cv.offsets, cv.validity, indices[:cap], n32)
-        out = _gather_string_bytes(cv.data, starts, new_offsets, lengths,
-                                   int(cv.data.shape[0]))
-        cols[i] = ColumnVector(DataType.STRING, out, validity, new_offsets,
-                               max_len=cv.max_len)
-    return ColumnarBatch(cols, out_rows, owned=True)
+    _fill_fixed_cols(cols, fixed, outs)
+    if sidx and lazy:
+        _gather_string_cols_traced(cols, batch, sidx, order, n)
+    elif sidx:
+        _gather_string_cols(cols, batch, sidx, order, None, out_cap, n_keep,
+                            False)
+    return ColumnarBatch(cols, n if lazy else n_keep, owned=True)
 
 
 @jax.jit

@@ -184,7 +184,7 @@ class TpuFusedStageExec(TpuExec):
         self._row_changing = any(k in ("filter", "limit") for k in kinds)
         # every row-changing op below the expand => all expand variants of
         # one input batch share the SAME live mask, so the stage computes
-        # one compaction plan per batch instead of one per variant
+        # one survivors' count per batch instead of one per variant
         self._live_shared = "expand" not in kinds or all(
             k not in ("filter", "limit")
             for k in kinds[kinds.index("expand") + 1:])
@@ -399,10 +399,9 @@ class TpuFusedStageExec(TpuExec):
         def factory(pidx: int) -> Iterator[ColumnarBatch]:
             from spark_rapids_tpu.columnar.batch import (
                 _compact_plan,
-                _gather_batch_traced,
                 bucket_capacity,
+                compact_rows,
                 compact_span,
-                gather_batch,
             )
             from spark_rapids_tpu.engine.retry import (
                 device_op_with_fallback,
@@ -469,33 +468,27 @@ class TpuFusedStageExec(TpuExec):
 
                 return with_retry(_attempt, site="fused", donated=donated)
 
-            def compact_plan(live, n):
+            def compact_count(live, n):
                 def _attempt():
                     M.record_dispatch()
                     return _compact_plan(live, n)
 
                 return with_retry(_attempt, site="fused")
 
-            def compact(out: ColumnarBatch, live, n, plan=None):
+            def compact(out: ColumnarBatch, live, n, n_keep=None):
                 """The stage exit's compaction of one output batch: the
-                survivors' order (computed here unless `plan` hands over
-                a sibling variant's) and the gather, in one
-                `filter.compact` span. -> (dense batch, plan)."""
+                survivors' count (synced here on the eager path unless
+                `n_keep` hands over a sibling variant's; never on the
+                lazy one) and the move, in one `filter.compact` span.
+                -> (dense batch, count or None)."""
                 with compact_span(out.num_rows, int(live.shape[0]),
                                   out.num_columns, lazy) as sp:
-                    if plan is None:
-                        order, nk = compact_plan(live, n)
+                    if not lazy and n_keep is None:
                         # tpulint: host-sync -- policy-gated stage-exit
-                        plan = (order, nk if lazy
-                                else int(jax.device_get(nk)))
-                    order, n_keep = plan
-                    if lazy:
-                        return _gather_batch_traced(out, order,
-                                                    n_keep), plan
-                    if sp is not None:
-                        sp.attrs["rows_out"] = n_keep
-                    return gather_batch(out, order, n_keep,
-                                        compaction=True), plan
+                        n_keep = int(jax.device_get(compact_count(live, n)))
+                    return with_retry(
+                        lambda: compact_rows(out, live, n, n_keep, sp),
+                        site="fused"), n_keep
 
             def run_simple(b: ColumnarBatch, off: int) -> ColumnarBatch:
                 """One-variant no-limit batch: the split-and-retry /
@@ -580,7 +573,7 @@ class TpuFusedStageExec(TpuExec):
                 # would corrupt the cross-batch LIMIT budget
                 batch, cols, ops2, enc_sig, out_enc = prep(batch)
                 n = jnp.asarray(batch.num_rows, dtype=jnp.int32)
-                plan = None
+                n_keep = None
                 for variant in range(self._n_variants):
                     if remaining is not None and remaining <= 0:
                         break
@@ -590,9 +583,9 @@ class TpuFusedStageExec(TpuExec):
                             ops=ops2, enc_sig=enc_sig)
                     out = wrap_out(outs, batch.num_rows, False, out_enc)
                     if self._row_changing:
-                        out, plan = compact(
+                        out, n_keep = compact(
                             out, live, n,
-                            plan if self._live_shared else None)
+                            n_keep if self._live_shared else None)
                     if remaining is not None and \
                             not self._limit_below_expand:
                         # tpulint: host-sync -- cross-batch LIMIT budget

@@ -414,8 +414,8 @@ def sort_agg_batch_count() -> int:
     return _SORT_AGG_BATCHES.value
 
 
-# batches whose filter survivors were made dense on the device (the plan
-# and the gather of columnar/batch.py `compact_span`): a filter folded
+# batches whose filter survivors were made dense on the device (the count
+# and the move of columnar/batch.py `compact_span`): a filter folded
 # into an aggregate's update program moves no row and counts nothing
 COMPACTED_BATCHES = "compactedBatches"
 _COMPACTED_BATCHES = Metric(COMPACTED_BATCHES)

@@ -231,12 +231,15 @@ def test_q1_aggregate_update_kernel_is_dense_in_f32(
 
 
 def test_compaction_programs_at_an_sf1_split(one_chip, no_persistent_cache):
-    """A filter's survivors in front of a sink (PR 40, `lineitem_write7`):
-    the plan, an argsort of the keep mask, and the gather of seven
-    columns and their validities through it, at the 2^20 lanes of a split
-    of two row groups; DOUBLE in f32, codes and the date in int32. The
-    plan is the program that costs a cold run its half minute; neither
-    keeps temporaries worth naming."""
+    """A filter's survivors in front of a sink (`lineitem_write7`) at the
+    2^20 lanes of a split of two row groups; DOUBLE in f32, codes and the
+    date in int32. Since PR 41 the count is a reduction and the move the
+    shift network over seven columns and their validities: no sort (the
+    argsort cost a cold run 25 s a capacity), no gather, no scatter in
+    either, a compiled name the device trace finds, and no temporaries
+    worth naming. The order alone through the network, what a plain
+    STRING column is fetched through, is the same program with an iota
+    for its columns."""
     from spark_rapids_tpu.columnar import batch as B
 
     cap = 2 * ROW_GROUP_CAP
@@ -244,18 +247,27 @@ def test_compaction_programs_at_an_sf1_split(one_chip, no_persistent_cache):
     def lanes(dtype):
         return jax.ShapeDtypeStruct((cap,), dtype, sharding=one_chip)
 
+    def assert_dense(text):
+        for op in ("sort", "gather", "scatter"):
+            assert f"stablehlo.{op}" not in text
+            assert f"stablehlo.dynamic_{op}" not in text
+
     count = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     lowered, text = _lower(B._compact_plan, (lanes(jnp.bool_), count), {})
-    assert "stablehlo.sort" in text
-    assert lowered.compile().memory_analysis().temp_size_in_bytes < 1 << 26
-    datas = tuple(lanes(d) for d in (jnp.float32,) * 4 + (jnp.int32,) * 3)
-    valids = tuple(lanes(jnp.bool_) for _ in datas)
-    lowered, text = _lower(B._compact_gather_fixed_cols,
-                           (cap, datas, valids, lanes(jnp.int32), count), {})
-    assert "stablehlo.sort" not in text and "stablehlo.scatter" not in text
+    assert_dense(text)
     compiled = lowered.compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 26
-    assert "jit__compact_gather_fixed_cols" in compiled.as_text()
+    assert "jit__compact_plan" in compiled.as_text()
+    datas = tuple(lanes(d) for d in (jnp.float32,) * 4 + (jnp.int32,) * 3)
+    valids = tuple(lanes(jnp.bool_) for _ in datas)
+    for columns, with_order in (((datas, valids), False), (((), ()), True)):
+        lowered, text = _lower(
+            B._compact_shift_fixed_cols,
+            (cap, *columns, lanes(jnp.bool_), count, with_order), {})
+        assert_dense(text)
+        compiled = lowered.compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 26
+        assert "jit__compact_shift_fixed_cols" in compiled.as_text()
 
 
 @pytest.mark.parametrize("builder, filename, sorts", [

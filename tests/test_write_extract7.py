@@ -428,26 +428,27 @@ def test_a_collect_of_the_written_plan_still_returns_strings(session,
 
 def test_the_compaction_has_a_span_a_counter_and_names(session, tmp_path):
     """What the device trace and the span tree find a compaction by: a
-    `filter.compact` span a batch under the task, `compactedBatches`, the
-    plan and the gather in programs whose names hold `compact`; the gather
-    a sort or a slice runs keeps its name and is not run."""
+    `filter.compact` span a batch under the task with the network's
+    steps, `compactedBatches`, the count and the move in programs whose
+    names hold `compact`; the gather a sort or a slice runs keeps its
+    name and is not run."""
     tables = [make_table(seed=21), make_table(seed=22)]
     src = write_files(tmp_path / "src", tables)
     session.conf.set("rapids.tpu.obs.tracing.enabled", True)
     df = extract(session, src)
     df.write.parquet(str(tmp_path / "warm"))        # compiles
     before = M.compacted_batch_count()
-    sizes = (B._compact_gather_fixed_cols._cache_size(),
+    sizes = (B._compact_shift_fixed_cols._cache_size(),
              B._gather_fixed_cols._cache_size())
     dispatches = M.dispatch_count()
     df.write.parquet(str(tmp_path / "out"))
     assert M.compacted_batch_count() - before == 2
-    # a fused stage, a plan and a gather a split, as before PR 40
+    # a fused stage, the survivors' count and their move a split
     assert M.dispatch_count() - dispatches == 6
-    assert (B._compact_gather_fixed_cols._cache_size(),
+    assert (B._compact_shift_fixed_cols._cache_size(),
             B._gather_fixed_cols._cache_size()) == sizes
     assert sizes[0] >= 1
-    for fn in (B._compact_plan, B._compact_gather_fixed_cols):
+    for fn in (B._compact_plan, B._compact_shift_fixed_cols):
         assert "compact" in fn.__name__
     assert "compact" not in B._gather_fixed_cols.__name__
     tree = session.last_query_trace
@@ -459,10 +460,12 @@ def test_the_compaction_has_a_span_a_counter_and_names(session, tmp_path):
         assert sp.attrs["rows_in"] == ROWS and sp.attrs["columns"] == 7
         assert sp.attrs["capacity"] == B.bucket_capacity(ROWS)
         assert sp.attrs["lazy"] is False
+        assert sp.attrs["steps"] == B.compact_steps(sp.attrs["capacity"])
     tasks = [sp for sp in tree.spans() if sp.kind == "task"]
     assert sum(sp.name == "filter.compact" for t in tasks
                for sp in _below(t)) == 2
     assert tree.counts_total()[M.COMPACTED_BATCHES] == 2
+    assert session.last_query_metrics[M.COMPACTED_BATCHES] == 2
 
 
 def _below(span):
@@ -471,7 +474,7 @@ def _below(span):
         yield from _below(c)
 
 
-def test_a_lazy_compaction_says_so_and_gathers_under_the_same_name(
+def test_a_lazy_compaction_says_so_and_moves_under_the_same_name(
         session, tmp_path):
     tables = [make_table(seed=23)]
     src = write_files(tmp_path / "src", tables)
@@ -479,12 +482,18 @@ def test_a_lazy_compaction_says_so_and_gathers_under_the_same_name(
     session.conf.set("rapids.tpu.obs.tracing.enabled", True)
     untouched = B._gather_fixed_cols._cache_size()
     out = str(tmp_path / "out")
+    dispatches = M.dispatch_count()
     extract(session, src).write.parquet(out)
+    # a fused stage and the network, which counts for itself
+    assert M.dispatch_count() - dispatches == 2
     assert B._gather_fixed_cols._cache_size() == untouched
     spans = [sp for sp in session.last_query_trace.spans()
              if sp.name == "filter.compact"]
     assert len(spans) == 1 and spans[0].attrs["lazy"] is True
     assert "rows_out" not in spans[0].attrs
+    assert spans[0].attrs["steps"] == B.compact_steps(
+        spans[0].attrs["capacity"])
+    assert session.last_query_metrics[M.COMPACTED_BATCHES] == 1
     assert_same_rows(pq.read_table(part_files(out)), reference(tables))
 
 

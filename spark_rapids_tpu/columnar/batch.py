@@ -740,22 +740,40 @@ def to_host_many(batches: Sequence["ColumnarBatch"],
     grouped pack program needs co-located inputs). keep_encoded=True (the
     serialized shuffle) keeps dictionary columns as host CODES instead of
     expanding them at the fence."""
-    batches = [b if b.live is None else ensure_compact(b) for b in batches]
+    if any(b.live is not None for b in batches):
+        with OBS.span("sink.pack"):
+            batches = [ensure_compact(b) for b in batches]
     out: List[Optional[HostColumnarBatch]] = [None] * len(batches)
     # per-device open group: dev_key -> (entries, bytes)
     groups: dict = {}
 
     def flush(dev_key):
+        """One fence, in the four steps a traced query sees as spans:
+        `sink.pack` (the pack program issued), `sink.wait` (the device
+        finishing, traced queries only), `sink.transfer` (the copy and
+        its numpy views), `sink.finish` (host columns rebuilt)."""
         group, _bytes = groups.pop(dev_key, ([], 0))
         if not group:
             return
         arrays = tuple(a for _, segs, _, _ in group for a in segs)
-        host = {k: np.asarray(v) for k, v in jax.device_get(
-            _download_grouped(arrays)).items()}
-        offs = {k: 0 for k in host}
-        for bi, _segs, n, trim in group:
-            out[bi] = batches[bi]._download_finish(
-                host, offs, n, trim, keep_encoded=keep_encoded)
+        with OBS.span("sink.pack"):
+            packed = _download_grouped(arrays)
+        if OBS.current_tracer() is not None:
+            # the one place tracing calls into jax: it tells the device
+            # still working from the copy. No dispatch, and no fence the
+            # next line would not make: nothing counts it
+            with OBS.span("sink.wait"):
+                jax.block_until_ready(packed)
+        with OBS.span("sink.transfer") as sp:
+            host = {k: np.asarray(v)
+                    for k, v in jax.device_get(packed).items()}
+            if sp is not None:
+                sp.attrs["bytes"] = sum(v.nbytes for v in host.values())
+        with OBS.span("sink.finish"):
+            offs = {k: 0 for k in host}
+            for bi, _segs, n, trim in group:
+                out[bi] = batches[bi]._download_finish(
+                    host, offs, n, trim, keep_encoded=keep_encoded)
 
     for bi, b in enumerate(batches):
         if not b.columns:

@@ -44,7 +44,7 @@ from spark_rapids_tpu.exec.base import (
 from spark_rapids_tpu.exec.transitions import current_task_id
 from spark_rapids_tpu.io.arrow_convert import arrow_to_host_batch
 from spark_rapids_tpu.memory.semaphore import TpuSemaphore
-from spark_rapids_tpu.obs.trace import span as obs_span, wall_ns
+from spark_rapids_tpu.obs.trace import span as obs_span
 from spark_rapids_tpu.ops.base import AttributeReference
 from spark_rapids_tpu.utils import metrics as M
 
@@ -464,39 +464,46 @@ class _FileScanBase(PhysicalExec):
         # on the prefetcher's thread, which carries the task's context
         # and span (io/prefetch.py)
         with obs_span("scan.host_decode", columns=len(data_attrs)) as sp:
-            table = read_split(split, data_attrs, dict_columns=dict_columns)
-            batch = arrow_to_host_batch(
-                table, data_attrs,
-                conf.get(C.ENCODED_MAX_DICT_FRACTION) if dict_columns
-                else None)
-            del table
-            if sp is not None:
-                sp.attrs["rows"] = batch.num_rows
-                if dict_columns:
-                    coded = [c for c in batch.columns
-                             if getattr(c, "dictionary", None) is not None]
-                    sp.attrs["dict_columns"] = len(coded)
-                    sp.attrs["dict_bytes"] = sum(
-                        c.data.nbytes + int(c.dictionary.host_offsets[-1])
-                        for c in coded)
-            if pv:
-                # append partition-value constant columns (reference:
-                # ColumnarPartitionReaderWithPartitionValues)
-                batch = _with_partition_columns(batch, self.attrs, pv)
-            max_rows = conf.get(C.MAX_READ_BATCH_SIZE_ROWS)
-            if batch.num_rows <= max_rows:
-                batches = [batch]
-            else:
-                batches = [batch.slice(i, max_rows)
-                           for i in range(0, batch.num_rows, max_rows)]
-            del batch
-            if stage:
-                t0 = wall_ns() if sp is not None else 0
-                batches = [hb.stage_upload() for hb in batches]
+            # its three steps, each a span of its own: Arrow's pool works
+            # in the first while this thread sleeps; the other two are
+            # this thread's numpy and Python
+            with obs_span("scan.arrow_read"):
+                table = read_split(split, data_attrs,
+                                   dict_columns=dict_columns)
+            with obs_span("scan.convert"):
+                batch = arrow_to_host_batch(
+                    table, data_attrs,
+                    conf.get(C.ENCODED_MAX_DICT_FRACTION) if dict_columns
+                    else None)
+                del table
                 if sp is not None:
-                    sp.attrs["pack_ms"] = (wall_ns() - t0) / 1e6
-                    sp.attrs["packed_bytes"] = sum(
-                        b.nbytes for st in batches for b in st.bufs)
+                    sp.attrs["rows"] = batch.num_rows
+                    if dict_columns:
+                        coded = [c for c in batch.columns
+                                 if getattr(c, "dictionary", None)
+                                 is not None]
+                        sp.attrs["dict_columns"] = len(coded)
+                        sp.attrs["dict_bytes"] = sum(
+                            c.data.nbytes
+                            + int(c.dictionary.host_offsets[-1])
+                            for c in coded)
+                if pv:
+                    # append partition-value constant columns (reference:
+                    # ColumnarPartitionReaderWithPartitionValues)
+                    batch = _with_partition_columns(batch, self.attrs, pv)
+                max_rows = conf.get(C.MAX_READ_BATCH_SIZE_ROWS)
+                if batch.num_rows <= max_rows:
+                    batches = [batch]
+                else:
+                    batches = [batch.slice(i, max_rows)
+                               for i in range(0, batch.num_rows, max_rows)]
+                del batch
+            if stage:
+                with obs_span("scan.pack") as packed:
+                    batches = [hb.stage_upload() for hb in batches]
+                    if packed is not None:
+                        packed.attrs["packed_bytes"] = sum(
+                            b.nbytes for st in batches for b in st.bufs)
         # handed over one at a time: a batch that has gone downstream is
         # not kept alive from here
         batches.reverse()
@@ -1322,11 +1329,18 @@ class TpuFileScanExec(_FileScanBase, TpuExec):
                     if rest_table is None:
                         # read the way the host path reads a split: ONE
                         # threaded Arrow read, then a slice a row group
-                        rest_table = read_split(split, plan.rest, pf)
-                    hb = arrow_to_host_batch(
-                        rest_table.slice(rest_at, rows), plan.rest)
+                        with obs_span("scan.arrow_read"):
+                            rest_table = read_split(split, plan.rest, pf)
+                    with obs_span("scan.convert"):
+                        hb = arrow_to_host_batch(
+                            rest_table.slice(rest_at, rows), plan.rest)
                     rest_at += rows
-                host = self._stage_host_part(hb, plan.rest, plan.pv, rows)
+                with obs_span("scan.pack") as packed:
+                    host = self._stage_host_part(hb, plan.rest, plan.pv,
+                                                 rows)
+                    if packed is not None and host is not None:
+                        packed.attrs["packed_bytes"] = sum(
+                            b.nbytes for b in host.bufs)
             yield _StagedRowGroup(rg, rows, chunks, host)
 
     def _decode_staged(self, plan: "_SplitPlan", item: "_StagedRowGroup",

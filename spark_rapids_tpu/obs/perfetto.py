@@ -39,6 +39,10 @@ def trace_to_chrome_events(trace) -> dict:
     def walk(sp) -> None:
         end = sp.end_ns if sp.end_ns is not None else sp.start_ns
         args = {"kind": sp.kind}
+        if sp.cpu_ns is not None:
+            # the thread's CPU time inside the span: dur less this is
+            # time it spent off a core
+            args["cpu_ns"] = sp.cpu_ns
         args.update({str(k): _prim(v) for k, v in sp.attrs.items()})
         args.update({str(k): _prim(v) for k, v in sp.counts.items()})
         events.append({

@@ -10,11 +10,16 @@ timeline (obs/perfetto.py) or aggregated per stage/operator
 
 Overhead contract (docs/observability.md):
 
-- HOST CLOCK ONLY: a span records time.perf_counter_ns at open and close
-  — never a device value, never .block_until_ready(), never a transfer.
+- HOST CLOCKS ONLY: a span reads the wall clock (time.perf_counter_ns)
+  at open and at close — never a device value, never a transfer. The
+  few spans named in `CPU_CLOCKED` read their thread's CPU clock
+  (time.thread_time_ns) beside it, and a traced query reads the
+  process's CPU clock (time.process_time_ns) once at each end.
   Tracing adds ZERO device dispatches and ZERO host fences; the flagship
   deviceDispatches/fencesPerQuery counts are identical with tracing on
-  vs off (pinned by tests/test_observability.py).
+  vs off (pinned by tests/test_observability.py). The one place tracing
+  calls into jax is the sink's `sink.wait` (columnar/batch.to_host_many:
+  block_until_ready on arrays the next line fetches anyway).
 - TRUE NO-OP WHEN OFF: with `rapids.tpu.obs.tracing.enabled` off the
   ambient QueryContext carries no tracer, `span(...)` returns one shared
   no-op context manager (no allocation, no clock read), and the metric
@@ -47,6 +52,24 @@ def wall_ns() -> int:
     return time.perf_counter_ns()
 
 
+# the busy half of a span: CPU time of the calling thread alone. Wall less
+# CPU is time the thread spent off a core: queued for the interpreter's
+# lock or a core, asleep while Arrow's pool, the device or the disk works
+def thread_cpu_ns() -> int:
+    return time.thread_time_ns()
+
+
+# the spans that read it, by name: the ones a per-layer metric or a table
+# of PERF.md section 5 reads (one thread's own numpy, Python or encode,
+# and the permit wait, which must read about 0). Not every span: where
+# the kernel answers this clock with a system call (6 us on the chip's
+# host, against 0.09 for the wall clock) a read a span was 3.3 ms of a
+# 75 ms action (PERF.md section 6, PR 43)
+CPU_CLOCKED = frozenset({
+    "scan.convert", "scan.pack", "sink.finish", "write.file",
+    "Acquire TPU Semaphore"})
+
+
 # ambient current span (parallel to utils/metrics._QUERY_CTX; propagated
 # onto worker threads by the scheduler's copy_context submission)
 _CURRENT_SPAN: "contextvars.ContextVar[Optional[Span]]" = \
@@ -67,7 +90,7 @@ class Span:
     this span was current on its thread."""
 
     __slots__ = ("name", "kind", "start_ns", "end_ns", "tid", "attrs",
-                 "counts", "children", "owner")
+                 "counts", "children", "owner", "cpu_ns", "_cpu_start_ns")
 
     def __init__(self, name: str, kind: str, start_ns: int,
                  attrs: Optional[dict] = None, owner=None):
@@ -84,6 +107,12 @@ class Span:
         # (a contextvar that outlived its query on some thread) can never
         # be mutated under the wrong lock or absorb a foreign child
         self.owner = owner
+        # CPU ns of the opening thread between open and close, for a
+        # span named in CPU_CLOCKED; None for every other, and for one
+        # closed on another thread (a generator resumed elsewhere),
+        # closed by finish(), or noted after the fact
+        self.cpu_ns: Optional[int] = None
+        self._cpu_start_ns: Optional[int] = None
 
     @property
     def duration_ns(self) -> int:
@@ -138,6 +167,11 @@ class QueryTracer:
                 self._annotation_cls = None
         self.root = Span(f"query:{name}", KIND_QUERY, wall_ns(),
                          {"tenant": tenant}, owner=self)
+        # CPU of ALL the process's threads so far (the tasks', Arrow's
+        # and XLA's pools); finish() writes the difference onto the root
+        # as `proc_cpu_ms`. PROCESS-WIDE: with one client it is one
+        # action's, with several it is everybody's
+        self._proc_cpu_start_ns = time.process_time_ns()
         self._n_spans = 1
         self._finished = False
 
@@ -162,6 +196,13 @@ class QueryTracer:
         counts, unlike the dropped span's timing, must stay exact: they
         reconcile against the query's own metrics)."""
         sp = Span(name, kind, wall_ns(), attrs, owner=self)
+        if name in CPU_CLOCKED:
+            # after the wall clock here and before it in close_span: the
+            # CPU interval lies inside the wall interval. (Where the
+            # kernel's CPU clock steps in ticks, 10 ms on the chip's
+            # host, one span's reading may still pass its wall: there
+            # only sums over many spans say anything)
+            sp._cpu_start_ns = thread_cpu_ns()
         parent = self._parent()
         token = None
         with self._lock:
@@ -187,6 +228,9 @@ class QueryTracer:
         sp, token, anno = handle
         if anno is not None:
             anno.__exit__(None, None, None)
+        if sp._cpu_start_ns is not None and sp.tid == threading.get_ident():
+            # closed on the thread whose clock was read at the opening
+            sp.cpu_ns = thread_cpu_ns() - sp._cpu_start_ns
         sp.end_ns = wall_ns()
         if token is not None:
             _CURRENT_SPAN.reset(token)
@@ -230,6 +274,8 @@ class QueryTracer:
         with self._lock:
             self._finished = True
         end = wall_ns()
+        self.root.attrs["proc_cpu_ms"] = \
+            (time.process_time_ns() - self._proc_cpu_start_ns) / 1e6
         # a query killed mid-flight (cancel / deadline expiry / shed,
         # engine/cancel.py) unwinds through exceptions that skip worker
         # threads' close_span calls: close every still-open span at the
@@ -408,9 +454,11 @@ class QueryTrace:
             if sp.counts:
                 extras = " " + " ".join(
                     f"{k}={v}" for k, v in sorted(sp.counts.items()))
+            cpu = "" if sp.cpu_ns is None \
+                else f" cpu={sp.cpu_ns / 1e6:.3f}ms"
             lines.append("  " * depth
                          + f"[{sp.kind}] {sp.name}"
-                         f" {sp.duration_ns / 1e6:.3f}ms{extras}")
+                         f" {sp.duration_ns / 1e6:.3f}ms{cpu}{extras}")
             for c in sp.children:
                 walk(c, depth + 1)
 

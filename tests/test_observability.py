@@ -226,12 +226,113 @@ def test_traced_write_span_tree_and_accounting(session, tmp_path, encoder):
                    for s in d2h)
 
 
+SINK_STEPS = ["sink.pack", "sink.wait", "sink.transfer", "sink.finish"]
+
+
+def _sink_rows(session, tmp_path, action):
+    """The rows an action hands back: a collect's own, a write's files
+    read back by Arrow."""
+    import pyarrow.parquet as pq
+
+    if action == "collect":
+        rows = _flagship(_mk_df(session)).collect()
+        return sorted(tuple(r) for r in rows)
+    session.set_conf(C.PARQUET_DEVICE_ENCODE.key, False)  # the chip's path
+    df, _ = _write_source(session)
+    df.write.parquet(str(tmp_path))
+    table = pq.read_table(str(tmp_path))
+    return sorted(zip(*(table.column(n).to_pylist()
+                        for n in table.column_names)))
+
+
+@pytest.mark.parametrize("action", ["collect", "write"])
+def test_traced_fence_has_its_four_steps(session, tmp_path, action):
+    """Every DeviceToHost of a traced action (a collect's query-level
+    fence, a write's fence a task: both go through
+    columnar/batch.to_host_many) has four children, one after the other
+    on its thread: sink.pack, sink.wait (tracing's one call into jax),
+    sink.transfer with the host `bytes` fetched, sink.finish. The rows,
+    the dispatches and the fences are what the untraced action gives."""
+    _sink_rows(session, tmp_path / "warm", action)
+    before = M.dispatch_count(), M.fence_count()
+    off_rows = _sink_rows(session, tmp_path / "off", action)
+    off = M.dispatch_count() - before[0], M.fence_count() - before[1]
+    assert session.last_query_trace is None
+    session.set_conf(C.OBS_TRACING.key, True)
+    before = M.dispatch_count(), M.fence_count()
+    on_rows = _sink_rows(session, tmp_path / "on", action)
+    on = M.dispatch_count() - before[0], M.fence_count() - before[1]
+    assert on_rows == off_rows and len(on_rows) > 0
+    assert on == off and on[1] >= 1
+    trace = session.last_query_trace
+    fences = trace.find("DeviceToHost")
+    assert len(fences) == on[1], trace.render()
+    for fence in fences:
+        names = [c.name for c in fence.children]
+        # a live-masked batch (the collect's) is made dense first, in a
+        # sink.pack of its own ahead of the group's
+        assert names in (SINK_STEPS, ["sink.pack"] + SINK_STEPS), \
+            trace.render()
+        wait, transfer, finish = fence.children[-3:]
+        assert fence.start_ns <= fence.children[0].start_ns and \
+            finish.end_ns <= fence.end_ns
+        for a, b in zip(fence.children, fence.children[1:]):
+            assert a.end_ns <= b.start_ns
+        for child in fence.children:
+            assert child.tid == fence.tid
+            # sink.finish alone reads its thread's CPU clock (a fence's
+            # host numpy; obs.trace.CPU_CLOCKED)
+            assert (child.cpu_ns is not None) == (child is finish)
+        assert finish.cpu_ns >= 0
+        assert set(transfer.attrs) == {"bytes"}
+        assert transfer.attrs["bytes"] > 0
+        assert not (wait.counts or transfer.counts or finish.counts)
+    # nothing of the sink outside a fence
+    inside = {id(c) for f in fences for c in f.children}
+    assert all(id(sp) in inside for sp in trace.spans()
+               if sp.name.startswith("sink."))
+
+
+@pytest.mark.usefixtures("device_string_decoder")
+def test_traced_string_scan_host_half_has_its_steps(tmp_path):
+    """The HOST half of a scan with a string column the device decodes
+    (`_stage_split`): a row group's scan.host_decode holds scan.convert
+    and scan.pack (`packed_bytes`: what that row group's scan.upload
+    moves), and the split's first one the ONE scan.arrow_read before
+    them."""
+    session = srt.new_session({"rapids.tpu.sql.spmd.meshDevices": 1,
+                               C.OBS_TRACING.key: True})
+    try:
+        df, _ = _parquet_source(session, tmp_path, string=True)
+        df.filter(F.col("p") < 500) \
+            .agg(F.sum("x"), F.sum("q"), F.count("s")).collect()
+        trace = session.last_query_trace
+        assert session.last_query_metrics[M.CPU_FALLBACK_EVENTS] == 0
+    finally:
+        session.stop()
+    tasks = [sp for sp in trace.spans()
+             if any(c.name == "scan.split" for c in sp.children)]
+    assert len(tasks) == 2
+    for task in tasks:
+        decodes = [c for c in task.children if c.name == "scan.host_decode"]
+        assert [d.attrs["rg"] for d in decodes] == [0, 1]
+        assert [[c.name for c in d.children] for d in decodes] == [
+            ["scan.arrow_read", "scan.convert", "scan.pack"],
+            ["scan.convert", "scan.pack"]]
+        uploads = [c for rg in task.children if rg.name == "scan.rowgroup"
+                   for c in rg.children if c.name == "scan.upload"]
+        assert [d.children[-1].attrs["packed_bytes"] for d in decodes] == \
+            [u.attrs["bytes"] for u in uploads]
+        assert all("pack_ms" not in d.attrs for d in decodes)
+
+
 def test_traced_fixed_width_parquet_scan_spans(tmp_path):
     """A scan without a string column is Arrow's (PR 30): a split leaves
-    one scan.host_decode (`columns`, `rows`, and since PR 32 the packing
-    for the upload: `pack_ms`, `packed_bytes`), opened on the prefetcher's
+    one scan.host_decode (`columns`, `rows`), opened on the prefetcher's
     thread under the task's span and closed before the task asks for its
-    permit, and one scan.upload (`bytes`, `staged`) a batch, on the
+    permit, with its three steps as children on that thread (PR 43:
+    scan.arrow_read, scan.convert, scan.pack with `packed_bytes`; no
+    `pack_ms`), and one scan.upload (`bytes`, `staged`) a batch, on the
     task's thread and after its permit; no span of the device decoder. A
     task waiting for the admission permit is no deeper in the tree than a
     permit holder's upload."""
@@ -255,12 +356,25 @@ def test_traced_fixed_width_parquet_scan_spans(tmp_path):
     assert len(tasks) == 2
     for task in tasks:
         (decode,) = [c for c in task.children if c.name == "scan.host_decode"]
-        assert set(decode.attrs) == {"columns", "rows", "pack_ms",
-                                     "packed_bytes"}
+        assert set(decode.attrs) == {"columns", "rows"}
         assert decode.attrs["columns"] == 3
         assert decode.attrs["rows"] == 2 * 4096
-        assert 0 < decode.attrs["pack_ms"] <= decode.duration_ns / 1e6
         assert decode.tid != task.tid  # the reader thread's
+        read, convert, pack = decode.children
+        assert [c.name for c in decode.children] == \
+            ["scan.arrow_read", "scan.convert", "scan.pack"]
+        assert set(pack.attrs) == {"packed_bytes"}
+        assert not read.attrs and not convert.attrs
+        for child in decode.children:
+            # one after the other on the reader's thread; the two that
+            # are this thread's own work with the CPU it spent in them
+            # (in scan.arrow_read it sleeps while Arrow's pool works)
+            assert child.tid == decode.tid and not child.children
+        assert read.cpu_ns is None
+        assert convert.cpu_ns >= 0 and pack.cpu_ns >= 0
+        assert decode.start_ns <= read.start_ns <= read.end_ns \
+            <= convert.start_ns <= convert.end_ns <= pack.start_ns \
+            <= pack.end_ns <= decode.end_ns
         (asked,) = [c for c in task.children
                     if c.name == "Acquire TPU Semaphore"]
         (upload,) = [c for c in task.children if c.name == "scan.upload"]
@@ -269,7 +383,7 @@ def test_traced_fixed_width_parquet_scan_spans(tmp_path):
         # q and p as int32 (q narrows on its value range), x as f64 here,
         # a validity byte each: what was packed is what goes up
         assert upload.attrs["bytes"] >= 2 * 4096 * (4 + 4 + 8)
-        assert upload.attrs["bytes"] == decode.attrs["packed_bytes"]
+        assert upload.attrs["bytes"] == pack.attrs["packed_bytes"]
         assert decode.end_ns <= asked.start_ns
         assert asked.end_ns <= upload.start_ns
 

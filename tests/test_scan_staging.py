@@ -641,11 +641,15 @@ def test_arrow_path_packs_no_split_under_a_held_permit(
         assert (decode.tid == task.tid) == (depth == 0)
         assert up.tid == task.tid and up.attrs["staged"] == 1
         assert decode.attrs["rows"] == 2 * ROWS
-        assert 0 < decode.attrs["pack_ms"] <= decode.duration_ns / 1e6
+        # the packing is a span of its own under it (PR 43), last of three
+        (pack,) = [c for c in decode.children if c.name == "scan.pack"]
+        assert decode.children[-1] is pack and pack.tid == decode.tid
+        assert 0 < pack.duration_ns <= decode.duration_ns
+        assert "pack_ms" not in decode.attrs
         # four columns and their validity, padded to the capacity bucket
-        assert decode.attrs["packed_bytes"] == \
+        assert pack.attrs["packed_bytes"] == \
             4096 * (8 + 8 + 4 + 8) + 4 * 4096
-        assert up.attrs["bytes"] == decode.attrs["packed_bytes"]
+        assert up.attrs["bytes"] == pack.attrs["packed_bytes"]
 
 
 @pytest.mark.parametrize("depth", [0, 1])

@@ -26,7 +26,16 @@ import contextlib
 import functools
 import itertools
 import threading
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import (
+    Any,
+    Callable,
+    Iterator,
+    List,
+    NamedTuple,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 import numpy as np
 
@@ -592,10 +601,13 @@ class ColumnarBatch:
 
     # -- download (reference: GpuColumnarToRowExec copyToRowHost) ------------
     def _download_plan(self):
-        """(device arrays to fetch, n_or_None, trim) for this batch — the
-        first phase of to_host, shared with the batched to_host_many.
-        Encoded (dictionary) columns download their CODES only — the
-        dictionary's values already live on the host."""
+        """(device arrays to fetch, n_or_None, trim, columns) for this
+        batch — the first phase of to_host, shared with the batched
+        to_host_many. Encoded (dictionary) columns download their CODES
+        only — the dictionary's values already live on the host.
+        `columns` is what `_download_finish` rebuilds each host column
+        from (a `_ColumnPlan`): it holds no device array, so the batch
+        can be dropped between the fetch and the finish."""
         from spark_rapids_tpu.columnar.encoded import is_encoded
 
         if self.rows_on_host:
@@ -613,89 +625,20 @@ class ColumnarBatch:
         else:
             n = self.host_rows()
             trim = min(self.capacity, bucket_capacity(max(n, 1)))
-        arrays = []
+        arrays, columns = [], []
         for cv in self.columns:
             if cv.dtype is DataType.STRING and not is_encoded(cv):
                 arrays.extend([cv.offsets[:trim + 1], cv.data,
                                cv.validity[:trim]])
             else:
                 arrays.extend([cv.data[:trim], cv.validity[:trim]])
+            columns.append(_ColumnPlan(
+                cv.dtype, np.dtype(cv.data.dtype), int(cv.data.shape[0]),
+                cv.dictionary if is_encoded(cv) else None))
         if n is None:
             arrays.append(jnp.asarray(self.num_rows,
                                       dtype=jnp.int32).reshape(1))
-        return arrays, n, trim
-
-    def _download_finish(self, host, offs, n, trim,
-                         keep_encoded: bool = False) -> HostColumnarBatch:
-        """Reconstruct host columns from the grouped download buffers,
-        consuming segments at the shared per-dtype cursors `offs`.
-        Encoded columns arrive as codes: keep_encoded=True (the serialized
-        shuffle, the spill store, a file writer's sink) keeps them as
-        HostDictionaryColumn; otherwise they expand here through the host
-        dictionary — the result-sink form of late materialization (the
-        values never crossed the fence)."""
-        from spark_rapids_tpu.columnar.encoded import (
-            HostDictionaryColumn,
-            is_encoded,
-            materialize_host_values,
-        )
-
-        def take(count, np_dtype):
-            np_dtype = np.dtype(np_dtype)
-            key = "uint8" if np_dtype == np.bool_ else np_dtype.name
-            seg = host[key][offs[key]:offs[key] + count]
-            offs[key] += count
-            if np_dtype == np.bool_:
-                return seg.astype(bool)
-            return seg
-
-        # consume raw segments in the exact _download_plan append order
-        # first (the count, when device-resident, rides LAST), then build
-        raw = []
-        for cv in self.columns:
-            if cv.dtype is DataType.STRING and not is_encoded(cv):
-                raw.append((take(trim + 1, np.int32),
-                            take(int(cv.data.shape[0]), np.uint8),
-                            take(trim, np.bool_)))
-            else:
-                raw.append((take(trim, np.dtype(cv.data.dtype)),
-                            take(trim, np.bool_)))
-        if n is None:
-            n = int(take(1, np.int32)[0])
-            self.num_rows = n
-        out = []
-        for cv, seg in zip(self.columns, raw):
-            if is_encoded(cv):
-                codes = seg[0][:n].astype(np.int32)
-                validity = seg[1][:n]
-                codes = np.where(validity, codes, 0)
-                if keep_encoded:
-                    out.append(HostDictionaryColumn(
-                        cv.dtype, codes, validity, cv.dictionary))
-                else:
-                    strs = materialize_host_values(codes, validity,
-                                                   cv.dictionary)
-                    out.append(HostColumnVector(cv.dtype, strs, validity))
-            elif cv.dtype is DataType.STRING:
-                offsets, data, validity = seg
-                validity = validity[:n]
-                strs = np.empty(n, dtype=object)
-                for i in range(n):
-                    if validity[i]:
-                        strs[i] = bytes(
-                            data[offsets[i]:offsets[i + 1]]
-                        ).decode("utf-8", errors="replace")
-                    else:
-                        strs[i] = ""
-                out.append(HostColumnVector(DataType.STRING, strs, validity))
-            else:
-                data, validity = seg[0][:n], seg[1][:n]
-                npdt = cv.dtype.to_np()
-                if data.dtype != npdt:
-                    data = data.astype(npdt)
-                data = np.where(validity, data, npdt.type(0))
-                out.append(HostColumnVector(cv.dtype, data, validity))
-        return HostColumnarBatch(out, n)
+        return arrays, n, trim, columns
 
     def to_host(self) -> HostColumnarBatch:
         """Single-transfer download: one jitted device pack into per-dtype
@@ -730,32 +673,132 @@ def _batch_device_key(b: "ColumnarBatch"):
 DOWNLOAD_BYTE_BUDGET = 256 << 20
 
 
-def to_host_many(batches: Sequence["ColumnarBatch"],
-                 byte_budget: int = DOWNLOAD_BYTE_BUDGET,
-                 keep_encoded: bool = False) -> List[HostColumnarBatch]:
-    """Download MANY device batches with one grouped transfer (one fence)
-    per `byte_budget` worth of data — the collect/transition path would
-    otherwise pay one host round trip per batch.
-    Batches on different devices download in per-device groups (the
-    grouped pack program needs co-located inputs). keep_encoded=True (the
-    serialized shuffle) keeps dictionary columns as host CODES instead of
-    expanding them at the fence."""
+class _ColumnPlan(NamedTuple):
+    """What `_download_finish` needs of one device column, none of it on
+    the device: the logical dtype, the dtype and length of `data` as it
+    was fetched (a DOUBLE narrowed by the upload; a STRING's bytes), and
+    the dictionary of an encoded column (None for any other)."""
+    dtype: DataType
+    data_dtype: np.dtype
+    data_len: int
+    dictionary: Any
+
+
+def _download_finish(columns: Sequence[_ColumnPlan], host, offs, n, trim,
+                     keep_encoded: bool = False) -> HostColumnarBatch:
+    """Reconstruct one batch's host columns from the grouped download
+    buffers, consuming segments at the shared per-dtype cursors `offs`.
+    Pure host work: it reads `columns` (`ColumnarBatch._download_plan`)
+    and the fetched bytes, never the device batch.
+    Encoded columns arrive as codes: keep_encoded=True (the serialized
+    shuffle, the spill store, a file writer's sink) keeps them as
+    HostDictionaryColumn; otherwise they expand here through the host
+    dictionary — the result-sink form of late materialization (the
+    values never crossed the fence)."""
+    from spark_rapids_tpu.columnar.encoded import (
+        HostDictionaryColumn,
+        materialize_host_values,
+    )
+
+    def take(count, np_dtype):
+        np_dtype = np.dtype(np_dtype)
+        key = "uint8" if np_dtype == np.bool_ else np_dtype.name
+        seg = host[key][offs[key]:offs[key] + count]
+        offs[key] += count
+        if np_dtype == np.bool_:
+            return seg.astype(bool)
+        return seg
+
+    # consume raw segments in the exact _download_plan append order
+    # first (the count, when device-resident, rides LAST), then build
+    raw = []
+    for col in columns:
+        if col.dtype is DataType.STRING and col.dictionary is None:
+            raw.append((take(trim + 1, np.int32),
+                        take(col.data_len, np.uint8),
+                        take(trim, np.bool_)))
+        else:
+            raw.append((take(trim, col.data_dtype),
+                        take(trim, np.bool_)))
+    if n is None:
+        n = int(take(1, np.int32)[0])
+    out = []
+    for col, seg in zip(columns, raw):
+        if col.dictionary is not None:
+            codes = seg[0][:n].astype(np.int32)
+            validity = seg[1][:n]
+            codes = np.where(validity, codes, 0)
+            if keep_encoded:
+                out.append(HostDictionaryColumn(
+                    col.dtype, codes, validity, col.dictionary))
+            else:
+                strs = materialize_host_values(codes, validity,
+                                               col.dictionary)
+                out.append(HostColumnVector(col.dtype, strs, validity))
+        elif col.dtype is DataType.STRING:
+            offsets, data, validity = seg
+            validity = validity[:n]
+            strs = np.empty(n, dtype=object)
+            for i in range(n):
+                if validity[i]:
+                    strs[i] = bytes(
+                        data[offsets[i]:offsets[i + 1]]
+                    ).decode("utf-8", errors="replace")
+                else:
+                    strs[i] = ""
+            out.append(HostColumnVector(DataType.STRING, strs, validity))
+        else:
+            data, validity = seg[0][:n], seg[1][:n]
+            npdt = col.dtype.to_np()
+            if data.dtype != npdt:
+                data = data.astype(npdt)
+            data = np.where(validity, data, npdt.type(0))
+            out.append(HostColumnVector(col.dtype, data, validity))
+    return HostColumnarBatch(out, n)
+
+
+def _permit_held() -> bool:
+    """Whether the calling task holds the admission permit (a traced
+    `sink.finish` records it: host work that needs none)."""
+    from spark_rapids_tpu.exec.transitions import current_task_id
+    from spark_rapids_tpu.memory.semaphore import TpuSemaphore
+
+    return TpuSemaphore.get().held_by(current_task_id())
+
+
+def _finish_group(fetched, out: list, keep_encoded: bool) -> None:
+    """The fourth step of a fence, `sink.finish`: the host batches of one
+    fetched group rebuilt into `out`. `fetched` is what `_fetch_groups`
+    yields: the fence's bytes on the host (a numpy array a dtype) and, a
+    batch, (its place in `out`, columns, n, trim). No device array."""
+    host, entries = fetched
+    with OBS.span("sink.finish") as sp:
+        if sp is not None:
+            sp.attrs["permit_held"] = _permit_held()
+        offs = {k: 0 for k in host}
+        for bi, columns, n, trim in entries:
+            out[bi] = _download_finish(columns, host, offs, n, trim,
+                                       keep_encoded=keep_encoded)
+
+
+def _fetch_groups(batches: Sequence["ColumnarBatch"], byte_budget: int,
+                  out: list) -> Iterator[tuple]:
+    """The device half of a download: groups `batches` a device and a
+    `byte_budget`, and yields each group fetched, one fence each, in the
+    three steps a traced query sees as spans: `sink.pack` (the pack
+    program issued), `sink.wait` (the device finishing, traced queries
+    only), `sink.transfer` (the copy and its numpy views).
+    `_finish_group` is the fourth. A batch without columns is its row
+    count, put into `out` here."""
     if any(b.live is not None for b in batches):
         with OBS.span("sink.pack"):
             batches = [ensure_compact(b) for b in batches]
-    out: List[Optional[HostColumnarBatch]] = [None] * len(batches)
     # per-device open group: dev_key -> (entries, bytes)
     groups: dict = {}
 
     def flush(dev_key):
-        """One fence, in the four steps a traced query sees as spans:
-        `sink.pack` (the pack program issued), `sink.wait` (the device
-        finishing, traced queries only), `sink.transfer` (the copy and
-        its numpy views), `sink.finish` (host columns rebuilt)."""
-        group, _bytes = groups.pop(dev_key, ([], 0))
-        if not group:
-            return
-        arrays = tuple(a for _, segs, _, _ in group for a in segs)
+        group, _bytes = groups.pop(dev_key)
+        arrays = tuple(a for _, segs, _, _, _ in group for a in segs)
         with OBS.span("sink.pack"):
             packed = _download_grouped(arrays)
         if OBS.current_tracer() is not None:
@@ -769,28 +812,69 @@ def to_host_many(batches: Sequence["ColumnarBatch"],
                     for k, v in jax.device_get(packed).items()}
             if sp is not None:
                 sp.attrs["bytes"] = sum(v.nbytes for v in host.values())
-        with OBS.span("sink.finish"):
-            offs = {k: 0 for k in host}
-            for bi, _segs, n, trim in group:
-                out[bi] = batches[bi]._download_finish(
-                    host, offs, n, trim, keep_encoded=keep_encoded)
+        # a device-resident row count came with the batch's int32
+        # segments, as their last lane: the batch keeps it as a host int
+        counts = 0
+        for bi, segs, n, _trim, _columns in group:
+            counts += sum(a.shape[0] for a in segs if a.dtype == jnp.int32)
+            if n is None:
+                batches[bi].num_rows = int(host["int32"][counts - 1])
+        return host, [(bi, columns, n, trim)
+                      for bi, _, n, trim, columns in group]
 
     for bi, b in enumerate(batches):
         if not b.columns:
             out[bi] = HostColumnarBatch([], b.host_rows())
             continue
-        arrays, n, trim = b._download_plan()
+        arrays, n, trim, columns = b._download_plan()
         sz = b.device_memory_size()
         dev = _batch_device_key(b)
         group, group_bytes = groups.get(dev, ([], 0))
         if group and group_bytes + sz > byte_budget:
-            flush(dev)
+            yield flush(dev)
             group, group_bytes = [], 0
-        group.append((bi, arrays, n, trim))
+        group.append((bi, arrays, n, trim, columns))
         groups[dev] = (group, group_bytes + sz)
     for dev in list(groups):
-        flush(dev)
+        yield flush(dev)
+
+
+def to_host_many(batches: Sequence["ColumnarBatch"],
+                 byte_budget: int = DOWNLOAD_BYTE_BUDGET,
+                 keep_encoded: bool = False) -> List[HostColumnarBatch]:
+    """Download MANY device batches with one grouped transfer (one fence)
+    per `byte_budget` worth of data — the collect/transition path would
+    otherwise pay one host round trip per batch.
+    Batches on different devices download in per-device groups (the
+    grouped pack program needs co-located inputs). keep_encoded=True (the
+    serialized shuffle) keeps dictionary columns as host CODES instead of
+    expanding them at the fence. A group is finished (`sink.finish`)
+    before the next is fetched."""
+    out: List[Optional[HostColumnarBatch]] = [None] * len(batches)
+    for fetched in _fetch_groups(batches, byte_budget, out):
+        _finish_group(fetched, out, keep_encoded)
     return out  # type: ignore[return-value]
+
+
+def fetch_many(batches: Sequence["ColumnarBatch"],
+               byte_budget: int = DOWNLOAD_BYTE_BUDGET,
+               keep_encoded: bool = False
+               ) -> Callable[[], List[HostColumnarBatch]]:
+    """`to_host_many` in two calls, for a caller that gives the chip back
+    in between (exec/transitions.DeviceToHostExec): every group is
+    fetched here, which is all of the download that touches the device,
+    and the call returned rebuilds the host batches, `to_host_many`'s to
+    the byte. What is returned holds no device array: the caller may
+    drop `batches` before it calls it."""
+    out: List[Optional[HostColumnarBatch]] = [None] * len(batches)
+    groups = list(_fetch_groups(batches, byte_budget, out))
+
+    def finish() -> List[HostColumnarBatch]:
+        while groups:  # a group's bytes go as its batches are rebuilt
+            _finish_group(groups.pop(0), out, keep_encoded)
+        return out  # type: ignore[return-value]
+
+    return finish
 
 
 # ---------------------------------------------------------------------------

@@ -105,18 +105,20 @@ class RequireSingleBatch(CoalesceGoal):
         return isinstance(other, RequireSingleBatch)
 
 
-def sink_download_many(run, keep_encoded: bool = False):
-    """Grouped sink download with async error attribution: the ONE place
-    a query is allowed to block on device values. A device-rooted error
-    surfacing here under issue-ahead execution belongs to some upstream
-    dispatch, not to the transfer — it re-raises as TpuAsyncSinkError so
-    the session's checked replay re-attributes it to the originating op
-    (docs/async-execution.md). Shared by the query-level lifted sink and
-    the per-partition DeviceToHostExec path. `keep_encoded`: a dictionary
-    column comes back as codes + dictionary (`HostDictionaryColumn`), for
-    a consumer that takes it so (a file writer); otherwise it is expanded
-    to values here, as a result's rows need."""
-    from spark_rapids_tpu.columnar.batch import to_host_many
+def sink_fetch_many(run, keep_encoded: bool = False):
+    """The device half of a grouped sink download, with async error
+    attribution: the ONE place a query is allowed to block on device
+    values. A device-rooted error surfacing here under issue-ahead
+    execution belongs to some upstream dispatch, not to the transfer — it
+    re-raises as TpuAsyncSinkError so the session's checked replay
+    re-attributes it to the originating op (docs/async-execution.md).
+    Returns `columnar/batch.fetch_many`'s finish: the call that rebuilds
+    the host batches, pure host work that holds no device array.
+    `keep_encoded`: a dictionary column comes back as codes + dictionary
+    (`HostDictionaryColumn`), for a consumer that takes it so (a file
+    writer); otherwise it is expanded to values at the finish, as a
+    result's rows need."""
+    from spark_rapids_tpu.columnar.batch import fetch_many
     from spark_rapids_tpu.engine.async_exec import async_enabled
     from spark_rapids_tpu.engine.retry import (
         TpuAsyncSinkError,
@@ -134,7 +136,7 @@ def sink_download_many(run, keep_encoded: bool = False):
                      keep_encoded=keep_encoded)
     try:
         return with_retry(
-            lambda: to_host_many(run, keep_encoded=keep_encoded),
+            lambda: fetch_many(run, keep_encoded=keep_encoded),
             site="transfer.download")
     except Exception as e:  # noqa: BLE001 — attribution boundary
         typed = as_typed_error(e)
@@ -144,6 +146,12 @@ def sink_download_many(run, keep_encoded: bool = False):
         raise TpuAsyncSinkError(
             f"device error surfaced at the sink download: {typed}"
         ) from e
+
+
+def sink_download_many(run, keep_encoded: bool = False):
+    """`sink_fetch_many` and its finish in one call: the query-level
+    lifted sink, which holds no permit to give back in between."""
+    return sink_fetch_many(run, keep_encoded)()
 
 
 # ---------------------------------------------------------------------------
@@ -197,9 +205,42 @@ class HostToDeviceExec(TpuExec):
 
 
 class DeviceToHostExec(PhysicalExec):
-    """Download device batches to host and release the semaphore (reference:
-    GpuColumnarToRowExec releases at batch end, GpuColumnarToRowExec.scala:109;
-    GpuBringBackToHost.scala:52)."""
+    """Download device batches to host and release the semaphore
+    (reference: GpuColumnarToRowExec copies a batch to the host, releases
+    the semaphore, and only then iterates its rows; the task takes it
+    again at its next device use: GpuColumnarToRowExec.scala:62-155,
+    release :109; GpuBringBackToHost.scala:52).
+
+    The child is drained in bounded runs and each run downloaded with ONE
+    grouped transfer (per-batch downloads cost one fence each); the run
+    size ramps 1 -> 32. A run's download is two halves: the fetch
+    (`sink_fetch_many`: pack, wait, transfer: all that touches the
+    device) and the finish (host columns rebuilt from the fetched bytes:
+    numpy on one thread, 22-31 ms a 24-28 MB fence on the chip's host).
+    The permit is for the first half. The child is pulled ONE batch
+    ahead of the run, under the permit (a child with buffered device
+    work, a coalesce's last concat or an aggregate's emit, does it there,
+    never after a release), so a run is fetched knowing whether the child
+    has ended. If it has, the run's device batches are dropped after the
+    fetch, the task gives the permit back, and the run is finished
+    without it, while another task uploads; if not, the run is finished
+    under the permit as before, and the batch pulled ahead (resident
+    beside the run meanwhile: one batch more than before, where a
+    partition has more than one) opens the next run. So the
+    `_download_finish` of a partition's LAST run never holds the chip,
+    and whatever the consumer does with the host batches after it reaches
+    the device only through a path that acquires (`HostToDeviceExec`, a
+    scan's upload). The `finally` is the backstop for an exception and
+    for a consumer that stops early.
+
+    Early exit (LIMIT): a run's host batches are handed over one child
+    batch later than the run holds: the first after at most TWO child
+    batches and one fetch (it was one and one before the look-ahead).
+
+    The pull ahead comes before the run's `DeviceToHost` span opens, not
+    inside it: a child keeps spans open across its yields
+    (`coalesce-concat`), and a span may only close while it is the
+    innermost."""
 
     placement = "cpu"  # output is host data
 
@@ -225,28 +266,25 @@ class DeviceToHostExec(PhysicalExec):
         def factory(pidx: int) -> Iterator[HostColumnarBatch]:
             sem = TpuSemaphore.get()
             try:
-                # drain in bounded runs and download each run with ONE
-                # grouped transfer (per-batch downloads cost one fence
-                # each). The run size
-                # ramps 1 -> 32 so an early-exit consumer (LIMIT) still
-                # gets its first batch after one child batch + one
-                # download, while steady-state pays one fence per 32.
-                run: list = []
-                run_bytes = 0
+                child = iter(child_pb.iterator(pidx))
                 run_cap = 1
-                for db in child_pb.iterator(pidx):
-                    run.append(db)
-                    run_bytes += db.device_memory_size()
-                    if len(run) >= run_cap or run_bytes > (128 << 20):
-                        with M.trace_range("DeviceToHost", total_time):
-                            hbs = sink_download_many(run, keep_encoded)
-                        yield from hbs
-                        run, run_bytes = [], 0
-                        run_cap = min(run_cap * 2, 32)
-                if run:
+                ahead = next(child, None)
+                while ahead is not None:
+                    run, run_bytes = [ahead], ahead.device_memory_size()
+                    ahead = next(child, None)
+                    while ahead is not None and len(run) < run_cap \
+                            and run_bytes <= (128 << 20):
+                        run.append(ahead)
+                        run_bytes += ahead.device_memory_size()
+                        ahead = next(child, None)
                     with M.trace_range("DeviceToHost", total_time):
-                        hbs = sink_download_many(run, keep_encoded)
+                        finish = sink_fetch_many(run, keep_encoded)
+                        del run
+                        if ahead is None:  # the child has ended
+                            sem.release_if_necessary(current_task_id())
+                        hbs = finish()
                     yield from hbs
+                    run_cap = min(run_cap * 2, 32)
             finally:
                 sem.release_if_necessary(current_task_id())
 

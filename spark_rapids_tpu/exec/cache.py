@@ -10,6 +10,12 @@ which is the difference between host-link bandwidth and HBM bandwidth.
 The cache is keyed by the logical CacheRelation node (weakly, so dropping
 the DataFrame frees the HBM copies) and segregated by engine placement:
 the CPU oracle caches host batches, the TPU exec caches device batches.
+
+What the device cache shows of itself (docs/observability.md): a
+`cache.materialize` span a partition on the first execution, a
+`cache.serve` span a batch handed out on every execution, the process-wide
+`cachedBatchesServed` / `cacheRestoredBatches` (a batch that had left the
+device and was brought back) and the gauge `resident_bytes()`.
 """
 
 from __future__ import annotations
@@ -18,6 +24,7 @@ import threading
 import weakref
 from typing import Dict, List
 
+from spark_rapids_tpu.columnar.encoded import is_encoded
 from spark_rapids_tpu.exec.base import (
     CpuExec,
     ExecContext,
@@ -26,7 +33,11 @@ from spark_rapids_tpu.exec.base import (
     TpuExec,
     count_output,
 )
+from spark_rapids_tpu.exec.transitions import current_task_id
+from spark_rapids_tpu.memory.semaphore import TpuSemaphore
+from spark_rapids_tpu.obs import trace as OBS
 from spark_rapids_tpu.ops.base import AttributeReference
+from spark_rapids_tpu.utils import metrics as M
 
 _LOCK = threading.Lock()
 _DEVICE_CACHE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
@@ -53,6 +64,13 @@ def cached_row_count(logical_node):
                 return None  # device-resident count: not worth a sync here
             total += n
     return total
+
+
+def is_materialized(logical_node) -> bool:
+    """Whether either engine holds the relation now (plan/signature.py:
+    a plan analyzed before it was is not the plan of an action after)."""
+    with _LOCK:
+        return logical_node in _DEVICE_CACHE or logical_node in _HOST_CACHE
 
 
 def cached_host_partitions(logical_node):
@@ -83,6 +101,29 @@ def cached_device_partition_rows(logical_node):
             rows.append(n)
         out.append(rows)
     return out
+
+
+def cached_device_bytes(logical_node):
+    """Registered bytes of a device-cached relation's buffers, whatever
+    tier each is on (serving brings every one back), or None before it is
+    materialized: what the resource analyzer books for the relation."""
+    with _LOCK:
+        parts = _DEVICE_CACHE.get(logical_node)
+    if parts is None:
+        return None
+    return sum(b.size for part in parts for b in part)
+
+
+def resident_bytes() -> int:
+    """Bytes of every device-cached relation's buffers that are on the
+    device now: what was materialized, less what the spill framework took
+    away and has not brought back (utils/metrics.cache_resident_bytes)."""
+    from spark_rapids_tpu.memory.spill import StorageTier
+
+    with _LOCK:
+        bufs = [b for parts in _DEVICE_CACHE.values()
+                for part in parts for b in part]
+    return sum(b.size for b in bufs if b.tier is StorageTier.DEVICE)
 
 
 def invalidate(logical_node) -> None:
@@ -178,13 +219,29 @@ class TpuCachedScanExec(_CachedScanBase, TpuExec):
 
             def mat(pidx: int):
                 out = []
-                for b in child_pb.iterator(pidx):
-                    n = b.host_rows() if hasattr(b, "host_rows") else b.num_rows
-                    if n > 0:
-                        # cache entries OUTLIVE the registering query:
-                        # a later cancellation must not free them
-                        out.append(fw.add_device_batch(
-                            b, scope_to_query=False))
+                with OBS.span("cache.materialize", partition=pidx):
+                    try:
+                        rows = dict_columns = 0
+                        for b in child_pb.iterator(pidx):
+                            n = b.host_rows() if hasattr(b, "host_rows") \
+                                else b.num_rows
+                            if n > 0:
+                                dict_columns = sum(map(is_encoded, b.columns))
+                                # cache entries OUTLIVE the registering
+                                # query: a later cancellation must not
+                                # free them
+                                out.append(fw.add_device_batch(
+                                    b, scope_to_query=False))
+                                rows += n
+                    except BaseException:
+                        # a failed attempt's buffers belong to no query
+                        # and no relation: nothing else would free them
+                        _free_buffers(out)
+                        raise
+                    OBS.annotate(
+                        rows=rows, batches=len(out),
+                        bytes=sum(b.size for b in out),
+                        columns=len(self.output), dict_columns=dict_columns)
                 return out
 
             from spark_rapids_tpu.engine.scheduler import run_job_or_serial
@@ -205,7 +262,15 @@ class TpuCachedScanExec(_CachedScanBase, TpuExec):
         def factory(pidx: int):
             def gen():
                 for buf in cached[pidx]:
-                    yield fw.get_device_batch(buf)
+                    # a cached batch is a task's first data on the device,
+                    # as an upload is a scan's: the admission permit is
+                    # taken here, or no task of a cached query would hold one
+                    TpuSemaphore.get().acquire_if_necessary(current_task_id())
+                    with OBS.span("cache.serve", bytes=buf.size):
+                        batch, restored = fw.fetch_device_batch(buf)
+                        M.record_cached_batch_served(restored)
+                        OBS.annotate(restored=int(restored))
+                    yield batch
             return count_output(self.metrics, gen())
 
         return PartitionedBatches(len(cached), factory)

@@ -463,7 +463,11 @@ class SpillFramework:
             return self._read_bytes(buf)
 
     def get_device_batch(self, buf: SpillableBuffer) -> ColumnarBatch:
-        """Materialize on device, re-uploading if spilled (reference:
+        return self.fetch_device_batch(buf)[0]
+
+    def fetch_device_batch(self, buf: SpillableBuffer):
+        """(the batch on the device, whether this call had to bring it
+        back): materialize on device, re-uploading if spilled (reference:
         RapidsBufferCatalog.acquireBuffer + getColumnarBatch climbing tiers).
 
         buf.lock is NOT held across ensure_headroom/upload (cross-buffer
@@ -474,7 +478,7 @@ class SpillFramework:
                 # store-held batches are multi-read by construction: they
                 # must never carry the consume-once donation proof
                 buf.device_batch.owned = False
-                return buf.device_batch
+                return buf.device_batch, False
             data = self._read_bytes(buf)
         # outside the lock: spill others + upload
         self.watermark.ensure_headroom(len(data))
@@ -483,9 +487,9 @@ class SpillFramework:
         with buf.lock:
             if buf.device_batch is not None:  # lost the race
                 buf.device_batch.owned = False
-                return buf.device_batch
+                return buf.device_batch, False
             if buf.tier is None:  # freed meanwhile
-                return batch
+                return batch, True
             # promote back to the device tier so later accesses are free
             store = self._store_for(buf.tier)
             store.untrack(buf)
@@ -499,7 +503,7 @@ class SpillFramework:
                 buf.disk_path = None
             buf.tier = StorageTier.DEVICE
             self.device_store.track(buf)
-            return batch
+            return batch, True
 
     def get_host_batch(self, buf: SpillableBuffer) -> HostColumnarBatch:
         """Materialize on host without touching the device tier placement."""

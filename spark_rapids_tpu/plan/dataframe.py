@@ -330,7 +330,12 @@ class DataFrame:
         InMemoryTableScan path)."""
         if isinstance(self._plan, L.CacheRelation):
             return self
-        return self._with_plan(L.CacheRelation(self._plan))
+        # the optimizer never prunes below a cache (plan/optimizer._cache:
+        # the node is the cache's key), so what the relation materializes
+        # is pruned here, once, to what it holds: `select(...).cache()`
+        # scans the selected columns, not the file's
+        return self._with_plan(L.CacheRelation(
+            self.session._optimized(self._plan)))
 
     persist = cache
 

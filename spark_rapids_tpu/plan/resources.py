@@ -1377,6 +1377,7 @@ class _Analyzer:
 
     def _cached_scan(self, node) -> AbsState:
         from spark_rapids_tpu.exec.cache import (
+            cached_device_bytes,
             cached_device_partition_rows,
             cached_host_partitions,
         )
@@ -1406,8 +1407,12 @@ class _Analyzer:
             # its stats) IS the cached relation's
             st = self.visit(node.children[0])
         if node.placement == "tpu":
-            # the materialized relation is device-resident (spillable)
-            self._resident(node, st.total_bytes.hi, st, Interval.exact(0))
+            # the materialized relation is device-resident (spillable):
+            # once it is, at the bytes its buffers were registered with;
+            # before, at the child's estimate of what it will hold
+            registered = cached_device_bytes(node.logical_node)
+            self._resident(node, st.total_bytes.hi if registered is None
+                           else registered, st, Interval.exact(0))
         return st
 
     def _unknown(self, node) -> AbsState:

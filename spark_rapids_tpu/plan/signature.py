@@ -125,7 +125,15 @@ def _canon_node(p: "L.LogicalPlan", idmap: Dict[int, int],
         # a cached relation's materialization is keyed by node identity
         # (exec/cache.py); identity mode must carry it so two different
         # cached datasets with identical shapes never share a plan
-        ident = f";cache={id(p)}" if identity else ""
+        ident = ""
+        if identity:
+            # ... and whether it is materialized: a plan made before the
+            # relation was carries the child's estimate of it (rows, HBM
+            # booked, admission weight, broadcast choices) and must not
+            # be the plan of the actions that find it held
+            from spark_rapids_tpu.exec.cache import is_materialized
+
+            ident = f";cache={id(p)};held={is_materialized(p)}"
         return f"{name}({child}{ident})"
     # generic node: scalar/expression state from __dict__ (children
     # excluded — they canonicalize recursively below)

@@ -430,6 +430,40 @@ def compacted_batch_count() -> int:
     return _COMPACTED_BATCHES.value
 
 
+# the device-resident relation cache (exec/cache.py): batches a cached
+# scan handed to its consumer, and of those the ones that had left the
+# device (spilled to host or disk) and were uploaded again to be served.
+# cacheResidentBytes is a gauge, not a count: the bytes of cached batches
+# on the device at the moment it is read
+CACHED_BATCHES_SERVED = "cachedBatchesServed"
+CACHE_RESTORED_BATCHES = "cacheRestoredBatches"
+CACHE_RESIDENT_BYTES = "cacheResidentBytes"
+_CACHED_BATCHES_SERVED = Metric(CACHED_BATCHES_SERVED)
+_CACHE_RESTORED_BATCHES = Metric(CACHE_RESTORED_BATCHES)
+
+
+def record_cached_batch_served(restored: bool) -> None:
+    _CACHED_BATCHES_SERVED.add(1)
+    _note(CACHED_BATCHES_SERVED, 1)
+    if restored:
+        _CACHE_RESTORED_BATCHES.add(1)
+        _note(CACHE_RESTORED_BATCHES, 1)
+
+
+def cached_batches_served_count() -> int:
+    return _CACHED_BATCHES_SERVED.value
+
+
+def cache_restored_batch_count() -> int:
+    return _CACHE_RESTORED_BATCHES.value
+
+
+def cache_resident_bytes() -> int:
+    from spark_rapids_tpu.exec.cache import resident_bytes
+
+    return resident_bytes()
+
+
 # ---------------------------------------------------------------------------
 # Fault-tolerance accounting (engine/retry.py increments; queries snapshot
 # before/after, same pattern as the dispatch counter above)

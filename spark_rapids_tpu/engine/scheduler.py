@@ -37,6 +37,11 @@ property task RETRY already requires), so racing two attempts is safe:
 the first completion wins and the loser is cancelled through a
 TASK-scoped CancelToken (engine/cancel.py) that unwinds just that
 attempt, never the query. Metrics: speculativeTasks / speculativeWins.
+A task's elapsed, and a finished sibling's, is its time AT WORK
+(engine/pause_clock.AtWork): from the moment a pool thread picked it up,
+less the time programs were being built and less the time the whole
+process stood still. The task timeout stays on the wall: it is a promise
+to a caller, not a judgement of a task.
 """
 
 from __future__ import annotations
@@ -47,7 +52,7 @@ import threading
 from typing import Callable, Iterator, List, Optional, TypeVar
 
 from spark_rapids_tpu.engine import cancel as CX
-from spark_rapids_tpu.engine import compile_clock
+from spark_rapids_tpu.engine import pause_clock
 from spark_rapids_tpu.engine import retry as R
 from spark_rapids_tpu.exec.transitions import current_task_id, set_task_id
 from spark_rapids_tpu.memory.semaphore import TpuSemaphore
@@ -102,30 +107,25 @@ class _Attempt:
     """One racing execution attempt of a partition task (primary or
     speculative duplicate), with its task-scoped cancel token."""
 
-    __slots__ = ("future", "token", "submit_ns", "started_ns",
-                 "speculative", "_compile_ns0")
+    __slots__ = ("future", "token", "work", "speculative")
 
     def __init__(self, future: "cf.Future", token: "CX.CancelToken",
-                 submit_ns: int, speculative: bool):
+                 speculative: bool):
         self.future = future
         self.token = token
-        self.submit_ns = submit_ns
-        # stamped by the task itself when a pool thread PICKS IT UP:
+        # set by the task itself when a pool thread PICKS IT UP:
         # straggler math must never count queue wait as runtime (16 tasks
         # on an 8-thread pool would read the whole second wave as slow)
-        self.started_ns: Optional[int] = None
+        self.work: Optional[pause_clock.AtWork] = None
         self.speculative = speculative
-        self._compile_ns0 = 0
 
     def mark_started(self, now_ns: int) -> None:
-        self._compile_ns0 = compile_clock.compiling_ns(now_ns)
-        self.started_ns = now_ns
+        self.work = pause_clock.AtWork(now_ns)
 
     def runtime_ns(self, now_ns: int) -> int:
-        """Time since a pool thread picked the task up, less the time
-        programs were being built: a cold program is not a straggler."""
-        compiling = compile_clock.compiling_ns(now_ns) - self._compile_ns0
-        return now_ns - self.started_ns - compiling
+        """Time at work since a pool thread picked the task up: a cold
+        program is not a straggler, and neither is a stopped process."""
+        return self.work.ns(now_ns)
 
 
 class TaskScheduler:
@@ -361,10 +361,8 @@ class TaskScheduler:
         """Submit one racing attempt with its own task-scoped token, so
         the losing duplicate can be cancelled without touching the query
         token (which is terminal for the whole query)."""
-        from spark_rapids_tpu.obs.trace import wall_ns
-
         token = CX.CancelToken()
-        attempt = _Attempt(None, token, wall_ns(), speculative)
+        attempt = _Attempt(None, token, speculative)
         cctx = contextvars.copy_context()
         attempt.future = pool.submit(cctx.run, self._run_task_scoped, p,
                                      fn, token, speculative, attempt)
@@ -407,6 +405,7 @@ class TaskScheduler:
         task retry already requires of it."""
         from spark_rapids_tpu.obs.trace import wall_ns
 
+        pause_clock.start()
         tok = CX.current_token()
         # straggler detection needs a steady cadence even with no cancel
         # token to poll: the idle long-wait would sleep through the whole
@@ -452,8 +451,9 @@ class TaskScheduler:
                     if winner is not None:
                         a, res = winner
                         results[p] = res
-                        finished_ns.append(
-                            now - (a.started_ns or a.submit_ns))
+                        # on the straggler's own clock: a sibling that
+                        # finished across a pause must not inflate the p95
+                        finished_ns.append(a.runtime_ns(now))
                         if a.speculative:
                             M.record_speculative_win()
                         self._cancel_losers(al, a)
@@ -491,7 +491,7 @@ class TaskScheduler:
                             a0 = al[0]
                             # a still-QUEUED task is not a straggler — a
                             # duplicate would queue right behind it
-                            if a0.started_ns is None or \
+                            if a0.work is None or \
                                     not a0.future.running():
                                 continue
                             if a0.runtime_ns(now) < thr_ns:

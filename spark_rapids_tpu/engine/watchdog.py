@@ -26,11 +26,12 @@ the set of in-flight dispatch registrations on a fixed cadence:
 The timeout is cost-calibrated: `watchdog.dispatchTimeoutMs` when set,
 else 8x the admission-time CostModel prediction of the query's task wall
 (QueryContext.predicted_work_ns, obs/calibrate.py), else a 30s cold-
-start default. Time during which a program is being built is not silence
-(engine/compile_clock.py): a cold program's first dispatch may compile
-for minutes. The daemon is deliberately CONTEXT-FREE (it acts on tokens
-captured at registration, never on ambient state), uses only timed
-waits, and is torn down with the shared session runtime.
+start default. Silence is time at work (engine/pause_clock.AtWork): time
+during which a program is being built is not silence (a cold program's
+first dispatch may compile for minutes), and neither is time during which
+the whole process stood still. The daemon is deliberately CONTEXT-FREE
+(it acts on tokens captured at registration, never on ambient state),
+uses only timed waits, and is torn down with the shared session runtime.
 """
 
 from __future__ import annotations
@@ -40,7 +41,7 @@ import threading
 from typing import Dict, Optional
 
 from spark_rapids_tpu import conf as C
-from spark_rapids_tpu.engine import compile_clock
+from spark_rapids_tpu.engine import pause_clock
 from spark_rapids_tpu.obs.trace import wall_ns
 from spark_rapids_tpu.utils import metrics as M
 
@@ -61,8 +62,8 @@ _CURRENT_ENTRY: contextvars.ContextVar = contextvars.ContextVar(
 class DispatchEntry:
     """One in-flight dispatch attempt under watch."""
 
-    __slots__ = ("site", "token", "ctx", "start_ns", "timeout_ms",
-                 "released", "escalated", "_cvar_token", "_compile_ns0")
+    __slots__ = ("site", "token", "ctx", "work", "timeout_ms",
+                 "released", "escalated", "_cvar_token")
 
     def __init__(self, site: str, token, ctx, start_ns: int,
                  timeout_ms: float):
@@ -71,21 +72,20 @@ class DispatchEntry:
         self.ctx = ctx              # owning QueryContext (or None): the
         # daemon attributes its kills here — it runs with NO ambient
         # context of its own, by design
-        self.start_ns = start_ns
+        # a program's first dispatch includes its build (or the wait for
+        # another thread's build of it), which is not silence; nor is a
+        # stopped process
+        self.work = pause_clock.AtWork(start_ns)
         self.timeout_ms = timeout_ms
         # set by the watchdog when the entry is classified wedged: the
         # cooperative release every wait-point of this attempt polls
         self.released = threading.Event()
         self.escalated = False
         self._cvar_token = None
-        # a program's first dispatch includes its build (or the wait for
-        # another thread's build of it), which is not silence
-        self._compile_ns0 = compile_clock.compiling_ns(start_ns)
 
     def silent_ms(self, now_ns: int) -> float:
-        """Wall time in flight, less the time programs were being built."""
-        compiling = compile_clock.compiling_ns(now_ns) - self._compile_ns0
-        return (now_ns - self.start_ns - compiling) / 1e6
+        """Time in flight with nothing to excuse it."""
+        return self.work.ns(now_ns) / 1e6
 
 
 class DispatchWatchdog:
@@ -179,6 +179,7 @@ class DispatchWatchdog:
             entry._cvar_token = (self._seq,
                                  _CURRENT_ENTRY.set(entry))
         self._ensure_thread()
+        pause_clock.start()
         return entry
 
     def _deregister(self, entry: DispatchEntry) -> None:
@@ -193,37 +194,39 @@ class DispatchWatchdog:
     # -- the daemon ----------------------------------------------------------
     def _loop(self) -> None:
         while not self._stop.wait(self.poll_ms / 1000.0):
-            now = wall_ns()
-            with self._mu:
-                entries = list(self._entries.values())
-            for entry in entries:
-                silent_ms = entry.silent_ms(now)
-                if silent_ms < entry.timeout_ms:
-                    continue
-                if not entry.released.is_set():
-                    # first tier: classify wedged + cooperative release —
-                    # wait-points polling the event raise a retryable
-                    # TpuDispatchWedged and the combinators re-dispatch
-                    entry.released.set()
-                    with self._mu:
-                        self._wedged_sites[entry.site] = \
-                            self._wedged_sites.get(entry.site, 0) + 1
-                    M.record_watchdog_kill()
-                    if entry.ctx is not None:
-                        # per-query attribution: the daemon carries no
-                        # ambient context, so _note cannot route this
-                        entry.ctx.add(M.WATCHDOG_KILLS, 1)
-                elif (not entry.escalated
-                      and entry.token is not None
-                      and silent_ms >= entry.timeout_ms
-                      * _ESCALATE_MULTIPLE):
-                    # second tier: no cooperative wait-point picked up the
-                    # release — fire the owning query's token so the rest
-                    # of the query unwinds and reclaims
-                    entry.escalated = True
-                    entry.token.cancel(
-                        f"watchdog: dispatch wedged at {entry.site} "
-                        f"({silent_ms:.0f}ms silent)")
+            self._scan(wall_ns())
+
+    def _scan(self, now: int) -> None:
+        with self._mu:
+            entries = list(self._entries.values())
+        for entry in entries:
+            silent_ms = entry.silent_ms(now)
+            if silent_ms < entry.timeout_ms:
+                continue
+            if not entry.released.is_set():
+                # first tier: classify wedged + cooperative release —
+                # wait-points polling the event raise a retryable
+                # TpuDispatchWedged and the combinators re-dispatch
+                entry.released.set()
+                with self._mu:
+                    self._wedged_sites[entry.site] = \
+                        self._wedged_sites.get(entry.site, 0) + 1
+                M.record_watchdog_kill()
+                if entry.ctx is not None:
+                    # per-query attribution: the daemon carries no
+                    # ambient context, so _note cannot route this
+                    entry.ctx.add(M.WATCHDOG_KILLS, 1)
+            elif (not entry.escalated
+                  and entry.token is not None
+                  and silent_ms >= entry.timeout_ms
+                  * _ESCALATE_MULTIPLE):
+                # second tier: no cooperative wait-point picked up the
+                # release — fire the owning query's token so the rest
+                # of the query unwinds and reclaims
+                entry.escalated = True
+                entry.token.cancel(
+                    f"watchdog: dispatch wedged at {entry.site} "
+                    f"({silent_ms:.0f}ms silent)")
 
     # -- introspection -------------------------------------------------------
     def inflight_count(self) -> int:

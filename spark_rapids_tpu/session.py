@@ -333,9 +333,12 @@ class TpuSession:
         FI.disable_global()
         # the hung-dispatch watchdog daemon dies with the shared runtime
         # (its in-flight registry is meaningless across sessions)
+        from spark_rapids_tpu.engine import pause_clock
         from spark_rapids_tpu.engine.watchdog import DispatchWatchdog
 
         DispatchWatchdog.shutdown()
+        # ... and with it the heartbeat both it and speculation read
+        pause_clock.shutdown()
         # symmetric with the semaphore/spill singletons: a later session
         # must size its budget from ITS conf — without this, a test
         # session's hbm.sizeOverride leaks into every session that

@@ -327,15 +327,17 @@ def test_compile_clock_sees_backend_compile():
 
 
 def test_watchdog_and_scheduler_subtract_compile_time(monkeypatch):
-    from spark_rapids_tpu.engine import compile_clock
+    from spark_rapids_tpu.engine import compile_clock, pause_clock
     from spark_rapids_tpu.engine.scheduler import _Attempt
     from spark_rapids_tpu.engine.watchdog import DispatchEntry
 
     s = 1_000_000_000
+    pause_clock.shutdown()  # no heartbeat: the pause clock reads its total
+    monkeypatch.setattr(pause_clock, "_total_ns", 0)
     monkeypatch.setattr(compile_clock, "_total_ns", 0)
     monkeypatch.setattr(compile_clock, "_in_flight", 0)
     entry = DispatchEntry("t", None, None, 10 * s, 30000.0)
-    attempt = _Attempt(None, None, 10 * s, False)
+    attempt = _Attempt(None, None, False)
     attempt.mark_started(10 * s)
     # a compile (on any thread) that began 1 s after the dispatch and is
     # still running
@@ -348,3 +350,8 @@ def test_watchdog_and_scheduler_subtract_compile_time(monkeypatch):
     monkeypatch.setattr(compile_clock, "_total_ns", 84 * s)
     assert entry.silent_ms(100 * s) == pytest.approx(6000.0)
     assert attempt.runtime_ns(100 * s) == 6 * s
+    # ... and the whole process stood still for 4 of those
+    # (engine/pause_clock.py; tests/test_pause_clock.py has the clock)
+    monkeypatch.setattr(pause_clock, "_total_ns", 4 * s)
+    assert entry.silent_ms(100 * s) == pytest.approx(2000.0)
+    assert attempt.runtime_ns(100 * s) == 2 * s

@@ -543,7 +543,9 @@ class _Watch:
     with whether the task it ran for held its permit just then. A reader
     thread has no task id of its own: `_read_host_iter` is made on the
     task's thread, which is where the id is taken, and pulled wherever
-    the prefetch depth puts it."""
+    the prefetch depth puts it. Threads are noted as objects, kept alive
+    here: a thread's ident is free to go to the next thread once it has
+    ended, a reader's to a pool thread started late."""
 
     def __init__(self, monkeypatch, before_upload=None):
         self.packs, self.uploads = [], []
@@ -567,7 +569,8 @@ class _Watch:
             held = TpuSemaphore.get().held_by(task)
             staged = stage_upload(hb)
             held = held or TpuSemaphore.get().held_by(task)
-            watch.packs.append((task, held, threading.get_ident(), staged))
+            watch.packs.append((task, held, threading.current_thread(),
+                                staged))
             return staged
 
         def watched_upload(staged):
@@ -578,7 +581,7 @@ class _Watch:
             watch.uploads.append(
                 (current_task_id(),
                  TpuSemaphore.get().held_by(current_task_id()),
-                 threading.get_ident(), staged, bytes_before))
+                 threading.current_thread(), staged, bytes_before))
             return batch
 
         monkeypatch.setattr(SCAN.TpuFileScanExec, "_read_host_iter",
@@ -614,14 +617,14 @@ def test_arrow_path_packs_no_split_under_a_held_permit(
     _assert_every_row_once(rows, paths)
     # a split a task, packed once, and never while its task held a permit
     assert len(watch.packs) == 3 and len(watch.uploads) == 3
-    assert not any(held for _task, held, _tid, _staged in watch.packs)
+    assert not any(held for _task, held, _thread, _staged in watch.packs)
     assert len({task for task, *_ in watch.packs}) == 3
     # the transfer is what the permit covers: on the task's own thread
     assert all(held for _task, held, *_ in watch.uploads)
     assert {task for task, *_ in watch.uploads} == \
         {task for task, *_ in watch.packs}
-    task_threads = {tid for _task, _held, tid, *_ in watch.uploads}
-    pack_threads = {tid for _task, _held, tid, _staged in watch.packs}
+    task_threads = {thread for _task, _held, thread, *_ in watch.uploads}
+    pack_threads = {thread for _task, _held, thread, _staged in watch.packs}
     if depth:
         assert not pack_threads & task_threads  # the reader's
     else:

@@ -542,7 +542,12 @@ AGG_COMPACT_SYNC = _conf("rapids.tpu.engine.aggCompactSync").doc(
     "the exchange's zero-copy piece cap; bigger batches and string "
     "min/max buffers still compact. 'auto' additionally requires the "
     "measured backend fence cost to clear a fixed ~5 ms threshold and "
-    "the map partition count to stay under aggLazyMaxPartitions."
+    "the map partition count to stay under aggLazyMaxPartitions. An "
+    "aggregate with no grouping key does not consult this key: its "
+    "partial is one row whatever the device finds, so it has neither a "
+    "sync to save nor lanes to pad, and runs as one program a batch under "
+    "every value (sum/count/min/max/avg over fixed-width buffers; "
+    "ungroupedAggBatches counts them)."
 ).check(lambda v: None if v in ("auto", "always", "never")
         else "must be one of auto|always|never").string("auto")
 

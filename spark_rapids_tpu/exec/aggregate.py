@@ -18,7 +18,10 @@ instead of cudf's hash-based groupby. One jitted program per (expression
 fingerprint, capacity bucket) covers eval + grouping + every reduction. Host
 syncs per batch: the group count; with a string min/max aggregate, also a
 max-string-length read (sizes the static chunk count) and the string
-gather's byte-total read in _assemble.
+gather's byte-total read in _assemble. An aggregate with NO grouping key
+has no grouping to do and no count to ask for: its update is one program
+whose output is the partial's one row (`_build_ungrouped_update_kernel`),
+no sync at all.
 """
 
 from __future__ import annotations
@@ -80,6 +83,17 @@ COMPLETE = "complete"
 # 2026-09-26) stays below it, a remote backend (tens of ms) clears it. A fixed threshold, not a modeled
 # compute-saved comparison; conf 'always'/'never' override it either way.
 LAZY_FENCE_THRESHOLD_MS = 5.0
+
+# The reduce ops the ungrouped update program takes, and the merge ops it
+# can hand its one row to. What has to hold of each: a batch with no live
+# row leaves the buffer's empty state (a sum / min / max lane invalid, a
+# count lane 0), and the merge op treats that state as its identity, so a
+# row of empty states merges exactly as no row does and an input of
+# nothing but such rows still ends in `_emit`'s default row (sum NULL,
+# count 0). first / last / pct / unmergeable are not here: they stay on
+# the grouped path.
+UNGROUPED_UPDATE_OPS = frozenset({"sum", "count", "min", "max"})
+UNGROUPED_MERGE_OPS = frozenset({"sum", "min", "max"})
 
 
 class AggSpec(NamedTuple):
@@ -343,6 +357,59 @@ class TpuHashAggregateExec(_HashAggregateBase, TpuExec):
 
         return get_or_build(key, build,
                             donate_argnums=(0,) if donate else ())
+
+    def _ungrouped_ok(self) -> bool:
+        """Whether an update of this aggregate is the ungrouped program:
+        no grouping key, every buffer fixed-width, every op one whose
+        empty state its merge takes as the identity. Read off the plan
+        alone: `aggCompactSync` trades a sync against padded lanes, and
+        this path has neither."""
+        return (not self.grouping and self._lazy_ok()
+                and all(op in UNGROUPED_UPDATE_OPS
+                        for op, _e, _dt in self._update_ops())
+                and all(op in UNGROUPED_MERGE_OPS
+                        for op, _dt in self._merge_ops()))
+
+    def _build_ungrouped_update_kernel(self, input_attrs, input_exprs,
+                                       op_names, filters):
+        """The whole partial of a batch of an aggregate with no grouping
+        key: the collapsed filters and projections, each buffer reduced
+        over the live lanes as `RK.segment_reduce` reduces one group
+        (`RK.reduce_all`), written into lane 0 of a one-row column. No
+        group ids, no assembly, and a row count the host knows: 1."""
+        from spark_rapids_tpu.engine.jit_cache import get_or_build
+
+        bound_inputs = bind_all(input_exprs, input_attrs)
+        bound_filters = bind_all(filters, input_attrs)
+        buffer_npdts = tuple(physical_np_dtype(a.data_type)
+                             for a in self.buffer_attrs)
+        # a buffer that cannot be NULL (a count) holds its op's value over
+        # no lane, 0, where a nullable one is invalid; said of the buffer
+        # and not of the op because the run-aware collapse counts by
+        # summing run lengths
+        never_null = tuple(not a.nullable for a in self.buffer_attrs)
+        key = ("agg_ungrouped_update", buffer_npdts, never_null,
+               tuple(zip(op_names,
+                         (e.fingerprint() for e in bound_inputs))),
+               tuple(f.fingerprint() for f in bound_filters))
+
+        def build():
+            def agg_ungrouped_update(cols, num_rows):
+                _, in_cols, live, _ = _update_inputs(
+                    cols, num_rows, (), bound_inputs, bound_filters)
+                lane0 = jnp.arange(bucket_capacity(1)) == 0
+                outs = []
+                for op, cv, npdt, nn in zip(op_names, in_cols,
+                                            buffer_npdts, never_null):
+                    r, has = RK.reduce_all(op, cv.data, cv.validity & live)
+                    v = lane0 if nn else lane0 & has
+                    outs.append((jnp.where(v, r.astype(npdt),
+                                           jnp.zeros((), npdt)), v))
+                return outs
+
+            return jax.jit(agg_ungrouped_update)
+
+        return get_or_build(key, build)
 
     def _build_dense_update_kernel(self, input_attrs, key_exprs,
                                    input_exprs, op_names, filters,
@@ -664,6 +731,13 @@ class TpuHashAggregateExec(_HashAggregateBase, TpuExec):
                 from spark_rapids_tpu.utils.devprobe import fence_cost_ms
                 update_lazy = fence_cost_ms() >= LAZY_FENCE_THRESHOLD_MS
 
+        # An aggregate with no grouping key has neither a sync to save nor
+        # lanes to pad: its update is one program whose output is one row
+        # (`_build_ungrouped_update_kernel`), whatever the policy above.
+        ungrouped = do_update and self.placement == "tpu" and \
+            self._ungrouped_ok()
+        ungrouped_kernel = [None]
+
         def count_arg(b: ColumnarBatch):
             n = b.num_rows
             if isinstance(n, (int, np.integer)):
@@ -889,6 +963,38 @@ class TpuHashAggregateExec(_HashAggregateBase, TpuExec):
                         local = self._dense_batch(
                             outs, num_groups, enc_plan.key_dicts,
                             enc_plan.buf_dicts)
+                        running = local if running is None else \
+                            merge(concat_batches([running, local]))
+                        continue
+                    if ungrouped and \
+                            (enc_plan is None or not enc_plan.buf_dicts):
+                        M.record_ungrouped_agg_batch()
+                        memo = ungrouped_kernel[0]
+                        if memo is None or memo[0] != (enc_sig, run_key):
+                            memo = ((enc_sig, run_key),
+                                    self._build_ungrouped_update_kernel(
+                                        eff_attrs, eff_inputs,
+                                        tuple(eff_ops), eff_filters))
+                            ungrouped_kernel[0] = memo
+                        kern = memo[1]
+                        cols = ENC.eval_cols(batch, enc_plan.code_ords) \
+                            if enc_plan is not None \
+                            else [_col_to_colv(c) for c in batch.columns]
+                        if not cols:
+                            cols = [_synth_col(batch)]
+
+                        def _attempt():
+                            M.record_dispatch()
+                            return kern(cols, count_arg(batch))
+
+                        with M.trace_range("TpuHashAggregate.update",
+                                           self.metrics[M.TOTAL_TIME]):
+                            OBS.annotate(path="ungrouped",
+                                         **rows_attr(batch))
+                            outs = with_retry(_attempt, site="agg.update")
+                        # one row whatever the device found: a batch with
+                        # no live row leaves each buffer's empty state
+                        local = self._lazy_batch(outs, 1)
                         running = local if running is None else \
                             merge(concat_batches([running, local]))
                         continue

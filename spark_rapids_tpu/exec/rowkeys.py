@@ -430,6 +430,43 @@ def _sorted_segment_reduce(per_row_sorted, gi: GroupInfo, capacity: int,
     return scanned[ends]
 
 
+def reduce_all(op: str, data, vmask):
+    """Traced: one group's reduction of `data` over the lanes of `vmask`
+    with SQL null semantics, as scalars: (value, whether any lane
+    counted). The keyless case of `segment_reduce`, and what the
+    ungrouped update program (exec/aggregate.py) writes into its one
+    row; `count` always counts (0 over no lane)."""
+    if op == "count":
+        return jnp.sum(vmask.astype(jnp.int64)), jnp.array(True)
+    has = jnp.sum(vmask.astype(jnp.int32)) > 0
+    if op == "sum":
+        if jnp.dtype(data.dtype).kind in "iu" \
+                and jnp.dtype(data.dtype).itemsize < 8:
+            data = data.astype(jnp.int64)  # SQL sum over integrals is LONG
+        r = jnp.sum(jnp.where(vmask, data, jnp.zeros((), data.dtype)))
+    elif op == "any":
+        r = jnp.any(vmask & data.astype(bool))
+    elif jnp.dtype(data.dtype).kind == "f":
+        # total-order bits: NaN sorts greater than every number
+        bits = _float_order_bits(data)
+        if op == "min":
+            r = _float_from_order_bits(jnp.min(jnp.where(
+                vmask, bits, jnp.array(jnp.iinfo(bits.dtype).max,
+                                       bits.dtype)))
+            ).astype(data.dtype)
+        else:
+            r = _float_from_order_bits(jnp.max(jnp.where(
+                vmask, bits, jnp.array(0, bits.dtype)))
+            ).astype(data.dtype)
+    elif op == "min":
+        r = jnp.min(jnp.where(vmask, data, _type_max(data.dtype)))
+    elif op == "max":
+        r = jnp.max(jnp.where(vmask, data, _type_min(data.dtype)))
+    else:
+        raise ValueError(f"no whole-batch reduction for op {op!r}")
+    return r, has
+
+
 def segment_reduce(op: str, data, validity, gid, num_rows, capacity: int):
     """Reduce `data` per group with SQL null semantics.
 
@@ -469,7 +506,7 @@ def segment_reduce(op: str, data, validity, gid, num_rows, capacity: int):
                                  capacity).astype(jnp.int64)
             return cnt, jnp.ones((capacity,), bool)
         if keyless:
-            cnt = jnp.sum((validity & in_group).astype(jnp.int64))
+            cnt, _ = reduce_all("count", data, validity & in_group)
             return at_slot0(cnt), jnp.ones((capacity,), bool)
         seg = _seg_ids(gid, validity & in_group, capacity)
         ones = jnp.ones((capacity,), jnp.int64)
@@ -576,29 +613,8 @@ def segment_reduce(op: str, data, validity, gid, num_rows, capacity: int):
             out = jnp.where(outv, out, jnp.zeros((), out.dtype))
             return out, outv
         if keyless:
-            vmask = validity & in_group
-            nn = jnp.sum(vmask.astype(jnp.int32))
-            outv = at_slot0(nn > 0, bool)
-            if op == "sum":
-                r = jnp.sum(jnp.where(vmask, data, jnp.zeros((),
-                                                             data.dtype)))
-            elif op == "any":
-                r = jnp.any(vmask & data.astype(bool))
-            elif jnp.dtype(data.dtype).kind == "f":
-                bits = _float_order_bits(data)
-                if op == "min":
-                    r = _float_from_order_bits(jnp.min(jnp.where(
-                        vmask, bits, jnp.array(jnp.iinfo(bits.dtype).max,
-                                               bits.dtype)))
-                    ).astype(data.dtype)
-                else:
-                    r = _float_from_order_bits(jnp.max(jnp.where(
-                        vmask, bits, jnp.array(0, bits.dtype)))
-                    ).astype(data.dtype)
-            elif op == "min":
-                r = jnp.min(jnp.where(vmask, data, _type_max(data.dtype)))
-            else:
-                r = jnp.max(jnp.where(vmask, data, _type_min(data.dtype)))
+            r, has = reduce_all(op, data, validity & in_group)
+            outv = at_slot0(has, bool)
             out = jnp.where(outv, at_slot0(r), jnp.zeros((), r.dtype))
             return out, outv
         seg = _seg_ids(gid, validity & in_group, capacity)

@@ -2204,9 +2204,14 @@ class _Analyzer:
                 hi = min(_hi_or(cin.rows.hi, INF), G)
                 rows = Interval(min(1, cin.rows.lo),
                                 hi if hi != INF else cin.rows.hi)
-        else:
+        # the ungrouped update program (exec/aggregate.py
+        # `_ungrouped_ok`): one dispatch a batch and one row a partition
+        # that holds a batch, whatever the device finds in it
+        ungrouped = is_tpu and do_update and node._ungrouped_ok()
+        if not grouped:
             rows = Interval.exact(1) if node.mode != PARTIAL else \
-                Interval(0, cin.nonempty.hi)
+                Interval(min(cin.nonempty.lo, cin.rows.lo) if ungrouped
+                         else 0, cin.nonempty.hi)
         batches = cin.nonempty if node.mode == PARTIAL else \
             Interval(1 if (not grouped and node.mode != PARTIAL)
                      else cin.nonempty.lo, _hi_or(cin.nonempty.hi, 1))
@@ -2243,7 +2248,7 @@ class _Analyzer:
                     <= LAZY_PIECE_CAP_BYTES)
         exact = (cin.batches.is_exact and cin.nonempty.is_exact
                  and not cin.lazy_tail)
-        asm = 0 if upd_lazy else (2 + n_str_aggs)
+        asm = 0 if (upd_lazy or ungrouped) else (2 + n_str_aggs)
         merge_asm = 0 if lazy_ok else (2 + n_str_aggs)
         # a compacted output re-buckets to its group count; a lazy output
         # keeps the INPUT capacity (padded lanes), so only the compacted
@@ -2251,6 +2256,8 @@ class _Analyzer:
         compacts = not upd_lazy if do_update else not lazy_ok
         if grouped and G != INF and compacts:
             st.batch_rows = st.batch_rows.clamp_hi(int(G))
+        if ungrouped:
+            st.batch_rows = st.batch_rows.clamp_hi(1)
         if do_update:
             per_batch = 1 + asm
             d = cin.batches.scale(per_batch)

@@ -868,7 +868,8 @@ class TpuSession:
                          M.SPECULATIVE_TASKS, M.SPECULATIVE_WINS,
                          M.WATCHDOG_KILLS, M.DEVICE_RESETS,
                          M.DENSE_AGG_BATCHES, M.SORT_AGG_BATCHES,
-                         M.COMPACTED_BATCHES, M.CACHED_BATCHES_SERVED,
+                         M.UNGROUPED_AGG_BATCHES, M.COMPACTED_BATCHES,
+                         M.CACHED_BATCHES_SERVED,
                          M.CACHE_RESTORED_BATCHES):
                 self.last_query_metrics[name] = snap.get(name, 0)
             self.last_query_metrics[M.CACHE_RESIDENT_BYTES] = \

@@ -392,11 +392,17 @@ def dispatch_count() -> int:
 
 # grouped-aggregate update batches by the path they took: the table over
 # dictionary codes (exec/dense_agg.py) or the sort (exec/rowkeys.py). An
-# ungrouped aggregate counts in neither
+# ungrouped aggregate counts in neither: a batch whose partial was the one
+# program with a one-row output (exec/aggregate.py
+# `_build_ungrouped_update_kernel`) counts in ungroupedAggBatches, one that
+# stayed on the sort path's keyless form (a STRING min/max, first/last)
+# in none
 DENSE_AGG_BATCHES = "denseAggBatches"
 SORT_AGG_BATCHES = "sortAggBatches"
+UNGROUPED_AGG_BATCHES = "ungroupedAggBatches"
 _DENSE_AGG_BATCHES = Metric(DENSE_AGG_BATCHES)
 _SORT_AGG_BATCHES = Metric(SORT_AGG_BATCHES)
+_UNGROUPED_AGG_BATCHES = Metric(UNGROUPED_AGG_BATCHES)
 
 
 def record_agg_batch(dense: bool) -> None:
@@ -404,6 +410,15 @@ def record_agg_batch(dense: bool) -> None:
         else (SORT_AGG_BATCHES, _SORT_AGG_BATCHES)
     metric.add(1)
     _note(name, 1)
+
+
+def record_ungrouped_agg_batch() -> None:
+    _UNGROUPED_AGG_BATCHES.add(1)
+    _note(UNGROUPED_AGG_BATCHES, 1)
+
+
+def ungrouped_agg_batch_count() -> int:
+    return _UNGROUPED_AGG_BATCHES.value
 
 
 def dense_agg_batch_count() -> int:

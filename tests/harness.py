@@ -248,3 +248,48 @@ def gen_df(session: TpuSession, gens: Sequence[tuple], n: int = 512,
     schema = [(name, g.dtype) for name, g in gens]
     return session.createDataFrame(data, schema,
                                    num_partitions=num_partitions)
+
+
+def forbid_device_fetch_in_map_tasks(monkeypatch) -> List[int]:
+    """While a map task of an exchange runs (`run_map` under
+    `scheduler.run_job_or_serial`), `jax.device_get` and a `host_rows()`
+    that would have to fetch its count from the device fail the test.
+    Returns the list the guarded tasks' partition ids are appended to."""
+    import threading
+
+    import jax
+
+    from spark_rapids_tpu.columnar.batch import ColumnarBatch
+    from spark_rapids_tpu.engine import scheduler as SCH
+
+    in_map = threading.local()
+    tasks: List[int] = []
+    real_run, real_get = SCH.run_job_or_serial, jax.device_get
+    real_rows = ColumnarBatch.host_rows
+
+    def run(scheduler, n, fn):
+        if fn.__name__ != "run_map":
+            return real_run(scheduler, n, fn)
+
+        def guarded(p):
+            in_map.on = True
+            tasks.append(p)
+            try:
+                return fn(p)
+            finally:
+                in_map.on = False
+        return real_run(scheduler, n, guarded)
+
+    def device_get(x):
+        assert not getattr(in_map, "on", False), "device_get in a map task"
+        return real_get(x)
+
+    def host_rows(self):
+        assert self.rows_on_host or not getattr(in_map, "on", False), \
+            "host_rows() fetches a count in a map task"
+        return real_rows(self)
+
+    monkeypatch.setattr(SCH, "run_job_or_serial", run)
+    monkeypatch.setattr(jax, "device_get", device_get)
+    monkeypatch.setattr(ColumnarBatch, "host_rows", host_rows)
+    return tasks

@@ -335,3 +335,59 @@ def test_a_plan_made_before_the_relation_was_held_is_not_reused(relation):
     assert (third[M.PLAN_CACHE_MISSES], third[M.PLAN_CACHE_HITS]) == (0, 1)
     report = relation.session.last_resource_report
     assert report.peak_bytes.hi < 64 << 20
+
+
+# ---------------------------------------------------------------------------
+# The ungrouped partial over a cached batch: one program, nothing fetched
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("source", ["files", "cache"])
+def test_q6_partial_is_one_program_a_batch_and_the_analyzer_says_so(
+        relation, source):
+    """Q6 has no grouping key: every batch's partial is the ungrouped
+    update program, the action's dispatches are one a batch and the
+    merge's two, and the analyzer predicts that count: exactly over a
+    cached relation (it knows the batches), as an interval that holds it
+    over files (a scan's batches are not known before it runs)."""
+    relation.action()                      # materialize
+    if source == "cache":
+        _, metrics, _ = relation.action()
+    else:
+        q6(relation.table).collect()
+        metrics = dict(relation.session.last_query_metrics)
+    batches = relation.files
+    assert metrics[M.UNGROUPED_AGG_BATCHES] == batches
+    assert metrics[M.DENSE_AGG_BATCHES] == metrics[M.SORT_AGG_BATCHES] == 0
+    assert metrics[M.DEVICE_DISPATCHES] == batches + 2
+    predicted = relation.session.last_resource_report.dispatches
+    assert predicted.lo <= metrics[M.DEVICE_DISPATCHES] <= predicted.hi
+    if source == "cache":
+        assert relation.session.last_resource_report.dispatches_exact
+        assert predicted.lo == predicted.hi
+
+
+def test_a_cached_partial_task_fetches_nothing_from_the_device(
+        relation, monkeypatch):
+    """A map task over a cached batch: the permit, the batch, one program.
+    No `jax.device_get` and no `host_rows()` that would have to fetch a
+    count runs on its thread, and its span tree holds one update (no
+    finalize) with `ungroupedAggBatches` counted on the task."""
+    from tests.harness import forbid_device_fetch_in_map_tasks
+
+    relation.action()                      # materialize, unguarded
+    guarded = forbid_device_fetch_in_map_tasks(monkeypatch)
+    relation.session.conf.set(TRACING, True)
+    rows, metrics, tree = relation.action()
+    assert rows[0][0] == pytest.approx(q6_reference(relation.cols), rel=1e-9)
+    tasks = [sp for sp in tree.spans()
+             if sp.kind == "task" and any(c.name == "cache.serve"
+                                          for c in sp.children)]
+    assert len(tasks) == relation.files == len(guarded)
+    for task in tasks:
+        assert task.counts[M.UNGROUPED_AGG_BATCHES] == 1
+        updates = [c for c in task.children
+                   if c.name == "TpuHashAggregate.update"]
+        assert len(updates) == 1 and updates[0].attrs["path"] == "ungrouped"
+        assert updates[0].counts[M.DEVICE_DISPATCHES] == 1
+        # the gather over a key batch with no columns counted one here
+        assert M.DEVICE_DISPATCHES not in task.counts
+    assert not tree.find("TpuHashAggregate.finalize")

@@ -192,17 +192,22 @@ def test_cumsum_wrap_lanes(one_chip, no_persistent_cache):
 def test_q6_fused_aggregate_in_f32(smoke_programs, one_chip,
                                    no_persistent_cache):
     """q6's fused stage: the filter and l_extendedprice * l_discount folded
-    into the partial aggregate's update kernel, DOUBLE narrowed to f32."""
-    calls = [c for c in smoke_programs.rec.programs(
-        "_build_update_kernel", "exec/aggregate.py")
-        if "stablehlo.sort" not in c[1].lower(*c[2], **c[3]).as_text()]
-    assert calls, "q6 built no global update kernel"
+    into the partial aggregate's update kernel, DOUBLE narrowed to f32.
+    Since PR 50 that kernel is the ungrouped update program: no sort, no
+    scatter, and an output of `bucket_capacity(1)` lanes."""
+    from spark_rapids_tpu.columnar.batch import bucket_capacity
+
+    calls = smoke_programs.rec.programs("_build_ungrouped_update_kernel",
+                                        "exec/aggregate.py")
+    assert calls, "q6 built no ungrouped update kernel"
     fun, jitted, args, kwargs = calls[0]
     tiny = _capacity_of(args)
     lowered, text = _lower(jitted, _at_capacity(
         args, tiny, ROW_GROUP_CAP, one_chip), kwargs)
     assert f"tensor<{ROW_GROUP_CAP}xf32>" in text
-    assert "stablehlo.sort" not in text  # a global aggregate
+    assert "stablehlo.sort" not in text and "stablehlo.scatter" not in text
+    out = jax.tree.leaves(lowered.out_info)
+    assert out and all(o.shape == (bucket_capacity(1),) for o in out)
     lowered.compile()
 
 

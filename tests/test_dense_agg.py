@@ -137,12 +137,16 @@ def test_a_table_over_the_constant_takes_the_sort(session, tmp_path):
 
 
 def test_an_ungrouped_aggregate_counts_in_neither(session, tmp_path):
+    """It has no grouping to take either way: it counts in a third, the
+    ungrouped update program's `ungroupedAggBatches`."""
     pq.write_table(_table(11, 500, ["A"], ["F"]), str(tmp_path / "t.parquet"))
     before = (M.dense_agg_batch_count(), M.sort_agg_batch_count())
+    ungrouped = M.ungrouped_agg_batch_count()
     rows = run_on_tpu(session, lambda s: s.read.parquet(str(tmp_path))
                       .agg(F.sum("v").alias("sv")))
     assert len(rows) == 1
     assert (M.dense_agg_batch_count(), M.sort_agg_batch_count()) == before
+    assert M.ungrouped_agg_batch_count() == ungrouped + 1
 
 
 def test_group_reduce_blocks_a_long_float_sum():

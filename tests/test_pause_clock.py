@@ -153,13 +153,18 @@ def jumping_clock(monkeypatch):
     pause_clock.shutdown()
 
 
-@pytest.mark.parametrize("jump_s, slow_task, speculated", [
-    (3, None, 0),    # a pause alone: nobody is a straggler
-    (0, 11, 1),      # a straggler alone: as ever
-    (3, 11, 1),      # a straggler during a pause: still caught, alone
+@pytest.mark.parametrize("jump_s, slow_task, speculated, quick_s", [
+    (3, None, 0, 0.02),    # a pause alone: nobody is a straggler
+    (0, 11, 1, 0.02),      # a straggler alone: as ever
+    (3, 11, 1, 0.02),      # a straggler during a pause: still caught, alone
+    # siblings of a millisecond (an ungrouped partial over a cached batch):
+    # 4 x p95 is 4 ms, so the floor of 500 ms is the threshold, and the
+    # pause is no more a straggler for them than for tasks of 20 ms
+    (3, None, 0, 0.001),
 ])
 def test_speculation_tells_a_pause_from_a_straggler(jumping_clock, jump_s,
-                                                    slow_task, speculated):
+                                                    slow_task, speculated,
+                                                    quick_s):
     """16 tasks on the 8-thread pool. The first eight finish, the second
     eight are all in flight when the clock jumps: read off the wall every
     one of them is 3 s old, the pool's width of stragglers."""
@@ -174,7 +179,7 @@ def test_speculation_tells_a_pause_from_a_straggler(jumping_clock, jump_s,
             calls[p] = calls.get(p, 0) + 1
             first_try = calls[p] == 1
         if p < 8 or not first_try:
-            time.sleep(0.02)
+            time.sleep(quick_s)
             return p * 10
         if second_wave.wait(timeout=30.0) == 0 and jump_s:
             jumping_clock(jump_s * S)

@@ -1151,6 +1151,72 @@ def concat_batches(batches: Sequence[ColumnarBatch]) -> ColumnarBatch:
     return ColumnarBatch(out_cols, total, owned=True)
 
 
+# the most operands `concat_in_order`'s one program takes (a piece brings
+# two a column): tracing a jit over 832 took 2.2 s on the chip's host
+_IN_ORDER_OPERANDS = 1024
+
+
+def concat_in_order(batches: Sequence[ColumnarBatch]) -> ColumnarBatch:
+    """`concat_batches` for a few large pieces that will be read many
+    times (a resident relation's batch, exec/cache.py): ONE program that
+    copies each piece to its place, so no pack matrix stands beside the
+    pieces and the output (the transient is the output alone) and the
+    program holds no scatter. The row counts are operands, so a program
+    is keyed by the pieces' capacities in order. Pieces it cannot take
+    (a live mask, a count on the device, a plain STRING column, more
+    operands than a jit should trace) go to `concat_batches`."""
+    from spark_rapids_tpu.columnar.encoded import DictionaryColumn
+
+    assert batches, "cannot concat zero batches"
+    ncols = batches[0].num_columns
+    if len(batches) > 1 and ncols:
+        batches, enc_dicts = _align_encoded_positions(_same_device(batches))
+    if (len(batches) == 1 or not ncols
+            or 2 * ncols * len(batches) > _IN_ORDER_OPERANDS
+            or not all(b.rows_on_host and b.live is None for b in batches)
+            or any(c.dtype is DataType.STRING and ci not in enc_dicts
+                   for ci, c in enumerate(batches[0].columns))):
+        return concat_batches(batches)
+    starts = np.concatenate(
+        [[0], np.cumsum([b.num_rows for b in batches])]).astype(np.int32)
+    total = int(starts[-1])
+    cap = bucket_capacity(total)
+    outs = _pack_kernel(
+        "concat_in_order", _concat_in_order_traced, (0,), cap,
+        device_const(starts),
+        tuple(tuple(c.data for c in b.columns) for b in batches),
+        tuple(tuple(c.validity for c in b.columns) for b in batches))
+    out_cols: List[Optional[ColumnVector]] = [None] * ncols
+    _fill_out_cols(out_cols, list(range(ncols)), outs, batches)
+    for ci, shared in enc_dicts.items():
+        out_cols[ci] = DictionaryColumn(
+            batches[0].columns[ci].dtype, out_cols[ci].data,
+            out_cols[ci].validity, shared)
+    _concat_run_tables(out_cols, batches)
+    return ColumnarBatch(out_cols, total, owned=True)
+
+
+def _concat_in_order_traced(cap, starts, datas, valids):
+    """Piece p's whole capacity is written at `starts[p]`, in order, so
+    the lanes behind its rows are overwritten by the piece after it; what
+    the last one leaves behind the total is cleared. The buffer written
+    is the output and the largest piece over, so that no write is
+    clamped back onto rows."""
+    room = cap + max(d[0].shape[0] for d in datas)
+    keep = jnp.arange(cap, dtype=jnp.int32) < starts[len(datas)]
+    outs = []
+    for ci in range(len(datas[0])):
+        data = jnp.zeros((room,), datas[0][ci].dtype)
+        valid = jnp.zeros((room,), bool)
+        for p in range(len(datas)):
+            at = (starts[p],)
+            data = jax.lax.dynamic_update_slice(data, datas[p][ci], at)
+            valid = jax.lax.dynamic_update_slice(valid, valids[p][ci], at)
+        outs.append((jnp.where(keep, data[:cap], jnp.zeros((), data.dtype)),
+                     keep & valid[:cap]))
+    return outs
+
+
 def _concat_run_tables(out_cols, batches) -> None:
     from spark_rapids_tpu.columnar.runs import RunTable
 

@@ -230,7 +230,10 @@ CONCURRENT_TPU_TASKS = _conf("rapids.tpu.concurrentTpuTasks").doc(
 # ---------------------------------------------------------------------------
 BATCH_SIZE_BYTES = _conf("rapids.tpu.sql.batchSizeBytes").doc(
     "Target size in bytes of coalesced columnar batches "
-    "(reference: spark.rapids.sql.batchSizeBytes, GpuCoalesceBatches)."
+    "(reference: spark.rapids.sql.batchSizeBytes, GpuCoalesceBatches). "
+    "Also the size of a device-cached relation's batches: DataFrame.cache() "
+    "gathers what its plan hands over into batches of the largest capacity "
+    "bucket that stays inside it (exec/cache.py)."
 ).bytes(512 << 20)
 
 MAX_READ_BATCH_SIZE_ROWS = _conf("rapids.tpu.sql.reader.batchSizeRows").doc(

@@ -870,7 +870,8 @@ class TpuSession:
                          M.DENSE_AGG_BATCHES, M.SORT_AGG_BATCHES,
                          M.UNGROUPED_AGG_BATCHES, M.COMPACTED_BATCHES,
                          M.CACHED_BATCHES_SERVED,
-                         M.CACHE_RESTORED_BATCHES):
+                         M.CACHE_RESTORED_BATCHES,
+                         M.CACHE_COALESCED_PIECES):
                 self.last_query_metrics[name] = snap.get(name, 0)
             self.last_query_metrics[M.CACHE_RESIDENT_BYTES] = \
                 M.cache_resident_bytes()

@@ -449,10 +449,14 @@ def compacted_batch_count() -> int:
 # scan handed to its consumer, and of those the ones that had left the
 # device (spilled to host or disk) and were uploaded again to be served.
 # cacheResidentBytes is a gauge, not a count: the bytes of cached batches
-# on the device at the moment it is read
+# on the device at the moment it is read. cacheCoalescedPieces: batches as
+# the cached plan handed them over that a materialisation concatenated
+# into a resident batch of the target size (a piece kept as it came
+# counts nothing)
 CACHED_BATCHES_SERVED = "cachedBatchesServed"
 CACHE_RESTORED_BATCHES = "cacheRestoredBatches"
 CACHE_RESIDENT_BYTES = "cacheResidentBytes"
+CACHE_COALESCED_PIECES = "cacheCoalescedPieces"
 _CACHED_BATCHES_SERVED = Metric(CACHED_BATCHES_SERVED)
 _CACHE_RESTORED_BATCHES = Metric(CACHE_RESTORED_BATCHES)
 
@@ -463,6 +467,11 @@ def record_cached_batch_served(restored: bool) -> None:
     if restored:
         _CACHE_RESTORED_BATCHES.add(1)
         _note(CACHE_RESTORED_BATCHES, 1)
+
+
+def record_cache_coalesced_pieces(pieces: int) -> None:
+    # the materialising query's alone: nothing reads it process-wide
+    _note(CACHE_COALESCED_PIECES, pieces)
 
 
 def cached_batches_served_count() -> int:

@@ -211,6 +211,65 @@ def test_q6_fused_aggregate_in_f32(smoke_programs, one_chip,
     lowered.compile()
 
 
+RESIDENT_CAP = 1 << 23    # a resident relation's batch: 512 MB / 35 B
+
+
+def _assert_streams(text):
+    """Nothing that costs the chip's compiler minutes at 2^23 lanes, or
+    the chip 20 ns a lane: no sort, no scatter, no flat cumulative sum
+    (a `reduce_window` over the lanes: 31.8 s of compile at 2^20, PR 41)."""
+    for op in ("sort", "scatter", "reduce_window"):
+        assert f"stablehlo.{op}" not in text, op
+
+
+def test_a_resident_batch_is_assembled_and_summed_at_its_capacity(
+        smoke_programs, one_chip, no_persistent_cache):
+    """`DataFrame.cache()` holds its relation in batches of the engine's
+    target size (PR 51): `q6_cached`'s are ten pieces of 2^20 and 2^19
+    lanes, seven columns, in 2^23 lanes. The concat that assembles one
+    (`columnar/batch.concat_in_order`: a copy a piece, the row counts as
+    operands) and Q6's ungrouped update over it are compiled here for the
+    v5e at that capacity, both inside a bound on the seconds this test
+    measures; the concat's temporaries are one column's room (the output
+    and the largest piece over), not a second copy of the pieces."""
+    import time
+
+    from spark_rapids_tpu.columnar import batch as B
+
+    began = time.perf_counter()
+    caps = (1 << 20, 1 << 19) * 5
+    dtypes = (jnp.float32,) * 4 + (jnp.int32,) * 3
+
+    def lanes(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+    # tpulint: jit-cache -- one-shot compile of the device-only branch
+    lowered, text = _lower(
+        jax.jit(B._concat_in_order_traced, static_argnums=(0,)),
+        (RESIDENT_CAP, lanes(len(caps) + 1, jnp.int32),
+         tuple(tuple(lanes(c, d) for d in dtypes) for c in caps),
+         tuple(tuple(lanes(c, jnp.bool_) for _ in dtypes) for c in caps)),
+        {})
+    _assert_streams(text)
+    assert "stablehlo.gather" not in text
+    out = jax.tree.leaves(lowered.out_info)
+    assert [o.shape for o in out] == [(RESIDENT_CAP,)] * 14
+    ma = lowered.compile().memory_analysis()
+    assert 0 <= ma.output_size_in_bytes - 35 * RESIDENT_CAP < 4096
+    assert ma.temp_size_in_bytes < 64 << 20    # the pieces are 275 MB
+
+    calls = smoke_programs.rec.programs("_build_ungrouped_update_kernel",
+                                        "exec/aggregate.py")
+    assert calls, "q6 built no ungrouped update kernel"
+    fun, jitted, args, kwargs = calls[0]
+    lowered, text = _lower(jitted, _at_capacity(
+        args, _capacity_of(args), RESIDENT_CAP, one_chip), kwargs)
+    assert f"tensor<{RESIDENT_CAP}xf32>" in text
+    _assert_streams(text)
+    assert lowered.compile().memory_analysis().temp_size_in_bytes < 1 << 28
+    assert time.perf_counter() - began < 120
+
+
 def test_q1_aggregate_update_kernel_is_dense_in_f32(
         smoke_programs, one_chip, no_persistent_cache):
     """q1's update since PR 37: two dictionary-coded string keys from

@@ -559,6 +559,99 @@ def test_concat_wide_pieces_equals_numpy(branch, pieces, buckets):
     assert tally.operands == len(_WIDE) * per_group
 
 
+def _in_order_keys():
+    from spark_rapids_tpu.engine import jit_cache
+
+    with jit_cache._LOCK:
+        return {k for k in jit_cache._CACHE
+                if isinstance(k, tuple) and k[0] == "concat_in_order"}
+
+
+@pytest.mark.parametrize("rows", [
+    (16, 9, 16, 3), (5, 3), (8, 8), (1, 1, 1, 1, 1, 1, 1, 1, 1), (7, 120)],
+    ids=lambda r: "x".join(map(str, r)))
+def test_concat_in_order_equals_numpy_and_pads_with_nothing(rows):
+    """A resident relation's concat (exec/cache.py): one program that
+    copies each piece to its place. 5 + 3 rows in pieces of 8 lanes fill
+    an output of 8, so the last piece's bucket would reach past it: the
+    write is not clamped back onto rows. Behind the rows the lanes are
+    zero and invalid, as an upload leaves them, whatever the pieces
+    held there; and the program is keyed by the capacities alone."""
+    rng = np.random.default_rng(sum(rows))
+    hosts = [_wide_piece(rng, n) for n in rows]
+    devs = [hb.to_device() for hb in hosts]
+    for db in devs:     # garbage behind the rows: a concat must not keep it
+        for c in db.columns:
+            junk = jnp.arange(c.capacity) >= db.num_rows
+            c.data = jnp.where(junk, jnp.ones((), c.data.dtype), c.data)
+            c.validity = c.validity | junk
+    before = _in_order_keys()
+    out = B.concat_in_order(devs)
+    (key,) = _in_order_keys() - before or [None]
+    assert out.rows_on_host and out.live is None and out.owned
+    assert out.capacity == bucket_capacity(sum(rows))
+    want = [(np.concatenate([hb.columns[ci].data for hb in hosts]),
+             np.concatenate([hb.columns[ci].validity for hb in hosts]))
+            for ci in range(len(_WIDE))]
+    _assert_rows_equal(out.to_host(), want)
+    for c in out.columns:
+        assert not np.asarray(c.validity)[out.num_rows:].any()
+        assert not np.asarray(c.data)[out.num_rows:].any()
+    # the same capacities, other row counts: the program is there already
+    again = [_wide_piece(rng, max(1, n - 1)).to_device() for n in rows]
+    if [b.capacity for b in again] == [b.capacity for b in devs]:
+        B.concat_in_order(again)
+        assert _in_order_keys() - before == ({key} if key else set())
+
+
+def test_concat_in_order_keeps_dictionary_codes_and_leaves_the_rest(
+        monkeypatch):
+    """Dictionary-coded columns are aligned to one dictionary and travel
+    as codes; what the one program cannot take (a plain STRING column, a
+    live mask, more operands than a jit should trace) is `concat_batches`'
+    to do, with the same rows."""
+    hb1 = HostColumnarBatch([
+        HostColumnVector.from_pylist(["a", "b", None, "a"], DataType.STRING),
+        HostColumnVector.from_pylist([1, 2, 3, None], DataType.INT64)])
+    hb2 = HostColumnarBatch([
+        HostColumnVector.from_pylist(["c", "a", "c"], DataType.STRING),
+        HostColumnVector.from_pylist([None, 6, 7], DataType.INT64)])
+    want = hb1.to_pylist_rows() + hb2.to_pylist_rows()
+    plain = [hb1.to_device(), hb2.to_device()]
+    assert B.concat_in_order(plain).to_host().to_pylist_rows() == want
+
+    def coded(batch, values, codes):
+        col = ENC.DictionaryColumn(
+            DataType.STRING,
+            jnp.asarray(np.asarray(codes + [0] * (8 - len(codes)), np.int32)),
+            batch.columns[0].validity,
+            ENC.DeviceDictionary.from_values(values))
+        return ColumnarBatch([col, batch.columns[1]], batch.num_rows)
+
+    out = B.concat_in_order([coded(plain[0], ["a", "b"], [0, 1, 0, 0]),
+                             coded(plain[1], ["c", "a"], [0, 1, 0])])
+    assert ENC.is_encoded(out.columns[0])
+    assert out.columns[0].dictionary.size == 3
+    assert ENC.decode_batch(out).to_host().to_pylist_rows() == want
+    calls = []
+    monkeypatch.setattr(B, "concat_batches",
+                        lambda bs: calls.append(len(bs)) or concat_batches(bs))
+    B.concat_in_order(plain)                       # a plain STRING column
+    ints = [ColumnarBatch([b.columns[1]], b.num_rows) for b in plain]
+    masked = [ColumnarBatch(b.columns, jnp.asarray(b.num_rows, jnp.int32),
+                            live=jnp.arange(b.capacity) < b.num_rows)
+              for b in ints]
+    B.concat_in_order(masked)                      # live masks
+    B.concat_in_order(ints[:1])                    # nothing to concat
+    monkeypatch.setattr(B, "_IN_ORDER_OPERANDS", 3)
+    B.concat_in_order(ints)                        # 4 operands
+    assert calls == [2, 2, 1, 2]
+    monkeypatch.setattr(B, "_IN_ORDER_OPERANDS", 4)
+    assert B.concat_in_order(ints).to_host().to_pylist_rows() == [
+        (r[1],) for r in want]
+    assert calls == [2, 2, 1, 2]
+
+
 def _pack_keys():
     from spark_rapids_tpu.engine import jit_cache
 
